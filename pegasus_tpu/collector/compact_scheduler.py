@@ -133,7 +133,7 @@ def stage_cost_us(window: dict) -> float:
 def tune_knobs(ewma_us: float, knobs: dict) -> tuple:
     """Feedback-tune the fold's urgency thresholds from the measured
     merge cost (EWMA of stage_cost_us across ticks). Pure. Rationale:
-    expensive merges (slow device/tunnel, big partitions) amortize their
+    expensive merges (slow device, big partitions) amortize their
     fixed cost over more debt — promote LATER (doubled thresholds);
     cheap merges should keep read amplification low — promote EARLIER
     (halved thresholds, floored). -> (tuned knobs, report dict)."""

@@ -62,6 +62,11 @@ class _ConcurrencyGate:
 
 GATE = _ConcurrencyGate()
 
+# compactions an env update started run off the updater's thread, at most
+# this many at a time in one process (the reference bounds them by the
+# size of its compact thread pool)
+_BACKGROUND_SLOTS = threading.BoundedSemaphore(2)
+
 
 class ManualCompactService:
     MIN_INTERVAL_SECONDS = 0  # tests override; reference flag default 0=any
@@ -79,6 +84,7 @@ class ManualCompactService:
         self._last_trace = None  # per-stage breakdown of the last run
         self._last_error = None  # repr of the last FAILED run's exception
         self._last_fail_ms = 0
+        self._background = False  # an env-update compaction is queued/running
 
     # ------------------------------------------------------------------ time
 
@@ -90,6 +96,37 @@ class ManualCompactService:
         self._mock_now = seconds
 
     # ------------------------------------------------------------------ envs
+
+    def start_manual_compact_in_background(self, envs: dict) -> None:
+        """What an app-env update calls (server_impl.update_app_envs):
+        the compaction runs on its own thread, as the reference enqueues
+        LPC_MANUAL_COMPACT — so the meta's env push, and the shell's
+        `manual_compact` behind it, return once the env is accepted, not
+        after every replica has compacted inside the push. Progress and
+        failures read from query_compact_state as before."""
+        if self._check_disabled(envs) or not (self._check_once(envs)
+                                              or self._check_periodic(envs)):
+            return
+        with self._lock:
+            if self._state != _IDLE or self._background:
+                return
+            self._background = True
+            self._enqueue_ms = self.now_ms()
+        envs = dict(envs)
+
+        def run():
+            try:
+                with _BACKGROUND_SLOTS:
+                    self.start_manual_compact_if_needed(envs)
+            except Exception as e:  # noqa: BLE001 - _run recorded it for query_compact_state
+                print(f"[manual-compact] failed: {e!r}", flush=True)
+            finally:
+                with self._lock:
+                    self._background = False
+
+        from ..runtime.tasking import spawn_thread
+
+        spawn_thread(run, name="manual-compact")
 
     def start_manual_compact_if_needed(self, envs: dict) -> bool:
         """Called on every app-env update (and periodically); returns True
@@ -170,7 +207,7 @@ class ManualCompactService:
             self._start_ms = self.now_ms()
         counters.rate("manual_compact.running_count").increment()
         # device-backed compactions get a liveness probe BEFORE the merge
-        # (a wedged tunnel should be attributed to pre-existing device
+        # (a wedged device should be attributed to pre-existing device
         # state, not to the compaction) and AFTER it (refresh last_ok /
         # catch an in-run wedge the moment the merge returns or raises)
         is_device = getattr(self.server.engine.opts, "backend",
@@ -244,7 +281,7 @@ class ManualCompactService:
             if self._state == _RUNNING:
                 out = (f"running; started at {self._start_ms} "
                        f"(queued at {self._enqueue_ms})")
-            elif self._state == _QUEUED:
+            elif self._state == _QUEUED or self._background:
                 out = f"queued at {self._enqueue_ms}"
             elif self._last_finish_ms:
                 out = (f"idle; last finish at {self._last_finish_ms}, "

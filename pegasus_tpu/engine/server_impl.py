@@ -412,7 +412,7 @@ class PegasusServer:
         if scenario:
             self.set_usage_scenario(scenario)
         if any(k.startswith(consts.MANUAL_COMPACT_KEY_PREFIX) for k in envs):
-            self.manual_compact_service.start_manual_compact_if_needed(
+            self.manual_compact_service.start_manual_compact_in_background(
                 self._app_envs)
 
     def set_usage_scenario(self, scenario: str) -> bool:

@@ -40,11 +40,17 @@ import numpy as np
 from ..base.utils import epoch_now
 from ..engine.block import KVBlock
 from ..runtime.fail_points import inject as _inject
+from ..runtime.perf_counters import counters as _counters
 from ..runtime.tracing import COMPACT_TRACER as _TRACE
+from .kernel import DeviceKernel
 from .packing import DEFAULT_PREFIX_U32, compute_suffix_ranks, pack_key_prefixes, pack_sbytes
 
 _U32_MAX = np.uint32(0xFFFFFFFF)
 _MIN_BUCKET = 256  # pad runs to pow2 buckets >= this to bound jit recompiles
+
+# monotonic total of runs refused HBM residency for a key over the prefix
+# window (device-health's `bypass` block; chip_smoke.py requires zero)
+_C_LONG_KEY_BYPASS = _counters.number("engine.hbm.long_key_bypass_count")
 
 
 @dataclass
@@ -344,6 +350,9 @@ def pack_run_device(block, prefix_u32: int = DEFAULT_PREFIX_U32,
     max_klen = int(block.key_len.max())
     w = max(1, min(-(-min(max_klen, 4 * prefix_u32) // 4), prefix_u32))
     if max_klen > 4 * w:
+        # production policy (long keys need per-merge suffix ranks), but
+        # this file will never be HBM-resident nor device-read: count it
+        _C_LONG_KEY_BYPASS.increment()
         return None
     padded = _pow2ceil(block.n, _MIN_BUCKET)
     pref = pack_key_prefixes(block.key_arena, block.key_off, block.key_len, w)
@@ -507,7 +516,6 @@ def prepare_values(block: KVBlock) -> "DeviceVals | None":
 
 @functools.lru_cache(maxsize=64)
 def _compiled_val_gather(n: int, vl0: int, bucket: int):
-    import jax
     import jax.numpy as jnp
 
     def fn(val2d, idx):
@@ -516,7 +524,7 @@ def _compiled_val_gather(n: int, vl0: int, bucket: int):
         safe = jnp.clip(idx, 0, np.int32(n - 1))
         return jnp.take(val2d, safe, axis=0)
 
-    return jax.jit(fn)
+    return DeviceKernel(fn, "val_gather")
 
 
 def _finish_overlapped(concat: KVBlock, out_dev, real_idx, count: int,
@@ -738,7 +746,6 @@ def _pipeline_body(run_cols, aux_runs, padded_lens, nk, use_pallas,
 @functools.lru_cache(maxsize=256)
 def _compiled_pipeline(padded_lens: tuple, w: int, has_rank: bool):
     """Jitted pipeline over host-packed runs (prepare() uploads)."""
-    import jax
 
     from .pallas_merge import pallas_enabled
 
@@ -749,7 +756,7 @@ def _compiled_pipeline(padded_lens: tuple, w: int, has_rank: bool):
         return _pipeline_body(run_cols, aux, padded_lens, nk, use_pallas,
                               now, pidx, pmask, bottommost, do_filter)
 
-    return jax.jit(fn)
+    return DeviceKernel(fn, "merge_packed")
 
 
 def _make_cached_fn(padded_lens: tuple, run_ws: tuple, w: int,
@@ -825,9 +832,8 @@ def _make_cached_fn(padded_lens: tuple, run_ws: tuple, w: int,
 def _compiled_pipeline_cached(padded_lens: tuple, run_ws: tuple, w: int):
     """Jitted single-merge pipeline over cached device runs (see
     _make_cached_fn for the full contract)."""
-    import jax
-
-    return jax.jit(_make_cached_fn(padded_lens, run_ws, w))
+    return DeviceKernel(_make_cached_fn(padded_lens, run_ws, w),
+                        "merge_cached")
 
 
 @functools.lru_cache(maxsize=256)
@@ -835,9 +841,9 @@ def _compiled_pipeline_cached_padded(padded_lens: tuple, run_ws: tuple,
                                      w: int):
     """As _compiled_pipeline_cached but also returning the padded-concat
     survivor index (value-residency materialization needs it)."""
-    import jax
-
-    return jax.jit(_make_cached_fn(padded_lens, run_ws, w, want_padded=True))
+    return DeviceKernel(_make_cached_fn(padded_lens, run_ws, w,
+                                        want_padded=True),
+                        "merge_cached_padded")
 
 
 @functools.lru_cache(maxsize=64)
@@ -845,7 +851,6 @@ def _compiled_cached_val_gather(padded_lens: tuple, vl0: int, bucket: int):
     """Per-run masked value-row gather by PADDED-concat survivor index:
     run i owns indices [offs[i], offs[i]+padded_lens[i]). K clipped
     gathers + masked select — all HBM-bound, trivial next to the download."""
-    import jax
     import jax.numpy as jnp
 
     offs = np.cumsum([0] + list(padded_lens))
@@ -860,7 +865,7 @@ def _compiled_cached_val_gather(padded_lens: tuple, vl0: int, bucket: int):
             out = jnp.where(ok[:, None], rows, out)
         return out
 
-    return jax.jit(fn)
+    return DeviceKernel(fn, "val_gather_cached")
 
 
 def materialize_cached_survivors(concat: KVBlock, device_runs, mapped_idx,

@@ -49,6 +49,7 @@ from ..runtime.fail_points import inject as _inject
 from ..runtime.perf_counters import counters
 from ..runtime.tracing import COMPACT_TRACER as _TRACE
 from .compact import _pow2ceil
+from .kernel import DeviceKernel
 from .packing import pack_key_prefixes
 
 _FENCE_MAX = 4096     # fence entries per run (16 KiB of HBM at the cap)
@@ -58,6 +59,14 @@ _QUERY_MIN_BUCKET = 8  # pad query batches to pow2 buckets >= this
 _C_LOOKUPS = counters.number("read.device.lookup_count")
 _C_KEYS = counters.number("read.device.keys")
 _C_HITS = counters.number("read.device.hits")
+# range_batch kernel dispatches: the range twin of lookup_count. A range
+# query counts in read.range.device_count as soon as the device path was
+# ELIGIBLE; only this says the interval-resolve kernel actually ran (an
+# SST whose candidate set is under the min-batch floor resolves on the
+# host inside a "device" query)
+_C_RANGE_DISPATCH = counters.number("read.range.dispatch_count")
+# monotonic total of runs left host-served by a failed fence build
+_C_FENCE_FAIL = counters.number("read.device.fence_fail_count")
 
 
 def _fence_lower_bound(jnp, lex_less, padded_len, w, fence_len, steps,
@@ -98,7 +107,6 @@ def _fence_lower_bound(jnp, lex_less, padded_len, w, fence_len, steps,
 
 @functools.lru_cache(maxsize=64)
 def _compiled_fence_build(padded_len: int, fence_len: int):
-    import jax
     import jax.numpy as jnp
     from jax import lax
 
@@ -106,7 +114,7 @@ def _compiled_fence_build(padded_len: int, fence_len: int):
         pos = lax.iota(jnp.int32, fence_len) * step
         return jnp.take(col0, jnp.minimum(pos, n - 1))
 
-    return jax.jit(fn)
+    return DeviceKernel(fn, "fence_build")
 
 
 def build_fence_index(dr) -> bool:
@@ -129,6 +137,7 @@ def build_fence_index(dr) -> bool:
         dr.fence_len = fence_len
         return True
     except Exception as e:  # noqa: BLE001 - an index-less run is just host-served
+        _C_FENCE_FAIL.increment()
         print(f"[device-lookup] fence build failed: {e!r}", flush=True)
         dr.fence = None
         return False
@@ -141,7 +150,6 @@ def _compiled_lookup(padded_len: int, w: int, fence_len: int, qpad: int):
     (prefix lanes, klen) sort key -> exact-equality check. Keyed on the
     padded bucket lengths only, so a live engine's varying run/batch
     sizes share programs (the compaction pipeline's recompile rule)."""
-    import jax
     import jax.numpy as jnp
 
     from .device_sort import lex_less
@@ -159,7 +167,7 @@ def _compiled_lookup(padded_len: int, w: int, fence_len: int, qpad: int):
         eq &= jnp.take(klen, safe) == qklen
         return jnp.where(eq, lo, jnp.int32(-1))
 
-    return jax.jit(fn)
+    return DeviceKernel(fn, "lookup")
 
 
 def pack_queries(keys, w: int):
@@ -220,7 +228,6 @@ def _compiled_range(padded_len: int, w: int, fence_len: int, qpad: int):
     the start keys, once over the stop keys — in one program, yielding
     each query's contiguous row interval [lo, hi). Keyed on the padded
     bucket lengths like _compiled_lookup so live sizes share programs."""
-    import jax
     import jax.numpy as jnp
 
     from .device_sort import lex_less
@@ -237,7 +244,7 @@ def _compiled_range(padded_len: int, w: int, fence_len: int, qpad: int):
         # a stop below the start (empty/inverted range) clamps to empty
         return jnp.stack([lo, jnp.maximum(hi, lo)])
 
-    return jax.jit(fn)
+    return DeviceKernel(fn, "range")
 
 
 def range_batch(dr, ranges) -> np.ndarray:
@@ -268,6 +275,7 @@ def range_batch(dr, ranges) -> np.ndarray:
                  tuple(jnp.asarray(c) for c in scols), jnp.asarray(sklen),
                  tuple(jnp.asarray(c) for c in tcols), jnp.asarray(tklen))
         iv = np.asarray(out)[:, :nq].T.copy()
+    _C_RANGE_DISPATCH.increment()
     # a None stop packed as b"" would lower_bound to 0; patch to run end
     iv[open_stop, 1] = dr.n
     return iv

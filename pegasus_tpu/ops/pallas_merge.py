@@ -34,7 +34,7 @@ elements are exactly A[al:ai] ∪ B[bl:bi] and the next CHUNK are exactly
 out[d : d+CHUNK] (the chunk consumes at most CHUNK from each side, which
 the window covers).
 
-Mosaic (real-TPU) lowering notes, learned on hardware (r3):
+Mosaic (real-TPU) lowering notes, learned on hardware:
   - refs in ANY/HBM space cannot be loaded directly; slices must move via
     pltpu.make_async_copy into VMEM scratch, with tile-aligned offsets.
   - per-program split offsets live in SMEM.
@@ -52,16 +52,17 @@ In interpret mode (CPU tests) the same windowed body runs with direct
 ref loads instead of DMA — the generic interpreter does not model
 Mosaic's memory spaces.
 
-Gated by PEGASUS_PALLAS (default OFF; =1 enables). The only LOGGED
-hardware session (TPU_SESSION.log 13:49) shows the pre-rework kernel
-failing Mosaic lowering; the rework claims hardware byte-equality but
-was never re-logged, so the default stays off until a recorded session
-proves it (VERDICT-r3 weak 4). bench.py's TPU lane trials the kernel
-self-validatingly — byte-equality asserted against the XLA lane's
+Gated by PEGASUS_PALLAS (default OFF; =1 enables). The TPU body lowers
+through Mosaic on a v5e (jax 0.9.0, libtpu 0.0.34) and its output is
+byte-equal to CpuBackend's at 1M and 10M records (chip runs of PR 21;
+chip_smoke.py's compact phase repeats the check on every run). The
+default stays off: turning it on is a performance change, to be made
+with paired measurements (ROADMAP D2). bench.py's TPU lane trials the
+kernel self-validatingly — byte-equality asserted against the XLA lane's
 output — and reports it only when it lowers, matches, and wins.
 Correctness is pinned against device_sort.merge_two_sorted by
-tests/test_pallas_merge.py (interpret mode) and by the on-hardware
-byte-equality stage of tools/tpu_session.py.
+tests/test_pallas_merge.py (interpret mode) and on the chip by
+chip_smoke.py.
 
 Reference seam: the comparator loop inside RocksDB CompactRange
 (reference src/server/pegasus_server_impl.cpp:2814-2891).
@@ -76,9 +77,9 @@ from .device_sort import _partner_concat, lex_cmp
 
 
 def pallas_enabled() -> bool:
-    """Default OFF (see module docstring: the last logged hardware run
-    failed Mosaic lowering; flip only with a logged proof).
-    PEGASUS_PALLAS=1/0 forces either way."""
+    """Default OFF (see module docstring: it compiles and matches on the
+    chip; turning it on is a measured perf change). PEGASUS_PALLAS=1
+    enables."""
     return os.environ.get("PEGASUS_PALLAS") == "1"
 
 
@@ -318,6 +319,9 @@ def _compiled_merge(la, lb, n_ops, nk, interpret):
         )(al, bl, fills, *a_pad, *b_pad)
         return [m.reshape(-1)[:L_out] for m in merged]
 
+    # plain jit on purpose: the pipeline calls this inside its own trace,
+    # so it is inlined into that DeviceKernel's program and never compiles
+    # on its own under a lane guard (standalone calls are tests only)
     return jax.jit(fn)
 
 

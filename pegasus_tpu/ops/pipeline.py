@@ -2,7 +2,7 @@
 
 Every compaction path used to pay ``sum(pack + h2d + device + gather +
 sst_write)`` per range/level even though the stages run on disjoint
-resources (host CPU, PCIe/tunnel, device, host memcpy, disk). LUDA
+resources (host CPU, PCIe, device, host memcpy, disk). LUDA
 (arXiv 2004.03054) shows device-offloaded LSM compaction only wins when
 the CPU-side stages are pipelined against device work; RESYSTANCE
 (arXiv 2603.05162) shows serialized compaction stages leave large
@@ -72,6 +72,7 @@ def pipeline_depth() -> int:
 
 _POOL = None     #: guarded_by _POOL_LOCK
 _IO_POOL = None  #: guarded_by _POOL_LOCK
+_COMPILE_POOL = None  #: guarded_by _POOL_LOCK
 _POOL_LOCK = lockrank.named_lock("pipeline.pool_global")
 
 
@@ -102,6 +103,23 @@ def install_pool() -> ThreadPool:
             _IO_POOL = ThreadPool("THREAD_POOL_COMPACT_INSTALL",
                                   worker_count=2)
         return _IO_POOL
+
+
+def compile_pool() -> ThreadPool:
+    """Where kernels compile when a guarded call found them cold
+    (ops/kernel.py): HOST-ONLY work, minutes long for a merge network on
+    a TPU, so it gets its own workers — a compile must never occupy a
+    prime/prefetch worker, nor wait behind a wedged one. Two workers:
+    compiles of different programs overlap (two merge shapes in two
+    threads took 47 s wall together on a v5e, PERF.md section 5), and a
+    minutes-long merge compile cannot hold up the seconds-long read
+    kernels for long."""
+    global _COMPILE_POOL
+    with _POOL_LOCK:
+        if _COMPILE_POOL is None:
+            _COMPILE_POOL = ThreadPool("THREAD_POOL_KERNEL_COMPILE",
+                                       worker_count=2)
+        return _COMPILE_POOL
 
 
 class PipelineFuture:

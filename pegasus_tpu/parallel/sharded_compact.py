@@ -27,6 +27,7 @@ from ..engine.block import KVBlock
 from ..ops.compact import (CompactOptions, CompactResult, _apply_default_ttl,
                            _pow2ceil, _stats, apply_post_filters, merge_body,
                            sort_block)
+from ..ops.kernel import DeviceKernel
 from ..ops.packing import compute_suffix_ranks, pack_key_prefixes
 from ..runtime.fail_points import inject as _inject
 from ..runtime.lane_guard import LANE_GUARD
@@ -35,16 +36,6 @@ from ..runtime.tracing import COMPACT_TRACER as _TRACE
 
 def _next_bucket(n: int) -> int:
     return _pow2ceil(n, 1024)
-
-
-def _shard_map():
-    import jax
-
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map
 
 
 @functools.lru_cache(maxsize=32)
@@ -101,7 +92,7 @@ def _sharded_kernel(mesh_key, w: int, n_loc: int, cap: int, axis: str):
         )
         return r_gid[perm], keep, overflow[None]
 
-    smap = _shard_map()(
+    smap = jax.shard_map(
         per_device,
         mesh=mesh,
         in_specs=(
@@ -110,7 +101,7 @@ def _sharded_kernel(mesh_key, w: int, n_loc: int, cap: int, axis: str):
         ),
         out_specs=(P(axis), P(axis), P(axis)),
     )
-    return jax.jit(smap)
+    return DeviceKernel(smap, "merge_sharded")
 
 
 # shard_map needs the concrete Mesh at trace time; lru_cache keys must be
@@ -181,6 +172,10 @@ def sharded_compact(blocks, mesh, opts: CompactOptions, axis: str = "shard",
             _inject("compact.device")
             fn = _sharded_kernel(mesh_key, w, n_loc, cap, axis)
             gid_sorted, keep, overflow = fn(cols, *args, *scalars)
+            # which chips hold the output shards (stats: a mesh that
+            # quietly collapsed onto one device shows up here)
+            device_ids = sorted(s.device.id
+                                for s in gid_sorted.addressable_shards)
             gid_sorted = np.asarray(gid_sorted)
             keep = np.asarray(keep)
         if int(np.asarray(overflow).sum()) == 0:
@@ -204,7 +199,8 @@ def sharded_compact(blocks, mesh, opts: CompactOptions, axis: str = "shard",
             shards.append(shard)
         sp["records"] = out_total
     return shards, {"input_records": n, "output_records": out_total,
-                    "dropped": n - out_total, "n_shards": nsh, "capacity": cap}
+                    "dropped": n - out_total, "n_shards": nsh, "capacity": cap,
+                    "device_ids": device_ids}
 
 
 def sharded_compact_block(blocks, mesh, opts: CompactOptions,

@@ -815,8 +815,11 @@ class ReplicaStub:
         return json.dumps(out)
 
     def _cmd_batched_manual_compact(self, args) -> str:
+        from ..runtime.lane_guard import compile_wait
+
         app_id = int(args[0]) if args else None
-        stats = self.batched_manual_compact(app_id=app_id)
+        with compile_wait():  # operator-requested: wait for a cold kernel
+            stats = self.batched_manual_compact(app_id=app_id)
         return json.dumps(stats)
 
     def _on_query_replica_info(self, header, body) -> bytes:

@@ -520,6 +520,14 @@ class ReplicaApp:
         # public acceptor/router (replication/serve_groups.py)
         groups = int(os.environ.get("PEGASUS_SERVE_GROUPS")
                      or config.get_int(section, "serve_groups", 1))
+        if backend == "tpu" and groups <= 1:
+            # this process will own the engines: refuse to boot a
+            # tpu-backend node that jax would quietly run on the CPU (a
+            # grouped node's workers each make the same check — the
+            # router parent must stay off jax so they can have the chip)
+            from ..base.utils import open_device_backend
+
+            open_device_backend()
         if groups > 1:
             from ..replication.serve_groups import GroupedReplicaNode
 
@@ -816,6 +824,10 @@ class CompactOffloadApp:
             config.get_string("pegasus.server", "compaction_backend", "cpu"))
         root = config.get_string(section, "job_dir",
                                  os.path.join("pegasus-data", name))
+        if backend == "tpu":
+            from ..base.utils import open_device_backend
+
+            open_device_backend()
         self.svc = CompactOffloadService(
             root,
             host=config.get_string(section, "host", "127.0.0.1"),

@@ -130,7 +130,8 @@ class Shell:
                                 "(auto-captured on doctor degradation / "
                                 "chaos failures) or a manual capture now"),
             "trigger_audit": (self.cmd_trigger_audit,
-                              "trigger_audit [app] — decree-anchored "
+                              "trigger_audit [app [timeout_s]] — "
+                              "decree-anchored "
                               "consistency audit: every replica digests its "
                               "state at the same applied decree; mismatches "
                               "name the exact (app, pidx, node)"),
@@ -706,13 +707,35 @@ class Shell:
                 self.p(f"{i['id']}  trigger={i['trigger']} "
                        f"first_cause={i['first_cause']}  {i['reason']}")
 
+    def _audit_timeout_s(self) -> float:
+        """How long one audit call may take: every replica folds every
+        live record into its digest (about 30 MB/s of SST data per replica
+        on a v5e host, PERF.md section 5), so the 5 s that suits a small
+        table is sized up by the largest replica on disk at 4 MB/s."""
+        largest = 0
+        for n in self._nodes():
+            if not n.alive:
+                continue
+            try:
+                disk = json.loads(self._node_command(n.address,
+                                                     "replica-disk", []))
+                largest = max([largest] + [d["sst_bytes"]
+                                           for d in disk.values()])
+            except (RpcError, OSError, ValueError, KeyError):
+                continue
+        return 5.0 + largest / (4 << 20)
+
     def cmd_trigger_audit(self, args):
-        from ..collector.cluster_doctor import run_cluster_audit
+        from ..collector.cluster_doctor import (ClusterCaller,
+                                                run_cluster_audit)
 
         apps = [args[0]] if args else (
             [self.current_app] if self.current_app else None)
-        report = run_cluster_audit(self.meta_addrs, pool=self.pool,
-                                   apps=apps)
+        timeout = float(args[1]) if len(args) > 1 else self._audit_timeout_s()
+        report = run_cluster_audit(
+            self.meta_addrs, apps=apps, wait_s=timeout,
+            caller=ClusterCaller(self.meta_addrs, pool=self.pool,
+                                 timeout=timeout))
         self.p(json.dumps(report, indent=1))
         if report["mismatches"]:
             self.p(f"AUDIT FAILED: {len(report['mismatches'])} digest "

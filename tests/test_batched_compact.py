@@ -106,7 +106,9 @@ def test_batched_groups_share_compiled_programs():
             jobs.append((runs, drs, pidx))
         compact_partition_batch(jobs, opts)
     info = _compiled_batched_pipeline.cache_info()
-    assert info.misses == 1 and info.hits == 2, info
+    # (a cold kernel's first call re-enters the builder once more after the
+    # guard waited for its compile: one extra hit when nothing was cached)
+    assert info.misses == 1 and info.hits in (2, 3), info
 
 
 def test_batched_applies_user_rules_and_default_ttl():

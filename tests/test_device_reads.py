@@ -40,7 +40,8 @@ def read_guard():
     saved = READ_LANE_GUARD.config
     READ_LANE_GUARD.config = LaneGuardConfig(
         deadline_s=30.0, max_retries=1, backoff_base_s=0.001,
-        backoff_max_s=0.002, breaker_threshold=99, breaker_cooldown_s=60.0)
+        backoff_max_s=0.002, breaker_threshold=99, breaker_cooldown_s=60.0,
+        compile_wait_s=600.0)
     READ_LANE_GUARD.probe_fn = lambda: True
     READ_LANE_GUARD.reset()
     fp.setup()

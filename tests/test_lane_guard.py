@@ -34,7 +34,8 @@ def guard():
     saved_cfg, saved_probe = LANE_GUARD.config, LANE_GUARD.probe_fn
     LANE_GUARD.config = LaneGuardConfig(
         deadline_s=60.0, max_retries=1, backoff_base_s=0.001,
-        backoff_max_s=0.002, breaker_threshold=2, breaker_cooldown_s=60.0)
+        backoff_max_s=0.002, breaker_threshold=2, breaker_cooldown_s=60.0,
+        compile_wait_s=600.0)
     LANE_GUARD.probe_fn = lambda: True
     LANE_GUARD.reset()
     fp.setup()
@@ -55,6 +56,166 @@ def _assert_byte_equal(a, b):
     np.testing.assert_array_equal(a.val_arena, b.val_arena)
     np.testing.assert_array_equal(a.expire_ts, b.expire_ts)
     np.testing.assert_array_equal(a.deleted, b.deleted)
+
+
+# ------------------------------------------- a guarded call never compiles
+
+
+class _SlowJit:
+    """A jitted fn whose lowering takes `delay` seconds (a cold XLA:TPU
+    merge compile takes minutes) or raises `error`."""
+
+    def __init__(self, jitted, delay=0.0, error=None):
+        self._jitted, self._delay, self._error = jitted, delay, error
+        self.lowered = 0
+
+    def lower(self, *args):
+        import time
+
+        self.lowered += 1
+        time.sleep(self._delay)
+        if self._error is not None:
+            raise self._error
+        return self._jitted.lower(*args)
+
+
+def _slow_kernel(delay=0.0, error=None):
+    from pegasus_tpu.ops.kernel import DeviceKernel
+
+    k = DeviceKernel(lambda x: x + 1, "test_slow")
+    k._jit = _SlowJit(k._jit, delay, error)
+    return k
+
+
+def _production_wait(guard):
+    """The production setting: a call with a fallback never waits."""
+    from dataclasses import replace
+
+    guard.config = replace(guard.config, compile_wait_s=0.0)
+
+
+def test_cold_kernel_is_served_by_the_host_lane_and_counted(guard):
+    """COMPILE-BEHIND: the first guarded call of a shape does not compile
+    under the deadline (nor wait for the compiler): the compile runs on
+    the compile pool, the fallback serves the call, only `compile_behind`
+    moves — no fallback/retry/failure total, the breaker untouched — and
+    the next call of that shape runs the compiled program."""
+    import time
+
+    _production_wait(guard)
+    kernel = _slow_kernel(delay=0.5)
+    x = np.arange(4, dtype=np.int32)
+    t0 = time.monotonic()
+    out = guard.run(lambda: np.asarray(kernel(x)), lambda: "cpu", op="t",
+                    deadline_s=0.2)
+    assert out == "cpu" and time.monotonic() - t0 < 0.4
+    st = guard.state()
+    assert st["compile_behind"] == 1 and st["compile_wait_timeouts"] == 0
+    assert st["fallbacks"] == st["retries"] == st["device_failures"] == 0
+    assert st["deadline_abandons"] == 0 and not st["breaker_open"]
+    time.sleep(0.8)  # the compile pool finishes the program
+    out = guard.run(lambda: np.asarray(kernel(x)), lambda: "cpu", op="t",
+                    deadline_s=0.2)
+    np.testing.assert_array_equal(out, x + 1)
+    assert guard.state()["compile_behind"] == 1
+
+
+def test_compile_wait_is_outside_the_deadline_and_bounded(guard):
+    """A caller that asked for the device (`with compile_wait():`, or no
+    fallback) waits for the compile on its own thread: a compile longer
+    than the deadline abandons nothing, and the device part is still
+    bounded by the deadline. A wait that runs out is counted."""
+    import time
+
+    from pegasus_tpu.runtime.lane_guard import LaneError, compile_wait
+
+    _production_wait(guard)
+    x = np.arange(4, dtype=np.int32)
+    kernel = _slow_kernel(delay=0.5)
+    with compile_wait():
+        out = guard.run(lambda: np.asarray(kernel(x)), lambda: "cpu",
+                        op="t", deadline_s=0.2)
+    np.testing.assert_array_equal(out, x + 1)
+    kernel = _slow_kernel(delay=0.5)
+    out = guard.run(lambda: np.asarray(kernel(x)), None, op="t",
+                    deadline_s=0.2)
+    np.testing.assert_array_equal(out, x + 1)
+    st = guard.state()
+    assert st["compile_behind"] == st["deadline_abandons"] == 0
+    assert st["device_failures"] == st["retries"] == 0
+
+    def wedges_after_compile():
+        kernel(x)
+        time.sleep(5)
+
+    with compile_wait():
+        assert guard.run(wedges_after_compile, lambda: "cpu", op="t",
+                         deadline_s=0.2) == "cpu"
+    assert guard.state()["deadline_abandons"] == 1
+    guard.reset()
+
+    kernel = _slow_kernel(delay=0.6)
+    with compile_wait(0.1):
+        assert guard.run(lambda: kernel(x), lambda: "cpu", op="t") == "cpu"
+        with pytest.raises(LaneError, match="still compiling"):
+            guard.run(lambda: kernel(x), None, op="t")
+    st = guard.state()
+    assert st["compile_behind"] == st["compile_wait_timeouts"] == 2
+    assert st["fallbacks"] == st["device_failures"] == 0
+
+
+def test_compiler_error_is_an_ordinary_device_failure(guard):
+    """A program the compiler refuses fails every call of that shape
+    through the normal policy: failure totals, retry, fallback."""
+    kernel = _slow_kernel(error=RuntimeError("Mosaic refused"))
+    x = np.arange(4, dtype=np.int32)
+    assert guard.run(lambda: kernel(x), lambda: "cpu", op="t") == "cpu"
+    st = guard.state()
+    assert st["fallbacks"] == 1 and st["retries"] == 1
+    assert st["device_failures"] == 2 and st["compile_behind"] == 0
+    assert "Mosaic refused" in st["last_failure"]["error"]
+
+
+def test_nested_guard_leaves_the_compile_decision_to_the_outermost(guard):
+    _production_wait(guard)
+    kernel = _slow_kernel(delay=0.3)
+    x = np.arange(4, dtype=np.int32)
+
+    def outer_device():
+        return guard.run(lambda: np.asarray(kernel(x)),
+                         lambda: "inner-cpu", op="inner")
+
+    assert guard.run(outer_device, lambda: "outer-cpu", op="outer") \
+        == "outer-cpu"
+    assert guard.state()["compile_behind"] == 1
+
+
+def test_unguarded_callers_compile_once_on_their_own_thread():
+    """Outside a guard (a residency prime, a bench, a direct backend call)
+    the caller compiles, and concurrent callers of one program wait for
+    that one compile."""
+    import threading
+
+    from pegasus_tpu.ops.kernel import compile_report
+
+    kernel = _slow_kernel(delay=0.3)
+    x = np.arange(4, dtype=np.int32)
+    outs = []
+    threads = [threading.Thread(target=lambda: outs.append(
+        np.asarray(kernel(x)))) for _ in range(4)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert len(outs) == 4 and all((o == x + 1).all() for o in outs)
+    assert kernel._jit.lowered == 1
+    # a different input signature is a different program
+    np.testing.assert_array_equal(
+        np.asarray(kernel(np.arange(8, dtype=np.int32))),
+        np.arange(8) + 1)
+    assert kernel._jit.lowered == 2
+    report = compile_report()
+    assert report["compiled"] >= 2 and report["max_s"] >= 0.3
 
 
 # ------------------------------------------------------- fail-point verbs
@@ -329,6 +490,9 @@ def test_batched_wedged_prefetch_restacks_inline_no_hang(guard):
         runs, drs = make_partition(70 + pidx, 250)
         assert sum(d.padded_len for d in drs) <= 600
         jobs.append((runs, drs, pidx))
+    # compile the batched kernel first: the timing below is about the
+    # wedge, and a cold compile is waited for OUTSIDE the deadline
+    compact_partition_batch(jobs, opts)
     fp.cfg("compact.pipeline", "sleep(2000)")
     t0 = time.perf_counter()
     outs = compact_partition_batch(jobs, opts)
@@ -477,7 +641,8 @@ def read_guard():
     saved = READ_LANE_GUARD.config
     READ_LANE_GUARD.config = LaneGuardConfig(
         deadline_s=30.0, max_retries=1, backoff_base_s=0.001,
-        backoff_max_s=0.002, breaker_threshold=2, breaker_cooldown_s=60.0)
+        backoff_max_s=0.002, breaker_threshold=2, breaker_cooldown_s=60.0,
+        compile_wait_s=600.0)
     READ_LANE_GUARD.probe_fn = lambda: True
     READ_LANE_GUARD.reset()
     fp.setup()
@@ -600,10 +765,10 @@ def test_fail_point_lint_clean():
     assert proc.returncode == 0, proc.stderr
 
 
-def test_bench_degraded_line_carries_lane_state():
-    """bench.py JSON: the degraded line's watchdog heartbeat includes the
-    lane guard state, so BENCH_r06+ can't report a cpu-fallback run as a
-    tpu number without the counters showing it."""
+def test_bench_failure_diagnostics_carry_lane_state():
+    """bench.py: a run whose device lane wedged FAILS (non-zero, no result
+    line), and the stderr diagnostics carry the stopped child's watchdog
+    heartbeat — the surface the lane guard's totals ride out on."""
     env = dict(os.environ)
     env.update({"JAX_PLATFORMS": "cpu", "PEGASUS_BENCH_N": "20000",
                 "PEGASUS_BENCH_REPS": "1",
@@ -612,9 +777,8 @@ def test_bench_degraded_line_carries_lane_state():
     proc = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
                           capture_output=True, text=True, timeout=120,
                           env=env, cwd=REPO)
-    lines = [l for l in proc.stdout.strip().splitlines()
-             if l.startswith("{")]
-    assert proc.returncode == 0 and lines, proc.stderr[-500:]
-    line = json.loads(lines[-1])
-    assert line["value"] is None
-    assert line["detail"]["watchdog"]["wedged_at_stage"] == "device"
+    assert proc.returncode != 0
+    assert not [l for l in proc.stdout.splitlines() if l.startswith("{")]
+    diag = [json.loads(l) for l in proc.stderr.splitlines()
+            if l.startswith("{")][-1]
+    assert diag["watchdog"]["wedged_at_stage"] == "device"

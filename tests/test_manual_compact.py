@@ -112,3 +112,36 @@ def test_app_env_update_path(srv):
     srv.manual_compact_service.set_mock_now(1000)
     srv.update_app_envs({consts.MANUAL_COMPACT_ONCE_TRIGGER_TIME_KEY: "900"})
     assert srv.engine.stats()["l0_files"] == 0
+
+
+def test_env_update_compacts_in_the_background(srv, monkeypatch):
+    """An app-env update (the meta's push behind the shell's
+    `manual_compact`) returns once the env is accepted: the compaction runs
+    on its own thread, its progress readable from query_compact_state."""
+    import threading
+    import time
+
+    fill(srv)
+    srv.engine.flush()
+    svc = srv.manual_compact_service
+    gate = threading.Event()
+    real = srv.engine.manual_compact
+
+    def slow_compact(**kw):
+        gate.wait(10)
+        return real(**kw)
+
+    monkeypatch.setattr(srv.engine, "manual_compact", slow_compact)
+    trigger = str(int(time.time()) - 1)
+    t0 = time.monotonic()
+    srv.update_app_envs({consts.MANUAL_COMPACT_ONCE_TRIGGER_TIME_KEY: trigger})
+    assert time.monotonic() - t0 < 1.0   # did not wait for the compaction
+    assert svc.query_compact_state().startswith(("queued", "running"))
+    # the same env delivered again while it runs starts nothing new
+    srv.update_app_envs({consts.MANUAL_COMPACT_ONCE_TRIGGER_TIME_KEY: trigger})
+    gate.set()
+    deadline = time.monotonic() + 10
+    while not svc.query_compact_state().startswith("idle; last finish at"):
+        assert time.monotonic() < deadline, svc.query_compact_state()
+        time.sleep(0.02)
+    assert srv.engine.stats()["l0_files"] == 0

@@ -150,6 +150,30 @@ def test_shell_envs_and_manual_compact(shell):
     assert "idle" in text(out) or "running" in text(out)
 
 
+def test_shell_trigger_audit_sizes_its_timeout(shell, monkeypatch):
+    """`trigger_audit [app [timeout_s]]`: without an explicit timeout the
+    shell sizes it by the largest replica on disk (every replica folds
+    every live record into its digest), and the verdict still prints."""
+    sh, out = shell
+    sh.run_line("create audsz -p 2")
+    sh.run_line("use audsz")
+    sh.run_line("set k s v")
+    assert 5.0 <= sh._audit_timeout_s() < 6.0   # a tiny table: the 5 s floor
+    real = sh._node_command
+
+    def big_disk(node, command, args):
+        if command == "replica-disk":
+            return json.dumps({"9.0": {"sst_bytes": 400 << 20}})
+        return real(node, command, args)
+
+    monkeypatch.setattr(sh, "_node_command", big_disk)
+    assert sh._audit_timeout_s() == 105.0       # 5 s + 400 MB at 4 MB/s
+    sh.run_line("trigger_audit audsz")
+    assert "audit OK: 2 partition(s)" in text(out)
+    sh.run_line("trigger_audit audsz 30")
+    assert text(out).count("audit OK: 2 partition(s)") == 2
+
+
 def test_shell_remote_and_counters(shell, onebox):
     sh, out = shell
     sh.run_line("create cnttest -p 2")

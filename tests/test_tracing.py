@@ -233,12 +233,12 @@ def test_watchdog_idle_attribution():
 
 def test_watchdog_probe_error_is_a_failure_not_a_crash():
     def boom():
-        raise RuntimeError("tunnel reset")
+        raise RuntimeError("device reset")
 
     wd = DeviceHealthWatchdog(probe_fn=boom,
                               tracer=StageTracer(prefix="t_wd3"))
     assert wd.probe() is False
-    assert "tunnel reset" in wd.state()["last_error"]
+    assert "device reset" in wd.state()["last_error"]
 
 
 def test_watchdog_loop_heartbeats_status_file(tmp_path):

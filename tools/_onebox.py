@@ -7,8 +7,6 @@ import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 
 class Onebox:
     """In-process 1-meta/3-replica cluster with one table, cleaned up on

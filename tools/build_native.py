@@ -77,18 +77,21 @@ def _build(src: str, out: str, cc: list) -> str:
     return "rebuilt"
 
 
-def ensure(quiet: bool = False) -> dict:
+def ensure(quiet: bool = False, force: bool = False) -> dict:
     """Rebuild every stale native artifact. -> {label: status} with
     status in {fresh, rebuilt, missing-compiler, build-failed,
     missing-source}. Never raises: any failure means the pure-Python
-    twins serve (loudly, unless quiet)."""
+    twins serve (loudly, unless quiet). force=True rebuilds regardless
+    of mtimes (chip_smoke.py: the artifacts are gitignored leftovers a
+    fresh checkout does not have, and a copied tree's mtimes prove
+    nothing)."""
     statuses = {}
     for label, src, out, cc in _targets():
         if not os.path.exists(src):
             statuses[label] = "missing-source"
             continue
         try:
-            fresh = (os.path.exists(out)
+            fresh = (not force and os.path.exists(out)
                      and os.path.getmtime(out) >= os.path.getmtime(src))
         except OSError:
             fresh = False

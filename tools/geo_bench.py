@@ -18,8 +18,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 
 def main():
     n = int(os.environ.get("PEGASUS_GEOBENCH_N", 20_000))

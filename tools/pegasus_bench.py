@@ -22,8 +22,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 
 KNOWN_BENCHMARKS = ("scan_pegasus", "multisetrandom_pegasus",
                     "multigetrandom_pegasus",

@@ -43,8 +43,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 
 def expected_value(key: bytes) -> bytes:
     import hashlib
