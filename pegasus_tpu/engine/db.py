@@ -1608,6 +1608,7 @@ class LsmEngine:
         deferred=True moves the install's disk work onto the pipeline
         pool (see _install_merge_deferred)."""
         inputs = list(newer_files) + list(older_files)
+        # what is not cached loads here, one `sst_read` span a file
         input_blocks = [s.block() for s in inputs]
         mesh = self._sharded_mesh() if sharded else None
         opts = CompactOptions(
@@ -1675,8 +1676,11 @@ class LsmEngine:
         compaction lock. deferred=True swaps in memory immediately and
         moves the disk work onto the pipeline pool."""
         from ..ops.pipeline import pipeline_depth
+        from ..runtime.tracing import COMPACT_TRACER
 
-        out_blocks = _split_block(out_block, self.opts.target_file_size_bytes)
+        with COMPACT_TRACER.span("sst_split", records=out_block.n):
+            out_blocks = _split_block(out_block,
+                                      self.opts.target_file_size_bytes)
         inputs = list(newer_files) + list(older_files)
         if deferred and pipeline_depth() > 1:
             self._install_merge_deferred(inputs, out_blocks, target_level)
@@ -1688,25 +1692,28 @@ class LsmEngine:
             write_sst(path, ob, {"level": target_level,
                                  "last_flushed_decree": self._durable_decree},  #: unguarded_ok monotone watermark snapshot; the manifest (written under the lock) is authoritative
                       compression=self.opts.compression)
-            sst = SSTable(path)
+            with COMPACT_TRACER.span("sst_open"):
+                sst = SSTable(path)
             sst._block = ob  # already in memory: skip the disk re-read
             # compaction output stays device-resident for its NEXT merge
             self._device_run_budgeted(sst)
             new_ssts.append(sst)
-        with self._lock:
+        with COMPACT_TRACER.span("manifest_write"), self._lock:
             self._swap_levels_locked(inputs, new_ssts, target_level)
             self._write_manifest_locked()
-        for s in inputs:
-            # keep the loaded block cached: a reader that snapshotted this
-            # SSTable before we unlink must not re-read the dead path
-            # (ADVICE r1 medium); the object drops with its last reference.
-            # Its device columns are released NOW: the budget must see the
-            # HBM back before the object's last reference dies.
-            self._release_device_run(s)
-            try:
-                os.unlink(s.path)
-            except OSError:
-                pass
+        with COMPACT_TRACER.span("sst_unlink", records=len(inputs)):
+            for s in inputs:
+                # keep the loaded block cached: a reader that snapshotted
+                # this SSTable before we unlink must not re-read the dead
+                # path (ADVICE r1 medium); the object drops with its last
+                # reference. Its device columns are released NOW: the
+                # budget must see the HBM back before the object's last
+                # reference dies.
+                self._release_device_run(s)
+                try:
+                    os.unlink(s.path)
+                except OSError:
+                    pass
 
     def _swap_levels_locked(self, inputs, new_ssts, target_level: int):  #: requires self._lock
         """Swap the new files in and every input file out atomically —
@@ -1895,9 +1902,27 @@ class LsmEngine:
     def _manual_compact_waiting(self, bottommost, now, target_level) -> dict:
         from ..runtime.tracing import COMPACT_TRACER
 
+        # The session records the per-stage breakdown (sst_read / pack /
+        # h2d / device / gather / sst_write / manifest_write ...) into the
+        # stats the manual-compact service and shell report; it spans the
+        # whole call, so what no stage names is the call's own remainder.
+        with COMPACT_TRACER.session() as sess:
+            stats = self._manual_compact_merge(bottommost, now, target_level)
+            with COMPACT_TRACER.span("manifest_write"), self._lock:
+                # under the engine lock: concurrent writers update _meta's
+                # decree key through write()/write_batch() (caught by
+                # tools/analyze lock_discipline)
+                self._meta[META_LAST_MANUAL_COMPACT_FINISH_TIME] = \
+                    int(time.time())
+                self._write_manifest_locked()
+        if stats is None:   # nothing to merge
+            return {"input_records": 0, "output_records": 0, "dropped": 0}
+        return dict(stats, trace=sess.summary())
+
+    def _manual_compact_merge(self, bottommost, now, target_level):
+        """-> the merge's stats, or None when the engine holds no file."""
         self.flush()
         tl = target_level or self.opts.max_levels
-        stats = {"input_records": 0, "output_records": 0, "dropped": 0}
         with self._compaction_lock:
             with self._lock:
                 newer = list(self._l0)
@@ -1905,33 +1930,20 @@ class LsmEngine:
                     if lv < tl:
                         newer.extend(self._levels.get(lv, []))
                 older = list(self._levels.get(tl, []))
-            if newer or older:
-                # inputs stay visible to readers until _merge_to_level swaps
-                # the output in; a failed merge leaves the levels untouched.
-                # The session records the per-stage breakdown (pack / h2d /
-                # device / gather / sst_write) into the stats the manual-
-                # compact service and shell report.
-                gated = self.opts.backend == "tpu"
-                if gated:  # device-compaction concurrency accounting
-                    SCHED_GATE.enter()
-                try:
-                    with COMPACT_TRACER.session() as sess:
-                        stats = self._merge_to_level(newer, older,
-                                                     target_level=tl,
-                                                     bottommost=bottommost,
-                                                     now=now, sharded=True)
-                finally:
-                    if gated:
-                        SCHED_GATE.exit()
-                stats = dict(stats, trace=sess.summary())
-        with self._lock:
-            # under the engine lock: concurrent writers update _meta's
-            # decree key through write()/write_batch() (caught by
-            # tools/analyze lock_discipline)
-            self._meta[META_LAST_MANUAL_COMPACT_FINISH_TIME] = \
-                int(time.time())
-            self._write_manifest_locked()
-        return stats
+            if not (newer or older):
+                return None
+            # inputs stay visible to readers until _merge_to_level swaps
+            # the output in; a failed merge leaves the levels untouched
+            gated = self.opts.backend == "tpu"
+            if gated:  # device-compaction concurrency accounting
+                SCHED_GATE.enter()
+            try:
+                return self._merge_to_level(newer, older, target_level=tl,
+                                            bottommost=bottommost,
+                                            now=now, sharded=True)
+            finally:
+                if gated:
+                    SCHED_GATE.exit()
 
     def install_ingested_block(self, block: KVBlock) -> None:
         """Bulk-load install: a sorted, deduped block becomes a fresh L0 run

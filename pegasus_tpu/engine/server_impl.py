@@ -96,6 +96,11 @@ class _ReadCoalescer:
 
     MAX_LEADER_ROUNDS = 4
 
+    @staticmethod
+    def _wait_span():
+        """The time a caller is parked behind another leader's drain."""
+        return REQUEST_TRACER.span("read.coalesce_wait")
+
     def __init__(self, engine, max_batch: int = None):
         self.engine = engine
         self.max_batch = max_batch if max_batch is not None else \
@@ -145,6 +150,7 @@ class _ReadCoalescer:
         the round where every OWN slot is done, hand off on exit."""
         with self._lock:
             self._queue.extend(slots)
+        wait = self._wait_span()   # one span however often it parks
         while not all(s.done for s in slots):
             pending = next(s for s in slots if not s.done)
             with self._lock:
@@ -152,6 +158,7 @@ class _ReadCoalescer:
                 if lead:
                     self._draining = True
             if not lead:
+                wait.begin()
                 # parked; the bounded wait re-checks so a relinquished
                 # (or dead) leader's leftover queue gets a new leader.
                 # A poke without a result (leader handoff) clears the
@@ -161,6 +168,7 @@ class _ReadCoalescer:
                 if not pending.done:
                     pending.event.clear()
                 continue
+            wait.end()
             try:
                 rounds = 0
                 while True:
@@ -182,6 +190,7 @@ class _ReadCoalescer:
                         # slot so relinquished work doesn't wait out a
                         # 50ms poll tick
                         self._queue[0].event.set()
+        wait.end()
 
     def _serve(self, batch) -> None:
         self._c_batch_size.set(len(batch))
@@ -206,6 +215,10 @@ class _RangeCoalescer(_ReadCoalescer):
     Reverse ranges skip the queue entirely: the engine serves them
     host-side (and counts them in read.range.reverse_host_count) anyway,
     so there is nothing to share."""
+
+    @staticmethod
+    def _wait_span():
+        return REQUEST_TRACER.span("read.range.coalesce_wait")
 
     def __init__(self, engine, max_batch: int = None):
         super().__init__(engine, max_batch)
@@ -601,7 +614,8 @@ class PegasusServer:
         now = epoch_now() if now is None else now
         resp = msg.ReadResponse(app_id=self.app_id, partition_index=self.pidx,
                                 server=self.server)
-        raw = self._read_coalescer.get(key, now)
+        with REQUEST_TRACER.span("engine.get"):
+            raw = self._read_coalescer.get(key, now)
         if raw is None:
             resp.error = Status.NOT_FOUND
         else:
@@ -631,7 +645,8 @@ class PegasusServer:
         storage operation)."""
         t0 = time.perf_counter()
         now = epoch_now() if now is None else now
-        raws = self._read_coalescer.get_many(keys, now)
+        with REQUEST_TRACER.span("engine.get", batch=len(keys)):
+            raws = self._read_coalescer.get_many(keys, now)
         out = []
         elapsed_us = int((time.perf_counter() - t0) * 1e6)
         for key, raw in zip(keys, raws):

@@ -427,9 +427,14 @@ class SSTable:
     def block(self) -> KVBlock:
         if self._block is None:
             from ..runtime.perf_counters import counters
+            from ..runtime.tracing import COMPACT_TRACER
 
             counters.rate("engine.sst_block_load").increment()
-            self._block, _ = read_sst(self.path)
+            # the one place a file's data is loaded: read (or mmap) + crc
+            # of every section — a merge's inputs, a read's first touch
+            with COMPACT_TRACER.span("sst_read", records=self.n,
+                                     nbytes=self.data_bytes):
+                self._block, _ = read_sst(self.path)
         return self._block
 
     def maybe_contains(self, key: bytes) -> bool:

@@ -355,30 +355,33 @@ def pack_run_device(block, prefix_u32: int = DEFAULT_PREFIX_U32,
         _C_LONG_KEY_BYPASS.increment()
         return None
     padded = _pow2ceil(block.n, _MIN_BUCKET)
-    pref = pack_key_prefixes(block.key_arena, block.key_off, block.key_len, w)
-
-    def zpad(a):
-        out = np.zeros(padded, dtype=a.dtype)
-        out[: len(a)] = a
-        return jnp.asarray(out)
-
-    cols = tuple(jnp.asarray(_pad_to(np.ascontiguousarray(pref[:, j]), padded))
-                 for j in range(w))
-    klen = jnp.asarray(_pad_to(block.key_len.astype(np.uint32), padded))
-    val2d, vl0 = None, 0
-    if with_values:
-        uni = block.uniform_layout()
-        if uni is not None:
-            vl0 = uni[1]
-            rows = np.zeros((padded, vl0), np.uint8)
-            rows[: block.n] = block.val_arena.reshape(block.n, vl0)
-            val2d = jnp.asarray(rows)
-    dr = DeviceRun(
-        cols=cols, klen=klen,
-        expire=zpad(block.expire_ts),
-        deleted=zpad(block.deleted),
-        hash32=zpad(block.hash32),
-        n=block.n, padded_len=padded, w=w, val2d=val2d, vl0=vl0)
+    # the two stages of a prime, as the host-packed lane names them:
+    # every host array first, then every upload
+    with _TRACE.span("pack", records=block.n):
+        pref = pack_key_prefixes(block.key_arena, block.key_off,
+                                 block.key_len, w)
+        host_cols = [_pad_to(np.ascontiguousarray(pref[:, j]), padded)
+                     for j in range(w)]
+        host_klen = _pad_to(block.key_len.astype(np.uint32), padded)
+        host_aux = [_zpad_to(a, padded) for a in
+                    (block.expire_ts, block.deleted, block.hash32)]
+        rows, vl0 = None, 0
+        if with_values:
+            uni = block.uniform_layout()
+            if uni is not None:
+                vl0 = uni[1]
+                rows = np.zeros((padded, vl0), np.uint8)
+                rows[: block.n] = block.val_arena.reshape(block.n, vl0)
+    with _TRACE.span("h2d", records=block.n) as sp:
+        cols = tuple(jnp.asarray(c) for c in host_cols)
+        klen = jnp.asarray(host_klen)
+        expire, deleted, hash32 = (jnp.asarray(a) for a in host_aux)
+        val2d = jnp.asarray(rows) if rows is not None else None
+        dr = DeviceRun(
+            cols=cols, klen=klen, expire=expire, deleted=deleted,
+            hash32=hash32, n=block.n, padded_len=padded, w=w, val2d=val2d,
+            vl0=vl0)
+        sp["bytes"] = dr.nbytes()
     # read index as a byproduct of the compaction/flush prime: the sorted
     # key column is on the chip RIGHT NOW, so the fence build is one tiny
     # device gather (CompassDB's moment to build the point-read index)
@@ -698,20 +701,25 @@ def _pipeline_body(run_cols, aux_runs, padded_lens, nk, use_pallas,
     post-merge form: a key's duplicates are masked by `same` regardless of
     the newest version's filter bit, so a filtered newest still shadows
     (and drops) its older versions, exactly as before."""
+    import jax
     import jax.numpy as jnp
 
     from .device_sort import merge_two_sorted
     from .pallas_merge import merge_two_sorted_pallas
 
+    # the named scopes are op metadata only (a profile's device ops carry
+    # them); the compile cache keys on the IR with debug info stripped
     items = []
     for i, rc in enumerate(run_cols):
         *kcols, klen, idx = rc
         expire, deleted, hash32 = aux_runs[i]
-        expired = (expire > 0) & (expire <= now)
-        stale = jnp.where(pmask > 0, (hash32 & pmask) != pidx, False)
-        filt = expired | stale | (deleted & bottommost)
-        idx = jnp.where(do_filter & filt, np.int32(-1), idx)
-        kp = (klen << jnp.uint32(8)) | jnp.uint32(i)
+        with jax.named_scope("pegasus_filter"):
+            expired = (expire > 0) & (expire <= now)
+            stale = jnp.where(pmask > 0, (hash32 & pmask) != pidx, False)
+            filt = expired | stale | (deleted & bottommost)
+            idx = jnp.where(do_filter & filt, np.int32(-1), idx)
+        with jax.named_scope("pegasus_key_build"):
+            kp = (klen << jnp.uint32(8)) | jnp.uint32(i)
         items.append((padded_lens[i], list(kcols) + [kp, idx]))
     pad_fill = tuple([_U32_MAX] * nk + [np.int32(-1)])
     while len(items) > 1:
@@ -727,19 +735,20 @@ def _pipeline_body(run_cols, aux_runs, padded_lens, nk, use_pallas,
                 merged = [c[: la + lb] for c in merged]
         items = items[2:] + [(la + lb, merged)]
     _, cols = items[0]
-    idx = cols[-1]
-    kp = cols[nk - 1]
-    key_eq_cols = cols[: nk - 1] + [kp >> jnp.uint32(8)]
-    same_tail = functools.reduce(
-        jnp.logical_and, [c[1:] == c[:-1] for c in key_eq_cols]
-    )
-    same = jnp.concatenate([jnp.zeros(1, dtype=bool), same_tail])
-    keep = (idx >= 0) & ~same
-    n = idx.shape[0]
-    pos = jnp.cumsum(keep.astype(jnp.int32)) - 1
-    count = pos[-1] + 1
-    tgt = jnp.where(keep, pos, n)
-    out_idx = jnp.full((n,), -1, jnp.int32).at[tgt].set(idx, mode="drop")
+    with jax.named_scope("pegasus_dedup_compact"):
+        idx = cols[-1]
+        kp = cols[nk - 1]
+        key_eq_cols = cols[: nk - 1] + [kp >> jnp.uint32(8)]
+        same_tail = functools.reduce(
+            jnp.logical_and, [c[1:] == c[:-1] for c in key_eq_cols]
+        )
+        same = jnp.concatenate([jnp.zeros(1, dtype=bool), same_tail])
+        keep = (idx >= 0) & ~same
+        n = idx.shape[0]
+        pos = jnp.cumsum(keep.astype(jnp.int32)) - 1
+        count = pos[-1] + 1
+        tgt = jnp.where(keep, pos, n)
+        out_idx = jnp.full((n,), -1, jnp.int32).at[tgt].set(idx, mode="drop")
     return out_idx, count
 
 
@@ -790,18 +799,21 @@ def _make_cached_fn(padded_lens: tuple, run_ws: tuple, w: int,
 
     def fn(cached_runs, aux_runs, real_lens, now, pidx, pmask, bottommost,
            do_filter):
+        import jax
+
         run_cols = []
         for i, rc in enumerate(cached_runs):
             *kcols, klen = rc
-            iota = lax.iota(jnp.int32, padded_lens[i])
-            in_run = iota < real_lens[i].astype(jnp.int32)
-            # pads must keep 0xFF keys even in synthesized zero lanes, and
-            # a real record whose cached klen pad says 0xFF cannot occur
-            # (in_run covers exactly the packed rows)
-            for _ in range(w - run_ws[i]):
-                kcols.append(jnp.where(in_run, jnp.uint32(0), _U32_MAX))
-            gidx = jnp.where(in_run, iota + np.int32(padded_offsets[i]),
-                             np.int32(-1))
+            with jax.named_scope("pegasus_key_build"):
+                iota = lax.iota(jnp.int32, padded_lens[i])
+                in_run = iota < real_lens[i].astype(jnp.int32)
+                # pads must keep 0xFF keys even in synthesized zero lanes,
+                # and a real record whose cached klen pad says 0xFF cannot
+                # occur (in_run covers exactly the packed rows)
+                for _ in range(w - run_ws[i]):
+                    kcols.append(jnp.where(in_run, jnp.uint32(0), _U32_MAX))
+                gidx = jnp.where(in_run, iota + np.int32(padded_offsets[i]),
+                                 np.int32(-1))
             run_cols.append(tuple(kcols + [klen, gidx]))
         # aux_runs are already per-run ROW-aligned padded columns — exactly
         # what the pre-merge filter fold consumes (pad rows carry zeros,
@@ -892,6 +904,15 @@ def get_backend(name: str):
     return _BACKENDS[name]
 
 
+def _concat(runs) -> KVBlock:
+    """The runs as ONE block for the survivor gather to index: a copy of
+    every arena when there is more than one run."""
+    if len(runs) == 1:
+        return runs[0]
+    with _TRACE.span("concat", records=sum(b.n for b in runs)):
+        return KVBlock.concat(runs)
+
+
 def compact_blocks(blocks, opts: CompactOptions,
                    device_runs=None) -> CompactResult:
     """Merge K runs (newest first) into one sorted, deduped, filtered block.
@@ -950,14 +971,14 @@ def _compact_blocks_impl(blocks, opts: CompactOptions,
     def _cpu_lane() -> KVBlock:
         packed = pack_runs(runs, opts, need_sbytes=True)
         survivors = get_backend("cpu").survivors(packed, *fargs)
-        concat = runs[0] if len(runs) == 1 else KVBlock.concat(runs)
+        concat = _concat(runs)
         with _TRACE.span("gather", records=len(survivors)):
             return concat.gather(survivors)
 
     def _device_lane() -> KVBlock:
         if (device_runs is not None and len(device_runs) == len(runs)
                 and all(d is not None for d in device_runs)):
-            concat = runs[0] if len(runs) == 1 else KVBlock.concat(runs)
+            concat = _concat(runs)
             # cheap checks first: uniform_layout() is four O(n) reductions,
             # wasted work whenever value residency is off (the default)
             vl0s = {d.vl0 for d in device_runs} \
@@ -974,8 +995,7 @@ def _compact_blocks_impl(blocks, opts: CompactOptions,
             return gather_device_survivors(concat, dev_idx, count)
         packed = pack_runs(runs, opts, need_sbytes=False)
         dev_idx, count = backend.survivors_device(packed, *fargs)
-        concat = runs[0] if len(runs) == 1 else KVBlock.concat(runs)
-        return gather_device_survivors(concat, dev_idx, count)
+        return gather_device_survivors(_concat(runs), dev_idx, count)
 
     if backend.name == "tpu":
         # the lane guard owns every device failure mode: deadline-abandoned

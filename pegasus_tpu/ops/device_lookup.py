@@ -80,6 +80,16 @@ def _fence_lower_bound(jnp, lex_less, padded_len, w, fence_len, steps,
     for queries LONGER than the 4*w-byte window: such a query's lane
     image ties only with rows that are proper byte prefixes of it, and
     the klen tiebreak orders those below the query, same as bytes."""
+    import jax
+
+    with jax.named_scope("pegasus_fence_lower_bound"):
+        return _fence_lower_bound_body(jnp, lex_less, padded_len, w,
+                                       fence_len, steps, cols, klen, fence,
+                                       n, step, qcols, qklen)
+
+
+def _fence_lower_bound_body(jnp, lex_less, padded_len, w, fence_len, steps,
+                            cols, klen, fence, n, step, qcols, qklen):
     q0 = qcols[0]
     # fence window: rows before sample a-1 are < q0, rows from sample
     # b on are > q0, so the full-key lower_bound lies in [lo, hi)
@@ -157,15 +167,18 @@ def _compiled_lookup(padded_len: int, w: int, fence_len: int, qpad: int):
     steps = max(1, padded_len.bit_length())
 
     def fn(cols, klen, fence, n, step, qcols, qklen):
+        import jax
+
         lo = _fence_lower_bound(jnp, lex_less, padded_len, w, fence_len,
                                 steps, cols, klen, fence, n, step,
                                 qcols, qklen)
-        safe = jnp.minimum(lo, padded_len - 1)
-        eq = lo < n
-        for j in range(w):
-            eq &= jnp.take(cols[j], safe) == qcols[j]
-        eq &= jnp.take(klen, safe) == qklen
-        return jnp.where(eq, lo, jnp.int32(-1))
+        with jax.named_scope("pegasus_lookup_match"):
+            safe = jnp.minimum(lo, padded_len - 1)
+            eq = lo < n
+            for j in range(w):
+                eq &= jnp.take(cols[j], safe) == qcols[j]
+            eq &= jnp.take(klen, safe) == qklen
+            return jnp.where(eq, lo, jnp.int32(-1))
 
     return DeviceKernel(fn, "lookup")
 

@@ -143,23 +143,26 @@ def merge_network(cols, nk):
     log2(L) stages. This is the compaction hot path: two sorted runs
     become bitonic via concat(A, reverse(B)) (pad in the middle stays
     bitonic). L must be a power of two."""
+    import jax
     from jax import lax
 
     L = cols[0].shape[0]
     if L & (L - 1):
         raise ValueError(f"merge_network needs power-of-two length, got {L}")
-    iota = lax.iota(np.uint32, L)
-    j = L // 2
-    while j >= 1:
-        is_high = (iota & np.uint32(j)) != 0
-        cols = _exchange(cols, nk, j, is_high)
-        j //= 2
+    with jax.named_scope("pegasus_merge_network"):
+        iota = lax.iota(np.uint32, L)
+        j = L // 2
+        while j >= 1:
+            is_high = (iota & np.uint32(j)) != 0
+            cols = _exchange(cols, nk, j, is_high)
+            j //= 2
     return cols
 
 
 def sort_network(cols, nk):
     """Full bitonic sort, ascending. log2(L)*(log2(L)+1)/2 stages; used for
     unsorted single runs (flush). L must be a power of two."""
+    import jax
     from jax import lax
 
     L = cols[0].shape[0]
@@ -167,17 +170,18 @@ def sort_network(cols, nk):
         raise ValueError(f"sort_network needs power-of-two length, got {L}")
     if L == 1:
         return list(cols)
-    iota = lax.iota(np.uint32, L)
-    k = 2
-    while k <= L:
-        is_desc = (iota & np.uint32(k)) != 0 if k < L else None
-        j = k // 2
-        while j >= 1:
-            is_high = (iota & np.uint32(j)) != 0
-            flip = is_high if is_desc is None else is_high ^ is_desc
-            cols = _exchange(cols, nk, j, flip)
-            j //= 2
-        k *= 2
+    with jax.named_scope("pegasus_sort_network"):
+        iota = lax.iota(np.uint32, L)
+        k = 2
+        while k <= L:
+            is_desc = (iota & np.uint32(k)) != 0 if k < L else None
+            j = k // 2
+            while j >= 1:
+                is_high = (iota & np.uint32(j)) != 0
+                flip = is_high if is_desc is None else is_high ^ is_desc
+                cols = _exchange(cols, nk, j, flip)
+                j //= 2
+            k *= 2
     return cols
 
 
@@ -187,6 +191,7 @@ def merge_two_sorted(a_cols, b_cols, nk, pad_fill):
     sort after all real rows) is inserted between the ascending and the
     reversed descending half, which preserves bitonicity; pads sort to the
     tail. Returns padded merged columns (caller trims to la + lb)."""
+    import jax
     import jax.numpy as jnp
 
     la, lb = a_cols[0].shape[0], b_cols[0].shape[0]
@@ -195,7 +200,8 @@ def merge_two_sorted(a_cols, b_cols, nk, pad_fill):
         L <<= 1
     npad = L - la - lb
     merged = []
-    for a, b, fill in zip(a_cols, b_cols, pad_fill):
-        mid = jnp.full((npad,), fill, dtype=a.dtype)
-        merged.append(jnp.concatenate([a, mid, b[::-1]]))
+    with jax.named_scope("pegasus_bitonic_concat"):
+        for a, b, fill in zip(a_cols, b_cols, pad_fill):
+            mid = jnp.full((npad,), fill, dtype=a.dtype)
+            merged.append(jnp.concatenate([a, mid, b[::-1]]))
     return merge_network(merged, nk)

@@ -193,16 +193,21 @@ class MutationLog:
         active leader claims everything buffered and lands it as one
         group; appenders that arrive while it writes buffer into the NEXT
         group. A follower whose entry is still unclaimed after _stall_s
-        steals it back and degrades to the per-append path."""
+        steals it back and degrades to the per-append path. The time an
+        appender waits for a leader (until its entry is durable, it leads
+        itself, or it gives up) is ONE `plog.group_wait` span."""
         with self._gcv:
             self._gbuf.append(entry)
             self._gcv.notify_all()  # wake a lingering leader
+        wait = REQUEST_TRACER.span("plog.group_wait")
         while True:
             fallback = False
             with self._gcv:
                 if entry.done:
+                    wait.end()
                     return
                 if self._gleader:
+                    wait.begin()
                     if self._gcv.wait(self._stall_s):
                         continue
                     if entry not in self._gbuf:
@@ -215,9 +220,13 @@ class MutationLog:
                 else:
                     self._gleader = True
                     batch = self._claim_locked([])
+            wait.end()
             if fallback:
                 counters.rate("plog.group.fallback_count").increment()
-                self._write_group([entry])
+                # one close per degrade: stage.plog.group_fallback.n is
+                # the count the rate above never was
+                with REQUEST_TRACER.span("plog.group_fallback"):
+                    self._write_group([entry])
                 return
             # ---- leader, outside the cv: stragglers queue for next group
             try:
@@ -270,7 +279,9 @@ class MutationLog:
         n_frames = sum(len(b.frames) for b in batch)
         blob = b"".join(f for b in batch for f in b.frames)
         first_decree = batch[0].decrees[0]
-        with self._lock:
+        # one close per flush: stage.plog.flush.n IS the flush count
+        with REQUEST_TRACER.span("plog.flush", bytes=len(blob),
+                                 batch=n_frames), self._lock:
             if self._file is None or self._file_bytes >= self.segment_bytes:
                 self._roll_locked(first_decree)
             self._file.write(blob)

@@ -221,11 +221,15 @@ class Replica:
         slot = _WriteSlot(code, req)
         with self._batch_cv:
             self._batch_pending.append(slot)
+        # this writer's wait for a window leader: one span however often
+        # the loop turns
+        wait = REQUEST_TRACER.span("replica.window_wait")
         while True:
             with self._batch_cv:
                 if slot.done:
                     break
                 if self._batch_leader_active:
+                    wait.begin()
                     # handoff is notify-driven (the leader's finally block
                     # notify_all's); the timeout is only a defensive bound,
                     # not a polling cadence (ADVICE r2 weak: 50ms poll)
@@ -234,6 +238,7 @@ class Replica:
                 self._batch_leader_active = True
                 batch = self._batch_pending
                 self._batch_pending = []
+            wait.end()
             # this thread leads one window commit (outside the cv so
             # arriving writers can queue for the NEXT window meanwhile)
             try:
@@ -250,6 +255,7 @@ class Replica:
                     for s in batch:
                         s.done = True
                     self._batch_cv.notify_all()
+        wait.end()
         if slot.err is not None:
             raise slot.err
         return slot.resp

@@ -444,6 +444,7 @@ class RpcServer:
         self._pool.shutdown(wait=False)
 
     def _dispatch(self, sock, wlock, header: RpcHeader, body: bytes) -> None:
+        parsed = time.perf_counter()   # rpc.queue starts here
         # serve.dispatch: the chaos seam for a wedged group executor —
         # sleep(ms) stalls this connection's whole dispatch loop (frames
         # queue in the kernel buffer, the client's timeout is the bound),
@@ -474,7 +475,7 @@ class RpcServer:
                 # liveness escape: replication/lifecycle must never queue
                 # behind a pool full of work that is WAITING on them
                 spawn_thread(self._serve_one, sock, wlock, header, body,
-                             daemon=True)
+                             parsed, daemon=True)
                 return
         with self._busy_lock:
             self._busy += 1
@@ -482,30 +483,35 @@ class RpcServer:
         if depth > 0:
             self._depth_gauge.set(depth)
         try:
-            self._pool.submit(self._serve_pooled, sock, wlock, header, body)
+            self._pool.submit(self._serve_pooled, sock, wlock, header, body,
+                              parsed)
         except RuntimeError:   # server stopping: pool already shut down
             with self._busy_lock:
                 self._busy -= 1
 
-    def _serve_pooled(self, sock, wlock, header, body) -> None:
+    def _serve_pooled(self, sock, wlock, header, body, parsed) -> None:
         try:
-            self._serve_one(sock, wlock, header, body)
+            self._serve_one(sock, wlock, header, body, parsed)
         finally:
             with self._busy_lock:
                 self._busy -= 1
                 depth = self._busy - self.POOL_WORKERS
             self._depth_gauge.set(max(0, depth))
 
-    def _serve_one(self, sock, wlock, header: RpcHeader, body: bytes) -> None:
+    def _serve_one(self, sock, wlock, header: RpcHeader, body: bytes,
+                   parsed: float) -> None:
         resp = RpcHeader(seq=header.seq, code=header.code, is_response=True)
         out = b""
         t0 = time.perf_counter()
         # adopt the caller's trace context for the handler's whole stack
-        # (replication, plog, engine spans all land in the same trace)
-        scope = (REQUEST_TRACER.serve(
-            TraceContext(header.trace_id, header.trace_sampled, remote=True),
-            header.code) if header.trace_id else nullcontext())
-        with scope:
+        # (replication, plog, engine spans all land in the same trace); a
+        # frame without an id still closes rpc.server.<code> for the
+        # stage totals
+        ctx = (TraceContext(header.trace_id, header.trace_sampled,
+                            remote=True) if header.trace_id else None)
+        with REQUEST_TRACER.serve(ctx, header.code):
+            # the frame's wait for this thread, recorded in its trace
+            REQUEST_TRACER.event("rpc.queue", int((t0 - parsed) * 1e6))
             try:
                 fn = self._handlers.get(header.code)
                 if fn is None:
@@ -526,10 +532,14 @@ class RpcServer:
             int((time.perf_counter() - t0) * 1e6))
         if resp.error:
             counters.rate("rpc.server.error_count").increment()
-        try:
-            _send_frame(sock, resp, out, lock=wlock)
-        except (ConnectionError, OSError):
-            pass
+        # encode + write, after the handler's span: in a onebox the
+        # client's trace is still open and takes the record, a remote
+        # view has finalized and only the totals move
+        with REQUEST_TRACER.span_in(ctx, "rpc.reply", bytes=len(out)):
+            try:
+                _send_frame(sock, resp, out, lock=wlock)
+            except (ConnectionError, OSError):
+                pass
 
     def _dispatch_batch(self, sock, wlock, code: str, frames) -> None:
         """Dispatch a hot-code batch the reader coalesced: ONE pool task,
@@ -580,28 +590,35 @@ class RpcServer:
             self._depth_gauge.set(depth)
         try:
             self._pool.submit(self._serve_batch_pooled, sock, wlock, code,
-                              frames)
+                              frames, time.perf_counter())
         except RuntimeError:   # server stopping: pool already shut down
             with self._busy_lock:
                 self._busy -= 1
 
-    def _serve_batch_pooled(self, sock, wlock, code, frames) -> None:
+    def _serve_batch_pooled(self, sock, wlock, code, frames,
+                            parsed) -> None:
         try:
-            self._serve_batch(sock, wlock, code, frames)
+            self._serve_batch(sock, wlock, code, frames, parsed)
         finally:
             with self._busy_lock:
                 self._busy -= 1
                 depth = self._busy - self.POOL_WORKERS
             self._depth_gauge.set(max(0, depth))
 
-    def _serve_batch(self, sock, wlock, code: str, frames) -> None:
+    def _serve_batch(self, sock, wlock, code: str, frames,
+                     parsed: float) -> None:
         t0 = time.perf_counter()
+        REQUEST_TRACER.event("rpc.queue", int((t0 - parsed) * 1e6),
+                             batch=len(frames))
         headers = [h for h, _ in frames]
         bodies = [b for _, b in frames]
-        try:
-            results = self._batch_handlers[code](headers, bodies)
-        except Exception as e:  # handler bug -> errors, not a dead conn
-            results = [e] * len(frames)
+        # a batch is ONE dispatch: one rpc.server.<code> close for all of
+        # its frames (untraced by construction, see _dispatch_batch)
+        with REQUEST_TRACER.serve(None, code):
+            try:
+                results = self._batch_handlers[code](headers, bodies)
+            except Exception as e:  # handler bug -> errors, not a dead conn
+                results = [e] * len(frames)
         pairs, n_err = [], 0
         for header, res in zip(headers, results):
             resp = RpcHeader(seq=header.seq, code=header.code,
@@ -625,10 +642,11 @@ class RpcServer:
             lat.set(elapsed)
         if n_err:
             counters.rate("rpc.server.error_count").increment(n_err)
-        try:
-            _send_frames(sock, pairs, lock=wlock)
-        except (ConnectionError, OSError):
-            pass
+        with REQUEST_TRACER.span("rpc.reply", batch=len(frames)):
+            try:
+                _send_frames(sock, pairs, lock=wlock)
+            except (ConnectionError, OSError):
+                pass
 
 
 class RpcConnection:
@@ -843,8 +861,11 @@ class ConnectionPool:
         # connect OUTSIDE the pool lock: a black-holed peer blocks
         # create_connection for its full timeout, and holding the pool-wide
         # lock through that would serialize every other caller (including
-        # the replication write path) behind one dead host
-        fresh = RpcConnection(addr, shard=shard)
+        # the replication write path) behind one dead host. The connect is
+        # a wait of its own: a SYN the listener's backlog dropped comes
+        # back a second later, inside whatever call needed the connection
+        with REQUEST_TRACER.span("rpc.connect"):
+            fresh = RpcConnection(addr, shard=shard)
         with self._lock:
             cur = self._conns.get(key)
             if cur is not None and not cur._dead and cur is not conn:

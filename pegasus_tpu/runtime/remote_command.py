@@ -56,6 +56,8 @@ class RemoteCommandService:
         self.register("device-health", self._cmd_device_health)
         self.register("request-trace-dump", self._cmd_request_trace_dump)
         self.register("slow-requests", self._cmd_slow_requests)
+        self.register("profile-start", self._cmd_profile_start)
+        self.register("profile-stop", self._cmd_profile_stop)
         self.register("job-trace", self._cmd_job_trace)
         self.register("table-stats", self._cmd_table_stats)
         self.register("slo-status", self._cmd_slo_status)
@@ -153,6 +155,53 @@ class RemoteCommandService:
         return json.dumps(
             REQUEST_TRACER.slow_requests(int(args[0]) if args else 50),
             indent=1)
+
+    @staticmethod
+    def _cmd_profile_start(args) -> str:
+        """profile-start <dir> — start jax's profiler in THIS process (the
+        one that holds the chip): device lines plus the host's TraceMe
+        spans, among them every open span of runtime/tracing.py as
+        `pegasus:<name>`. The Python tracer stays off (it slows the host
+        and swells the trace). View <dir> with xprof / Perfetto."""
+        if len(args) != 1:
+            return "usage: profile-start <dir>"
+        import sys
+
+        if "jax" not in sys.modules:
+            return "no profile: this process has not loaded jax " \
+                   "(no device backend is open here)"
+        import jax
+
+        from .tracing import annotate_requests
+
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        try:
+            jax.profiler.start_trace(args[0], profiler_options=opts)
+        except Exception as e:  # noqa: BLE001 - e.g. a profile already runs
+            return f"profile-start failed: {e!r}"
+        annotate_requests(True)   # request spans join the stage spans
+        return f"profiling into {args[0]}"
+
+    @staticmethod
+    def _cmd_profile_stop(args) -> str:
+        """profile-stop — stop the profile profile-start began and write
+        it out (takes as long as the trace is large)."""
+        import sys
+
+        if "jax" not in sys.modules:
+            return "no profile: this process has not loaded jax"
+        import jax
+
+        from .tracing import annotate_requests
+
+        annotate_requests(False)
+        try:
+            jax.profiler.stop_trace()
+        except Exception as e:  # noqa: BLE001 - e.g. no profile runs
+            return f"profile-stop failed: {e!r}"
+        return "profile written"
 
     @staticmethod
     def _cmd_job_trace(args) -> str:

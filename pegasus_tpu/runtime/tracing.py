@@ -30,13 +30,118 @@ it (the `trace` detail in BENCH_*.json).
 """
 
 import collections
+import heapq
 import os
 import random
+import sys
 import threading
 import time
 from contextlib import contextmanager
 
 from .perf_counters import counters
+
+# ============================================================ stage spine
+#
+# What the two tracers below share (ISSUE 27). Every span either of them
+# closes adds to three monotone `number` counters, `stage.<name>.n`
+# (closes), `.us` (duration) and `.self_us` (duration minus the spans
+# closed inside it IN THE SAME THREAD), so any stage can be differenced
+# over a window through `perf-counters-by-prefix stage.` or /metrics —
+# the rings and ledgers below keep individual spans, these keep the sums.
+# One thread-local stack of open spans serves both tracers, so a
+# `read.device` stage span inside an `engine.get` request span subtracts
+# from it; a span closed by ANOTHER thread (a lane-guard worker, the
+# prepare fan-out) never does: its caller's self time is then the wait.
+# While a span is open it also holds a `jax.profiler.TraceAnnotation`
+# named `pegasus:<name>`, so a profile of the chip-holding process shows
+# the program's stages on /host:CPU beside the device's lines. The class
+# is taken from sys.modules: a process that has not imported jax (the
+# clients, the shell) never imports it for this. Stage spans (a few
+# hundred a second at most) always hold one; request spans (some twenty
+# an update: 13,000 a second on a busy node) hold one only between the
+# `profile-start` and `profile-stop` remote commands, which call
+# annotate_requests() — a trace somebody else takes of this process is
+# not swollen thirtyfold by them.
+
+_SPINE = threading.local()   # .stack: [[name, child_us], ...] innermost LAST
+_STAGES = {}    # span name -> (lock, n, us, self_us, "pegasus:<name>", name)
+_STAGES_LOCK = threading.Lock()
+_ANNOTATION = None           # jax.profiler.TraceAnnotation, once jax is loaded
+_ANNOTATE_REQUESTS = False   # see annotate_requests()
+
+
+def annotate_requests(on: bool) -> None:
+    """Whether RequestTracer spans hold a profiler annotation while open
+    (runtime/remote_command.py profile-start / profile-stop)."""
+    global _ANNOTATE_REQUESTS
+    _ANNOTATE_REQUESTS = bool(on)
+
+
+def _stage(name: str) -> tuple:
+    """The three counters of one span name, resolved once and kept: a
+    span close formats no string and takes no registry lock."""
+    st = _STAGES.get(name)
+    if st is None:
+        with _STAGES_LOCK:
+            st = _STAGES.get(name)
+            if st is None:
+                base = "stage." + name
+                st = _STAGES[name] = (threading.Lock(),
+                                      counters.number(base + ".n"),
+                                      counters.number(base + ".us"),
+                                      counters.number(base + ".self_us"),
+                                      "pegasus:" + name, name)
+    return st
+
+
+def _find_annotation():
+    global _ANNOTATION
+    cls = getattr(getattr(sys.modules.get("jax"), "profiler", None),
+                  "TraceAnnotation", None)
+    if cls is not None:
+        _ANNOTATION = cls
+    return cls
+
+
+def _add_totals(st: tuple, dur_us: int, child_us: int = 0) -> None:
+    """The one place a closed span reaches the `stage.` counters: three
+    adds under the stage's ONE lock (nothing else writes these counters,
+    so their own locks are not taken: a third of the cost on a path that
+    closes some thirty spans an update)."""
+    with st[0]:
+        st[1]._value += 1
+        st[2]._value += dur_us
+        st[3]._value += dur_us - child_us if dur_us > child_us else 0
+
+
+def _open(st: tuple, annotate: bool = True) -> tuple:
+    """Open the span of stage `st` in this thread -> the token _close
+    takes."""
+    try:
+        stack = _SPINE.stack
+    except AttributeError:
+        stack = _SPINE.stack = []
+    parent = stack[-1][0] if stack else ""
+    stack.append([st[5], 0])
+    cls = (_ANNOTATION or _find_annotation()) if annotate else None
+    ann = None
+    if cls is not None:
+        ann = cls(st[4])
+        ann.__enter__()
+    return st, stack, parent, ann, time.perf_counter()
+
+
+def _close(tok: tuple) -> tuple:
+    """-> (duration_us, the enclosing span's name or "")."""
+    st, stack, parent, ann, t0 = tok
+    dur_us = int((time.perf_counter() - t0) * 1e6)
+    if ann is not None:
+        ann.__exit__(None, None, None)
+    child_us = stack.pop()[1]
+    if stack:
+        stack[-1][1] += dur_us
+    _add_totals(st, dur_us, child_us)
+    return dur_us, parent
 
 
 class TraceSession:
@@ -79,6 +184,7 @@ class StageTracer:
         # shared (not thread-local) so the watchdog thread can read which
         # stage another thread is currently stuck in
         self._open = {}
+        self._exports = {}   # stage -> its four registry counters
 
     # ----------------------------------------------------------- span API
 
@@ -106,12 +212,12 @@ class StageTracer:
         with self._lock:
             self._open.setdefault(tid, []).append((stage, time.time()))
         box = {"records": records, "bytes": nbytes}
-        t0 = time.perf_counter()
         c0 = time.process_time()
+        tok = _open(_stage(stage))
         try:
             yield box
         finally:
-            dur_s = time.perf_counter() - t0
+            dur_s = _close(tok)[0] / 1e6
             # process (not thread) cpu time: includes concurrent threads'
             # work under the span — exactly what makes host contention
             # attributable from a recorded trace (see TraceSession.summary)
@@ -138,18 +244,30 @@ class StageTracer:
         with self._lock:
             self._spans.append((time.time(), 0, stage, dur_s, records,
                                 nbytes, 0.0))
+        # an interval computed after the fact may overlap open spans: it
+        # is all self time and subtracts from nothing
+        _add_totals(_stage(stage), int(dur_s * 1e6))
         self._export(stage, dur_s, records, nbytes)
         for sess in self._session_list():
             sess._add(stage, dur_s, records, nbytes)
 
     def _export(self, stage, dur_s, records, nbytes):
-        base = f"{self.prefix}.stage.{stage}"
-        counters.rate(f"{base}.count").increment()
-        counters.percentile(f"{base}.duration_us").set(int(dur_s * 1e6))
+        """`<prefix>.stage.<name>.*` (README; the scheduler's autotune
+        reads them), through counters resolved once per stage."""
+        ex = self._exports.get(stage)
+        if ex is None:
+            base = f"{self.prefix}.stage.{stage}"
+            ex = self._exports[stage] = (
+                counters.rate(f"{base}.count"),
+                counters.percentile(f"{base}.duration_us"),
+                counters.rate(f"{base}.records"),
+                counters.rate(f"{base}.bytes"))
+        ex[0].increment()
+        ex[1].set(int(dur_s * 1e6))
         if records:
-            counters.rate(f"{base}.records").increment(records)
+            ex[2].increment(records)
         if nbytes:
-            counters.rate(f"{base}.bytes").increment(nbytes)
+            ex[3].increment(nbytes)
 
     @contextmanager
     def session(self):
@@ -241,13 +359,16 @@ COMPACT_TRACER = StageTracer()
 #
 # Retention is two-tier:
 #   - a sampled ring buffer of completed traces (every `sample_every`-th
-#     trace; default every trace — this is a Python build, span cost is a
-#     dict append), served by GET /requests/trace and the
-#     `request-trace-dump` remote command;
+#     trace; default every trace; PEGASUS_TRACE_SAMPLE_EVERY=0 turns
+#     request traces off: no id on the wire, no record, only the stage
+#     totals), served by GET /requests/trace and the `request-trace-dump`
+#     remote command;
 #   - a slow-request ledger: ANY trace whose end-to-end duration reaches
 #     `slow_threshold_us` keeps its full stage timeline regardless of
-#     sampling — served by GET /requests/trace?slow=1 and the
-#     `slow-requests` remote command. A slow put is attributable to the
+#     sampling: the newest 256, and the slowest 32 since start, which a
+#     busy node's newest-256 would overwrite in seconds — served by GET
+#     /requests/trace?slow=1 and the `slow-requests` remote command
+#     (slowest first). A slow put is attributable to the
 #     client hop, the RPC layer, the quorum round or the engine without
 #     reproducing it.
 #
@@ -275,19 +396,83 @@ class TraceContext:
         self.remote = remote
 
 
+_THREADS_CTX = object()   # "the context installed in this thread"
+
+
+class _RequestSpan:
+    """One RequestTracer span: `with` yields its mutable attr dict. A
+    wait inside a retry loop, which cannot be a `with` block, calls
+    begin() where it first parks and end() where it stops (both may be
+    called again: the wait is ONE span however often the loop turns)."""
+
+    __slots__ = ("tr", "name", "attrs", "ctx", "st", "e", "depth", "ts",
+                 "tok")
+
+    def __init__(self, tr, name: str, attrs: dict, ctx=_THREADS_CTX):
+        self.tr, self.name, self.attrs, self.ctx = tr, name, attrs, ctx
+        # resolved here, not at the first close: a wait that never parked
+        # still publishes its (zero) totals
+        self.st = _stage(name)
+        self.tok = None
+
+    def begin(self) -> None:
+        if self.tok is None:
+            self.__enter__()
+
+    def end(self) -> None:
+        if self.tok is not None:
+            self.__exit__()
+            self.tok = None
+
+    def __enter__(self) -> dict:
+        local = self.tr._local
+        ctx = self.ctx
+        if ctx is _THREADS_CTX:
+            ctx = getattr(local, "ctx", None)
+        # a dict read is GIL-atomic: no tracer lock on the span path
+        e = self.tr._active.get(ctx.trace_id) if ctx is not None else None
+        self.e = e
+        if e is not None:
+            self.depth = getattr(local, "depth", 0)
+            local.depth = self.depth + 1
+            self.ts = time.time()
+        self.tok = _open(self.st, _ANNOTATE_REQUESTS)
+        return self.attrs
+
+    def __exit__(self, *exc) -> bool:
+        dur_us, parent = _close(self.tok)
+        e = self.e
+        if e is not None:
+            self.tr._local.depth = self.depth
+            rec = {"name": self.name, "ts": self.ts, "depth": self.depth,
+                   "parent": parent, "duration_us": dur_us}
+            rec.update(self.attrs)
+            if len(e["spans"]) < self.tr.MAX_SPANS:
+                e["spans"].append(rec)
+        return False
+
+
 class RequestTracer:
     MAX_ACTIVE = 4096       # leaked/abandoned trace guard
     MAX_SPANS = 512         # per-trace span cap (runaway scan sessions)
+    WORST = 32              # slowest traces since start, kept for good
 
     def __init__(self, capacity: int = 512, slow_capacity: int = 256):
         self._lock = threading.Lock()
         self._local = threading.local()
         self._ring = collections.deque(maxlen=capacity)
         self._slow = collections.deque(maxlen=slow_capacity)
+        # min-heap of (duration_us, seq, trace): what is being hunted
+        # outlives the newest-256 ring (a busy node overwrites that in
+        # seconds)
+        self._worst = []
+        self._worst_seq = 0
         self._active = {}   # trace_id -> open trace record
         self.slow_threshold_us = int(
             os.environ.get("PEGASUS_SLOW_REQUEST_US", "50000"))
-        self.sample_every = max(1, int(
+        # 0 = request traces off: no id on the wire, no per-trace record;
+        # the stage totals above stay
+        self.sample_every = max(0, int(
             os.environ.get("PEGASUS_TRACE_SAMPLE_EVERY", "1")))
         self._seq = 0
 
@@ -313,9 +498,10 @@ class RequestTracer:
         """Begin a trace in this thread (the CLIENT side of a request).
         Records a `client.<op>` span and finalizes the trace at exit.
         Nested client ops inside an active trace (e.g. copy_data's reads
-        feeding writes) record plain spans instead of new traces."""
+        feeding writes) record plain spans instead of new traces; so does
+        every op when sample_every is 0 (yields None: nothing to carry)."""
         prev = self.current()
-        if prev is not None:
+        if prev is not None or not self.sample_every:
             with self.span(f"client.{op}"):
                 yield prev
             return
@@ -335,11 +521,16 @@ class RequestTracer:
                            ctx.sampled)
 
     @contextmanager
-    def serve(self, ctx: TraceContext, op: str):
+    def serve(self, ctx, op: str):
         """Install a wire-propagated context for a SERVER-side handler and
         record the `rpc.server.<op>` span. When this process does not own
         the trace root, the trace's local view finalizes once its last
-        open handler returns."""
+        open handler returns. ctx None (a frame without a trace id) still
+        times the span for the stage totals."""
+        if ctx is None:
+            with self.span(f"rpc.server.{op}"):
+                yield None
+            return
         prev = self.current()
         e = self._entry(ctx.trace_id, op, root_local=False)
         with self._lock:
@@ -376,47 +567,51 @@ class RequestTracer:
         finally:
             self._local.ctx = prev
 
-    @contextmanager
-    def span(self, name: str, **attrs):
-        """Record one stage of the active trace (no-op without a context).
-        Yields the mutable attr dict so counts discovered mid-span can be
-        added before it closes."""
+    def span(self, name: str, **attrs) -> _RequestSpan:
+        """Time one stage of the serving path: always into the stage
+        totals, and into the active trace's record when this thread has a
+        context. `with` yields the mutable attr dict so counts discovered
+        mid-span can be added before it closes."""
+        return _RequestSpan(self, name, attrs)
+
+    def span_in(self, ctx, name: str, **attrs) -> _RequestSpan:
+        """span() for a context this thread no longer has installed (the
+        reply write after serve() has closed); ctx may be None."""
+        return _RequestSpan(self, name, attrs, ctx)
+
+    def event(self, name: str, dur_us: int, **attrs) -> None:
+        """A closed span measured after the fact (a frame's wait for a
+        pool thread ends where its handler starts): totals, and a record
+        in the active trace whose `ts` is the interval's start."""
+        _add_totals(_stage(name), dur_us)
         ctx = getattr(self._local, "ctx", None)
-        if ctx is None:
-            yield attrs
-            return
-        with self._lock:
-            e = self._active.get(ctx.trace_id)
-        if e is None:
-            yield attrs
-            return
-        depth = getattr(self._local, "depth", 0)
-        self._local.depth = depth + 1
-        ts = time.time()
-        t0 = time.perf_counter()
-        try:
-            yield attrs
-        finally:
-            self._local.depth = depth
-            rec = {"name": name, "ts": ts, "depth": depth,
-                   "duration_us": int((time.perf_counter() - t0) * 1e6)}
+        e = self._active.get(ctx.trace_id) if ctx is not None else None
+        if e is not None and len(e["spans"]) < self.MAX_SPANS:
+            stack = getattr(_SPINE, "stack", None)
+            rec = {"name": name, "ts": time.time() - dur_us / 1e6,
+                   "depth": getattr(self._local, "depth", 0),
+                   "parent": stack[-1][0] if stack else "",
+                   "duration_us": dur_us}
             rec.update(attrs)
-            with self._lock:
-                if len(e["spans"]) < self.MAX_SPANS:
-                    e["spans"].append(rec)
+            e["spans"].append(rec)
 
     # ---------------------------------------------------------- retention
 
     def _finalize(self, e: dict, dur_us: int, sampled: bool) -> None:
-        with self._lock:
-            self._active.pop(e["trace_id"], None)
         trace = {"trace_id": format(e["trace_id"], "016x"), "op": e["op"],
                  "ts": e["started"], "duration_us": dur_us,
                  "spans": e["spans"]}
         slow = dur_us >= self.slow_threshold_us
         with self._lock:
+            self._active.pop(e["trace_id"], None)
             if slow:
                 self._slow.append(trace)
+                self._worst_seq += 1   # ties never compare the dicts
+                item = (dur_us, self._worst_seq, trace)
+                if len(self._worst) < self.WORST:
+                    heapq.heappush(self._worst, item)
+                elif dur_us > self._worst[0][0]:
+                    heapq.heapreplace(self._worst, item)
             if sampled:
                 self._ring.append(trace)
         counters.rate("request.trace.completed_count").increment()
@@ -430,16 +625,21 @@ class RequestTracer:
             return list(self._ring)[-last:]
 
     def slow_requests(self, last: int = 50) -> list:
-        """The slow-request ledger: full stage timelines of every request
-        that crossed slow_threshold_us."""
+        """The slow-request ledger: full stage timelines of requests that
+        crossed slow_threshold_us — the WORST slowest since start first
+        (worst first), then the newest `last` of the rest."""
         with self._lock:
-            return list(self._slow)[-last:]
+            worst = [t for _, _, t in sorted(self._worst, reverse=True)]
+            recent = list(self._slow)[-last:]
+        kept = {id(t) for t in worst}
+        return worst + [t for t in recent if id(t) not in kept]
 
     def find(self, trace_id: str):
         """Look one completed trace up by hex id (ledger first: slow
         traces are the ones being hunted)."""
         with self._lock:
-            for t in list(self._slow) + list(self._ring):
+            for t in ([t for _, _, t in self._worst] + list(self._slow)
+                      + list(self._ring)):
                 if t["trace_id"] == trace_id:
                     return t
         return None

@@ -104,11 +104,28 @@ def test_one_put_yields_one_trace_with_full_stage_timeline(onebox):
     assert "replica.commit" in names
     assert "plog.append" in names
     assert "engine.apply" in names
+    # the waits and the work between those spans are named too (ISSUE 27)
+    assert "rpc.queue" in names and "plog.flush" in names
+    flush = next(s for s in trace["spans"] if s["name"] == "plog.flush")
+    assert flush["parent"] == "plog.append"
     # span durations nest sanely: the client span covers the whole trace
     client_span = next(s for s in trace["spans"]
                        if s["name"].startswith("client."))
     assert client_span["duration_us"] <= trace["duration_us"]
     assert all(s["duration_us"] >= 0 for s in trace["spans"])
+
+
+def test_one_get_yields_a_trace_through_the_engine_read(onebox):
+    _, _, client = onebox
+    client.set(b"tk", b"sk_get", b"payload")
+    assert client.get(b"tk", b"sk_get") == b"payload"
+    gets = [t for t in REQUEST_TRACER.trace(500)
+            if t["op"].endswith("_GET")
+            and any(s["name"] == "engine.get" for s in t["spans"])]
+    assert gets
+    by_name = {s["name"]: s for s in gets[-1]["spans"]}
+    assert by_name["engine.get"]["parent"].startswith("rpc.server.")
+    assert "rpc.queue" in by_name
 
 
 def test_requests_trace_http_route_serves_the_trace(onebox):
@@ -226,12 +243,16 @@ def test_request_tracer_root_and_span_nesting():
     assert trace["spans"][1]["records"] == 3
 
 
-def test_request_tracer_spans_without_context_are_noops():
+def test_request_tracer_spans_without_context_record_no_trace():
+    """No context, no per-trace record — the span is still timed into the
+    stage totals (tests/test_stage_totals.py)."""
     tr = RequestTracer()
+    before = counters.number("stage.orphan.n").value()
     with tr.span("orphan"):
         pass
     assert tr.trace() == []
     assert tr.slow_requests() == []
+    assert counters.number("stage.orphan.n").value() == before + 1
 
 
 def test_request_tracer_serve_finalizes_remote_view():
