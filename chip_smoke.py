@@ -906,9 +906,10 @@ def main() -> int:
         sizes = REHEARSAL
     ns.hashkeys = sizes["hashkeys"]
 
-    # the .so files on disk are gitignored leftovers: rebuild both from
-    # source, and fail if that fails — later runs would quietly serve the
-    # pure-Python twins
+    # a checkout's .so files prove nothing about its sources (fastcodec's
+    # is a gitignored leftover, libhostops.so a committed artifact):
+    # rebuild both, and fail if that fails — later runs would quietly
+    # serve the pure-Python twins
     from tools import build_native
 
     built = build_native.ensure(force=True)

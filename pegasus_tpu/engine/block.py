@@ -184,7 +184,7 @@ class KVBlock:
             from .. import native
 
             uni = self.uniform_layout() if native.available() else None
-            # the native kernel does unchecked pointer arithmetic; keep
+            # the native kernel refuses an index outside the rows; keep
             # numpy's bounds semantics (negatives/OOB fall through to the
             # fancy-index path, which wraps or raises) — two O(n)
             # reductions, negligible next to the gather
@@ -198,20 +198,27 @@ class KVBlock:
                 out_e = np.empty(count, np.uint32)
                 out_h = np.empty(count, np.uint32)
                 out_d = np.empty(count, np.bool_)
-                if native.gather_block_uniform(
-                        self.key_arena, kl0, self.val_arena, vl0,
-                        self.expire_ts, self.hash32, self.deleted,
-                        idx.astype(np.int32), out_k, out_v, out_e, out_h,
-                        out_d):
-                    return KVBlock(
-                        out_k, np.arange(count, dtype=np.int64) * kl0,
-                        np.full(count, kl0, np.int32),
-                        out_v, np.arange(count, dtype=np.int64) * vl0,
-                        np.full(count, vl0, np.int32), out_e, out_h, out_d)
+                native.gather_runs_uniform([self], kl0, vl0, idx, out_k,
+                                           out_v, out_e, out_h, out_d)
+                return KVBlock.uniform(kl0, vl0, out_k, out_v, out_e, out_h,
+                                       out_d)
         ka, ko, kl = _gather_arena(self.key_arena, self.key_off, self.key_len, idx)
         va, vo, vl = _gather_arena(self.val_arena, self.val_off, self.val_len, idx)
         return KVBlock(ka, ko, kl, va, vo, vl,
                        self.expire_ts[idx], self.hash32[idx], self.deleted[idx])
+
+    @staticmethod
+    def uniform(kl0: int, vl0: int, keys, vals, expire_ts, hash32,
+                deleted) -> "KVBlock":
+        """The uniform-layout block over gathered columns (keys and vals
+        hold the rows back to back, flat or [n, width]): what gather
+        returns for uniform input."""
+        n = len(expire_ts)
+        return KVBlock(
+            keys.reshape(-1), np.arange(n, dtype=np.int64) * kl0,
+            np.full(n, kl0, np.int32),
+            vals.reshape(-1), np.arange(n, dtype=np.int64) * vl0,
+            np.full(n, vl0, np.int32), expire_ts, hash32, deleted)
 
     @staticmethod
     def concat(blocks) -> "KVBlock":

@@ -2302,25 +2302,64 @@ class LsmEngine:
 
 def _split_block(block: KVBlock, target_bytes: int) -> list:
     """Split a sorted block into chunks of ~target_bytes (key+value arenas),
-    preserving order; every output chunk holds a disjoint key range."""
-    if block.n == 0:
+    preserving order; every output chunk holds a disjoint key range.
+
+    The chunks are VIEWS of the block: arenas and columns sliced, only the
+    offset columns rebased (8 B a row, not the record), and the cuts are
+    found on the offsets themselves. That needs arenas that hold the rows
+    back to back in row order, which every constructor emits (block.py
+    uniform_layout's precondition) and one subtraction checks; a block
+    with gaps is cut by copying, as before. A chunk keeps the whole
+    block's memory alive, as the copies together did."""
+    n = block.n
+    if n == 0:
         return [block]
-    total = block.key_bytes_total + block.val_bytes_total
+    key_total, val_total = block.key_bytes_total, block.val_bytes_total
+    total = key_total + val_total
     if total <= target_bytes:
         return [block]
-    sizes = block.key_len.astype(np.int64) + block.val_len.astype(np.int64)
-    cum = np.cumsum(sizes)
+    k0, v0 = int(block.key_off[0]), int(block.val_off[0])
+    dense = (int(block.key_off[-1]) + int(block.key_len[-1]) - k0 == key_total
+             and int(block.val_off[-1]) + int(block.val_len[-1]) - v0
+             == val_total)
+    if dense:
+        def bytes_through(i: int) -> int:   # rows [0, i]: where row i+1 starts
+            return total if i == n - 1 else (
+                int(block.key_off[i + 1]) - k0
+                + int(block.val_off[i + 1]) - v0)
+    else:
+        cum = np.cumsum(block.key_len.astype(np.int64)
+                        + block.val_len.astype(np.int64))
+
+        def bytes_through(i: int) -> int:
+            return int(cum[i])
     bounds = []
     start = 0
     base = 0
     for _ in range(int(total // target_bytes) + 1):
-        cut = np.searchsorted(cum, base + target_bytes, side="left") + 1
-        cut = min(int(cut), block.n)
+        # first row whose running total reaches the target, inclusive
+        cut = bisect.bisect_left(range(n), base + target_bytes,
+                                 key=bytes_through) + 1
+        cut = min(cut, n)
         if cut <= start:
             cut = start + 1
         bounds.append((start, cut))
-        if cut >= block.n:
+        if cut >= n:
             break
         start = cut
-        base = int(cum[cut - 1])
-    return [block.gather(np.arange(s, e, dtype=np.int64)) for s, e in bounds]
+        base = bytes_through(cut - 1)
+    if not dense:
+        return [block.gather(np.arange(s, e, dtype=np.int64))
+                for s, e in bounds]
+
+    def arena_rows(arena, off, ln, s, e):
+        lo, hi = int(off[s]), int(off[e - 1]) + int(ln[e - 1])
+        return arena[lo:hi], (off[s:e] - lo if lo else off[s:e]), ln[s:e]
+
+    return [KVBlock(*arena_rows(block.key_arena, block.key_off,
+                                block.key_len, s, e),
+                    *arena_rows(block.val_arena, block.val_off,
+                                block.val_len, s, e),
+                    block.expire_ts[s:e], block.hash32[s:e],
+                    block.deleted[s:e])
+            for s, e in bounds]

@@ -113,7 +113,9 @@ def _write_sst_impl(path: str, block: KVBlock, meta: dict,
     offset = 0
     for name, dtype in _COLUMNS:
         arr = np.ascontiguousarray(getattr(block, name), dtype=dtype)
-        raw = arr.tobytes()
+        # the column's own bytes (writable or an mmap's read-only pages),
+        # crc'd and written where they lie: no tobytes() copy
+        raw = arr.reshape(-1).view(np.uint8)
         stored = zlib.compress(raw, 1) if compression == "zlib" else raw
         sections[name] = {"offset": offset, "nbytes": len(stored),
                           "raw_nbytes": len(raw),

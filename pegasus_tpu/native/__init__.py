@@ -39,7 +39,10 @@ def _load():
         _tried = True
         if (not os.path.exists(_SO)
                 or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
-            if not _build():
+            # a checkout gives the committed .so and its source arbitrary
+            # mtimes: with no compiler the shipped library still loads,
+            # and _bind below refuses it if it predates a symbol
+            if not _build() and not os.path.exists(_SO):
                 return None
         try:
             lib = ctypes.CDLL(_SO)
@@ -85,12 +88,15 @@ def _bind(lib) -> None:
     lib.merge_counts.argtypes = [u8p, ctypes.c_int64, u8p, ctypes.c_int64,
                                  ctypes.c_int64, ctypes.c_int32, i64p]
     boolp = np.ctypeslib.ndpointer(np.bool_, flags="C")
-    lib.gather_block_uniform.argtypes = [
-        u8p, ctypes.c_int64, u8p, ctypes.c_int64, u32p, u32p, boolp,
-        i32p, ctypes.c_int64, u8p, u8p, u32p, u32p, boolp]
-    lib.gather_keys_uniform.argtypes = [
-        u8p, ctypes.c_int64, u32p, u32p, boolp,
-        i32p, ctypes.c_int64, u8p, u32p, u32p, boolp]
+    ptrs = ctypes.POINTER(ctypes.c_void_p)   # K base pointers, one a run
+    lib.gather_block_runs_uniform.argtypes = [
+        ptrs, ctypes.c_int64, ptrs, ctypes.c_int64, ptrs, ptrs, ptrs,
+        i64p, ctypes.c_int32, i32p, ctypes.c_int64,
+        u8p, u8p, u32p, u32p, boolp]
+    lib.gather_keys_runs_uniform.argtypes = [
+        ptrs, ctypes.c_int64, ptrs, ptrs, ptrs,
+        i64p, ctypes.c_int32, i32p, ctypes.c_int64,
+        u8p, u32p, u32p, boolp]
 
 
 def available() -> bool:
@@ -223,44 +229,69 @@ def pack_prefixes(arena, off, len32, w):
     return out.T
 
 
-def gather_block_uniform(key_arena, klen, val_arena, vlen, expire, hash32,
-                         deleted, idx, out_keys, out_vals, out_expire,
-                         out_hash32, out_deleted) -> bool:
-    """Fused one-pass gather of a uniform-record block into preallocated
-    outputs (keys, values, expire, hash32, deleted) with source-row
-    prefetching. idx is int32. Returns False if the library is absent
-    (caller falls back to per-array fancy indexing)."""
-    lib = _load()
-    if lib is None:
-        return False
-    lib.gather_block_uniform(
-        np.ascontiguousarray(key_arena, np.uint8), int(klen),
-        np.ascontiguousarray(val_arena, np.uint8), int(vlen),
-        np.ascontiguousarray(expire, np.uint32),
-        np.ascontiguousarray(hash32, np.uint32),
-        np.ascontiguousarray(deleted, np.bool_),
-        np.ascontiguousarray(idx, np.int32), len(idx),
-        out_keys, out_vals, out_expire, out_hash32, out_deleted)
-    return True
+def _columns(runs, name: str, dtype) -> list:
+    return [np.ascontiguousarray(getattr(r, name), dtype) for r in runs]
 
 
-def gather_keys_uniform(key_arena, klen, expire, hash32, deleted, idx,
-                        out_keys, out_expire, out_hash32,
-                        out_deleted) -> bool:
-    """Keys+aux half of the uniform gather (no values — they come off the
-    device in the value-residency path). Returns False if the library is
-    absent (caller falls back to fancy indexing)."""
-    lib = _load()
+def _bases(arrs: list):
+    """The arrays' base addresses as a C array (the caller keeps arrs)."""
+    return (ctypes.c_void_p * len(arrs))(*[a.ctypes.data for a in arrs])
+
+
+def gather_runs_uniform(runs, klen, vlen, idx, out_keys, out_vals,
+                        out_expire, out_hash32, out_deleted,
+                        use_native: bool = True) -> None:
+    """Rows idx of the runs AS IF concatenated, gathered into preallocated
+    outputs without the concatenation: run r owns the indices
+    [starts[r], starts[r+1]), starts = cumsum of the runs' row counts.
+    Every run is a uniform-layout block (KVBlock.uniform_layout) of the
+    one (klen, vlen). out_vals None gathers keys + aux only (the values
+    come off the device). Native by-run loop when the library is there,
+    else the numpy twin: one masked fancy-index per run.
+
+    Device-derived indices feed unchecked native pointer arithmetic (and
+    numpy fancy indexing would silently wrap a -1): a pipeline defect must
+    be loud, not memory corruption."""
+    starts = np.zeros(len(runs) + 1, np.int64)
+    np.cumsum([r.n for r in runs], out=starts[1:])
+    idx = np.asarray(idx)
+    count, total = len(idx), int(starts[-1])
+    if count and (int(idx.min()) < 0 or int(idx.max()) >= total):
+        raise ValueError(
+            "survivor index outside the runs' rows — device pipeline bug "
+            f"(min {int(idx.min())}, max {int(idx.max())}, n {total})")
+    idx = np.ascontiguousarray(idx, np.int32)
+    lib = _load() if use_native else None
     if lib is None:
-        return False
-    lib.gather_keys_uniform(
-        np.ascontiguousarray(key_arena, np.uint8), int(klen),
-        np.ascontiguousarray(expire, np.uint32),
-        np.ascontiguousarray(hash32, np.uint32),
-        np.ascontiguousarray(deleted, np.bool_),
-        np.ascontiguousarray(idx, np.int32), len(idx),
-        out_keys, out_expire, out_hash32, out_deleted)
-    return True
+        for r, run in enumerate(runs):
+            lo, hi = int(starts[r]), int(starts[r + 1])
+            sel = (idx >= lo) & (idx < hi)
+            local = idx[sel] - lo
+            out_keys.reshape(count, klen)[sel] = \
+                run.key_arena.reshape(run.n, klen)[local]
+            if out_vals is not None:
+                out_vals.reshape(count, vlen)[sel] = \
+                    run.val_arena.reshape(run.n, vlen)[local]
+            out_expire[sel] = run.expire_ts[local]
+            out_hash32[sel] = run.hash32[local]
+            out_deleted[sel] = run.deleted[local]
+        return
+    keys = _columns(runs, "key_arena", np.uint8)
+    expires = _columns(runs, "expire_ts", np.uint32)
+    hashes = _columns(runs, "hash32", np.uint32)
+    deleteds = _columns(runs, "deleted", np.bool_)
+    if out_vals is None:
+        lib.gather_keys_runs_uniform(
+            _bases(keys), int(klen), _bases(expires), _bases(hashes),
+            _bases(deleteds), starts, len(runs), idx, count,
+            out_keys.reshape(-1), out_expire, out_hash32, out_deleted)
+        return
+    vals = _columns(runs, "val_arena", np.uint8)
+    lib.gather_block_runs_uniform(
+        _bases(keys), int(klen), _bases(vals), int(vlen), _bases(expires),
+        _bases(hashes), _bases(deleteds), starts, len(runs), idx, count,
+        out_keys.reshape(-1), out_vals.reshape(-1), out_expire, out_hash32,
+        out_deleted)
 
 
 def merge_counts(a_sbytes, b_sbytes, side: str):

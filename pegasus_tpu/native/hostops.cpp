@@ -109,7 +109,7 @@ void pack_prefixes(const uint8_t* arena, const int64_t* off,
     }
 }
 
-// ------------------------------------------------ fused uniform gather
+// ------------------------------------------------- by-run uniform gather
 
 // Materialize a compaction output block from uniform-width records in ONE
 // pass over the survivor index: keys (klen bytes each), values (vlen),
@@ -118,61 +118,103 @@ void pack_prefixes(const uint8_t* arena, const int64_t* off,
 // The separate-pass form (5 fancy-index sweeps) was measured 2.0-2.9s at
 // 8.5M survivors on the 1-core dev host — DRAM-latency-bound on the
 // dependent row loads; prefetching + fusion cuts most of the stalls.
-void gather_block_uniform(const uint8_t* key_arena, int64_t klen,
-                          const uint8_t* val_arena, int64_t vlen,
-                          const uint32_t* expire, const uint32_t* hash32,
-                          const uint8_t* deleted, const int32_t* idx,
-                          int64_t n, uint8_t* out_keys, uint8_t* out_vals,
-                          uint32_t* out_expire, uint32_t* out_hash32,
-                          uint8_t* out_deleted) {
-    const int64_t AHEAD = 24;
+//
+// The rows come from K runs that are never concatenated: idx is in
+// real-concat space, run r owns [starts[r], starts[r+1]) with starts =
+// cumsum of the runs' row counts (K+1 entries), and each run brings its
+// own base pointers (K = 1: one block's own gather). Resolving an index to
+// (run, local row) is a scan over starts at the K a compaction sees (4 L0
+// files), a binary search above 8. The scan counts instead of stopping:
+// which run a survivor comes from is as good as random, and a mispredicted
+// exit a row cost a third of the loop. idx must lie in [0, starts[k]): the
+// Python side checks first.
+static inline int32_t run_of(const int64_t* starts, int32_t k, int64_t j) {
+    if (k <= 8) {
+        int32_t r = 0;
+        for (int32_t t = 1; t < k; t++) r += (j >= starts[t]);
+        return r;
+    }
+    int32_t lo = 0;   // last r with starts[r] <= j, by conditional moves
+    for (int32_t step = 1 << (31 - __builtin_clz((unsigned)k)); step;
+         step >>= 1) {
+        int32_t m = lo + step;
+        lo = (m < k && starts[m] <= j) ? m : lo;
+    }
+    return lo;
+}
+
+// WITH_VALS is a constant at both call sites below; inlined, each wrapper
+// keeps a loop without the branch.
+static inline __attribute__((always_inline)) void gather_runs_impl(
+        const bool WITH_VALS, const uint8_t* const* key_arenas, int64_t klen,
+        const uint8_t* const* val_arenas, int64_t vlen,
+        const uint32_t* const* expires, const uint32_t* const* hash32s,
+        const uint8_t* const* deleteds, const int64_t* starts, int32_t k,
+        const int32_t* idx, int64_t n, uint8_t* out_keys, uint8_t* out_vals,
+        uint32_t* out_expire, uint32_t* out_hash32, uint8_t* out_deleted) {
+    const int64_t AHEAD = WITH_VALS ? 24 : 32;
+    uint8_t ahead_run[32];   // run of idx[i], resolved when it was prefetched
+    for (int64_t i = 0; i < AHEAD && i < n; i++)
+        ahead_run[i & 31] = (uint8_t)run_of(starts, k, (int64_t)idx[i]);
     for (int64_t i = 0; i < n; i++) {
+        int32_t r = ahead_run[i & 31];
         if (i + AHEAD < n) {
             int64_t ja = (int64_t)idx[i + AHEAD];
-            __builtin_prefetch(key_arena + ja * klen, 0, 0);
-            __builtin_prefetch(val_arena + ja * vlen, 0, 0);
-            // values can span multiple lines; touch the middle + tail too
-            if (vlen > 64)
-                __builtin_prefetch(val_arena + ja * vlen + 64, 0, 0);
-            if (vlen > 128)
-                __builtin_prefetch(val_arena + ja * vlen + vlen - 1, 0, 0);
-            __builtin_prefetch(expire + ja, 0, 0);
-            __builtin_prefetch(hash32 + ja, 0, 0);
-            __builtin_prefetch(deleted + ja, 0, 0);
+            int32_t ra = run_of(starts, k, ja);
+            ahead_run[(i + AHEAD) & 31] = (uint8_t)ra;
+            ja -= starts[ra];
+            __builtin_prefetch(key_arenas[ra] + ja * klen, 0, 0);
+            if (WITH_VALS) {
+                const uint8_t* v = val_arenas[ra] + ja * vlen;
+                __builtin_prefetch(v, 0, 0);
+                if (vlen > 64) __builtin_prefetch(v + 64, 0, 0);
+                if (vlen > 128) __builtin_prefetch(v + vlen - 1, 0, 0);
+            }
+            __builtin_prefetch(expires[ra] + ja, 0, 0);
+            __builtin_prefetch(hash32s[ra] + ja, 0, 0);
+            __builtin_prefetch(deleteds[ra] + ja, 0, 0);
         }
-        int64_t j = (int64_t)idx[i];
-        memcpy(out_keys + i * klen, key_arena + j * klen, (size_t)klen);
-        memcpy(out_vals + i * vlen, val_arena + j * vlen, (size_t)vlen);
-        out_expire[i] = expire[j];
-        out_hash32[i] = hash32[j];
-        out_deleted[i] = deleted[j];
+        int64_t j = (int64_t)idx[i] - starts[r];
+        memcpy(out_keys + i * klen, key_arenas[r] + j * klen, (size_t)klen);
+        if (WITH_VALS)
+            memcpy(out_vals + i * vlen, val_arenas[r] + j * vlen,
+                   (size_t)vlen);
+        out_expire[i] = expires[r][j];
+        out_hash32[i] = hash32s[r][j];
+        out_deleted[i] = deleteds[r][j];
     }
 }
 
+void gather_block_runs_uniform(const uint8_t* const* key_arenas, int64_t klen,
+                               const uint8_t* const* val_arenas, int64_t vlen,
+                               const uint32_t* const* expires,
+                               const uint32_t* const* hash32s,
+                               const uint8_t* const* deleteds,
+                               const int64_t* starts, int32_t k,
+                               const int32_t* idx, int64_t n,
+                               uint8_t* out_keys, uint8_t* out_vals,
+                               uint32_t* out_expire, uint32_t* out_hash32,
+                               uint8_t* out_deleted) {
+    gather_runs_impl(true, key_arenas, klen, val_arenas, vlen, expires,
+                     hash32s, deleteds, starts, k, idx, n, out_keys,
+                     out_vals, out_expire, out_hash32, out_deleted);
+}
+
 // Keys-and-aux-only variant: the device-value-residency materialization
-// (ops/compact.py materialize_device_survivors) downloads value rows from
-// HBM while the host gathers only keys + fixed-width aux — the two halves
-// overlap, so this loop must not touch the value arena at all.
-void gather_keys_uniform(const uint8_t* key_arena, int64_t klen,
-                         const uint32_t* expire, const uint32_t* hash32,
-                         const uint8_t* deleted, const int32_t* idx,
-                         int64_t n, uint8_t* out_keys, uint32_t* out_expire,
-                         uint32_t* out_hash32, uint8_t* out_deleted) {
-    const int64_t AHEAD = 32;
-    for (int64_t i = 0; i < n; i++) {
-        if (i + AHEAD < n) {
-            int64_t ja = (int64_t)idx[i + AHEAD];
-            __builtin_prefetch(key_arena + ja * klen, 0, 0);
-            __builtin_prefetch(expire + ja, 0, 0);
-            __builtin_prefetch(hash32 + ja, 0, 0);
-            __builtin_prefetch(deleted + ja, 0, 0);
-        }
-        int64_t j = (int64_t)idx[i];
-        memcpy(out_keys + i * klen, key_arena + j * klen, (size_t)klen);
-        out_expire[i] = expire[j];
-        out_hash32[i] = hash32[j];
-        out_deleted[i] = deleted[j];
-    }
+// (ops/compact.py _finish_overlapped) downloads value rows from HBM while
+// the host gathers only keys + fixed-width aux — the two halves overlap, so
+// this loop must not touch the value arenas at all.
+void gather_keys_runs_uniform(const uint8_t* const* key_arenas, int64_t klen,
+                              const uint32_t* const* expires,
+                              const uint32_t* const* hash32s,
+                              const uint8_t* const* deleteds,
+                              const int64_t* starts, int32_t k,
+                              const int32_t* idx, int64_t n,
+                              uint8_t* out_keys, uint32_t* out_expire,
+                              uint32_t* out_hash32, uint8_t* out_deleted) {
+    gather_runs_impl(false, key_arenas, klen, nullptr, 0, expires, hash32s,
+                     deleteds, starts, k, idx, n, out_keys, nullptr,
+                     out_expire, out_hash32, out_deleted);
 }
 
 // ----------------------------------------------------- sorted-run merge

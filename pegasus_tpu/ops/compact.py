@@ -51,6 +51,10 @@ _MIN_BUCKET = 256  # pad runs to pow2 buckets >= this to bound jit recompiles
 # monotonic total of runs refused HBM residency for a key over the prefix
 # window (device-health's `bypass` block; chip_smoke.py requires zero)
 _C_LONG_KEY_BYPASS = _counters.number("engine.hbm.long_key_bypass_count")
+# monotonic totals, one a survivor gather: by pointer arithmetic over the
+# runs as they are, or (layouts not uniform) over a KVBlock.concat copy
+_C_GATHER_BY_RUN = _counters.number("compact.gather.by_run_count")
+_C_GATHER_CONCAT = _counters.number("compact.gather.concat_count")
 
 
 @dataclass
@@ -530,53 +534,38 @@ def _compiled_val_gather(n: int, vl0: int, bucket: int):
     return DeviceKernel(fn, "val_gather")
 
 
-def _finish_overlapped(concat: KVBlock, out_dev, real_idx, count: int,
+def _finish_overlapped(runs, out_dev, real_idx, count: int,
                        kl0: int, vl0: int) -> KVBlock:
     """Shared tail of both value-residency materializers: start the value
-    download, gather keys+aux on the host while it is in flight (native
-    fused loop, numpy fallback), assemble the uniform output block."""
+    download, gather keys+aux by run on the host while it is in flight
+    (native fused loop, numpy twin), assemble the uniform output block.
+    runs: uniform-layout blocks of the one (kl0, vl0); real_idx indexes
+    them as if concatenated."""
     with _TRACE.span("gather", records=count,
                      nbytes=count * (kl0 + vl0)):
         _inject("compact.gather")
-        return _finish_overlapped_impl(concat, out_dev, real_idx, count,
+        _C_GATHER_BY_RUN.increment()
+        return _finish_overlapped_impl(runs, out_dev, real_idx, count,
                                        kl0, vl0)
 
 
-def _finish_overlapped_impl(concat: KVBlock, out_dev, real_idx, count: int,
+def _finish_overlapped_impl(runs, out_dev, real_idx, count: int,
                             kl0: int, vl0: int) -> KVBlock:
+    from .. import native
+
     try:
         out_dev.copy_to_host_async()
     except AttributeError:
         pass
-    idx = np.asarray(real_idx[:count]).astype(np.int32, copy=False)
-    # device-derived indices feed unchecked native pointer arithmetic (and
-    # numpy fancy indexing would silently wrap a -1): a pipeline defect
-    # must be loud, not memory corruption
-    if count and (int(idx.min()) < 0 or int(idx.max()) >= concat.n):
-        raise ValueError(
-            "survivor index outside concat rows — device pipeline bug "
-            f"(min {int(idx.min())}, max {int(idx.max())}, n {concat.n})")
-    from .. import native
-
+    idx = np.asarray(real_idx[:count])
     out_k = np.empty((count, kl0), np.uint8)
     out_e = np.empty(count, np.uint32)
     out_h = np.empty(count, np.uint32)
     out_d = np.empty(count, np.bool_)
-    if not native.gather_keys_uniform(
-            concat.key_arena, kl0, concat.expire_ts, concat.hash32,
-            concat.deleted, idx, out_k.reshape(-1), out_e, out_h, out_d):
-        key2d = concat.key_arena.reshape(concat.n, kl0)
-        out_k[:] = key2d[idx]
-        out_e[:] = concat.expire_ts[idx]
-        out_h[:] = concat.hash32[idx]
-        out_d[:] = concat.deleted[idx]
+    native.gather_runs_uniform(runs, kl0, vl0, idx, out_k, None,
+                               out_e, out_h, out_d)
     out_v = np.asarray(out_dev)[:count]
-    return KVBlock(
-        out_k.reshape(-1), np.arange(count, dtype=np.int64) * kl0,
-        np.full(count, kl0, np.int32),
-        out_v.reshape(-1), np.arange(count, dtype=np.int64) * vl0,
-        np.full(count, vl0, np.int32),
-        out_e, out_h, out_d)
+    return KVBlock.uniform(kl0, vl0, out_k, out_v, out_e, out_h, out_d)
 
 
 def materialize_device_survivors(concat: KVBlock, dev_vals: DeviceVals,
@@ -588,7 +577,7 @@ def materialize_device_survivors(concat: KVBlock, dev_vals: DeviceVals,
     back to the host-gather path."""
     if count == 0:
         return KVBlock.empty()
-    uni = concat.uniform_layout()
+    uni = _shared_uniform_layout([concat])
     if uni is None or dev_vals is None or dev_vals.n != concat.n \
             or uni[1] != dev_vals.vl0:
         return gather_device_survivors(concat, dev_idx, count)
@@ -596,41 +585,60 @@ def materialize_device_survivors(concat: KVBlock, dev_vals: DeviceVals,
     bucket = min(_pow2ceil(count, 1 << 16), int(dev_idx.shape[0]))
     fn = _compiled_val_gather(dev_vals.n, vl0, bucket)
     out_dev = fn(dev_vals.val2d, dev_idx[:bucket])
-    return _finish_overlapped(concat, out_dev, dev_idx, count, kl0, vl0)
+    return _finish_overlapped([concat], out_dev, dev_idx, count, kl0, vl0)
 
 
-def gather_device_survivors(concat: KVBlock, dev_idx, count: int,
-                            chunks: int = 8) -> KVBlock:
-    """Materialize concat.gather(survivors) while the survivor index is
-    still in flight: the device index splits into chunks whose host copies
-    all start asynchronously up front, so the arena gather of chunk i
-    overlaps the transfer of chunks i+1.. (VERDICT-r2 item 3 — on this
-    box the index download and the memcpy-bound gather are comparable
-    costs; overlapped they pay max() instead of sum()).
+def _shared_uniform_layout(runs):
+    """(key_len, val_len) when every run is a uniform-layout block of the
+    same widths and the rows together fit an int32 index — what lets a
+    gather resolve a concat-space index to (run, row) by arithmetic; None
+    otherwise (variable-width keys or values: the redis proxy's, geo's)."""
+    if sum(b.n for b in runs) >= (1 << 31):
+        return None
+    uni = runs[0].uniform_layout()
+    if uni is None or any(b.uniform_layout() != uni for b in runs[1:]):
+        return None
+    return uni
 
-    Preallocating the output requires the uniform-record contiguous-arena
-    layout (the same precondition _gather_arena's fast path keys on);
-    anything else falls back to the one-shot download + gather."""
+
+def gather_runs(runs, idx, count: int, chunks: int = 8) -> KVBlock:
+    """KVBlock.concat(runs).gather(idx[:count]) without the concat where
+    the layout allows: idx is in real-concat space (run r owns
+    [starts[r], starts[r+1]), starts = cumsum of the runs' rows), so over
+    uniform runs of shared widths the gather resolves each index to
+    (run, row) and reads the runs where they lie — the only host copy of
+    a merge's output. Anything else concatenates first, under the `concat`
+    span, as before.
+
+    idx may still be in flight on the device: it splits into chunks whose
+    host copies all start asynchronously up front, so the arena gather of
+    chunk i overlaps the transfer of chunks i+1.. (VERDICT-r2 item 3 — on
+    this box the index download and the memcpy-bound gather are comparable
+    costs; overlapped they pay max() instead of sum())."""
     if count == 0:
         return KVBlock.empty()
-    with _TRACE.span("gather", records=count):
-        _inject("compact.gather")
-        return _gather_device_survivors_impl(concat, dev_idx, count, chunks)
-
-
-def _gather_device_survivors_impl(concat: KVBlock, dev_idx, count: int,
-                                  chunks: int) -> KVBlock:
-    n = concat.n
-    uni = concat.uniform_layout() if (count >= (1 << 16) and chunks > 1
-                                      and n < (1 << 31)) else None
+    # the fail point is the device lane's (survivor download +
+    # materialization): the cpu lane, the guard's fallback, brings a host
+    # index and must stay clear of it
+    in_flight = not isinstance(idx, np.ndarray)
+    uni = _shared_uniform_layout(runs)
     if uni is None:
-        return concat.gather(np.asarray(dev_idx[:count]))
-    kl0, vl0 = uni
+        runs = [_concat(runs)]   # its span closes before `gather` opens
+    (_C_GATHER_CONCAT if uni is None else _C_GATHER_BY_RUN).increment()
+    with _TRACE.span("gather", records=count):
+        if in_flight:
+            _inject("compact.gather")
+        if uni is None:
+            return runs[0].gather(np.asarray(idx[:count]))
+        return _gather_runs_impl(
+            runs, idx, count,
+            chunks if in_flight and count >= (1 << 16) else 1, *uni)
+
+
+def _gather_runs_impl(runs, idx, count: int, chunks: int,
+                      kl0: int, vl0: int) -> KVBlock:
     from .. import native
 
-    use_native = native.available()
-    key2d = concat.key_arena.reshape(n, kl0)
-    val2d = concat.val_arena.reshape(n, vl0)
     out_k = np.empty((count, kl0), np.uint8)
     out_v = np.empty((count, vl0), np.uint8)
     out_e = np.empty(count, np.uint32)
@@ -641,31 +649,24 @@ def _gather_device_survivors_impl(concat: KVBlock, dev_idx, count: int,
     for a, b in zip(bounds, bounds[1:]):
         if a == b:
             continue
-        part = dev_idx[a:b]
+        part = idx[a:b]
         try:
             part.copy_to_host_async()
         except AttributeError:
             pass
         parts.append((a, b, part))
     for a, b, part in parts:
-        idx = np.asarray(part)
-        if use_native and native.gather_block_uniform(
-                concat.key_arena, kl0, concat.val_arena, vl0,
-                concat.expire_ts, concat.hash32, concat.deleted,
-                idx.astype(np.int32, copy=False),
-                out_k[a:b], out_v[a:b], out_e[a:b], out_h[a:b], out_d[a:b]):
-            continue
-        out_k[a:b] = key2d[idx]
-        out_v[a:b] = val2d[idx]
-        out_e[a:b] = concat.expire_ts[idx]
-        out_h[a:b] = concat.hash32[idx]
-        out_d[a:b] = concat.deleted[idx]
-    return KVBlock(
-        out_k.reshape(-1), np.arange(count, dtype=np.int64) * kl0,
-        np.full(count, kl0, np.int32),
-        out_v.reshape(-1), np.arange(count, dtype=np.int64) * vl0,
-        np.full(count, vl0, np.int32),
-        out_e, out_h, out_d)
+        native.gather_runs_uniform(runs, kl0, vl0, np.asarray(part),
+                                   out_k[a:b], out_v[a:b], out_e[a:b],
+                                   out_h[a:b], out_d[a:b])
+    return KVBlock.uniform(kl0, vl0, out_k, out_v, out_e, out_h, out_d)
+
+
+def gather_device_survivors(concat: KVBlock, dev_idx, count: int,
+                            chunks: int = 8) -> KVBlock:
+    """gather_runs over one block that is already whole (the batched and
+    blockwise merges, bench lanes)."""
+    return gather_runs([concat], dev_idx, count, chunks)
 
 
 def _pad_to(a: np.ndarray, n: int) -> np.ndarray:
@@ -880,21 +881,21 @@ def _compiled_cached_val_gather(padded_lens: tuple, vl0: int, bucket: int):
     return DeviceKernel(fn, "val_gather_cached")
 
 
-def materialize_cached_survivors(concat: KVBlock, device_runs, mapped_idx,
-                                 padded_idx, count: int) -> KVBlock:
+def materialize_cached_survivors(runs, device_runs, mapped_idx,
+                                 padded_idx, count: int, kl0: int,
+                                 vl0: int) -> KVBlock:
     """Cached-run analogue of materialize_device_survivors: value rows are
     gathered per-run on device by padded-concat index and downloaded as one
     block, overlapped with the host keys+aux gather by real-concat index.
     Preconditions (caller-checked): every run has val2d with one shared
-    vl0, and concat has uniform layout matching it."""
+    vl0, and the runs share the uniform layout (kl0, vl0)."""
     if count == 0:
         return KVBlock.empty()
-    kl0, vl0 = concat.uniform_layout()
     padded_lens = tuple(r.padded_len for r in device_runs)
     bucket = min(_pow2ceil(count, 1 << 16), int(padded_idx.shape[0]))
     fn = _compiled_cached_val_gather(padded_lens, vl0, bucket)
     out_dev = fn(tuple(r.val2d for r in device_runs), padded_idx[:bucket])
-    return _finish_overlapped(concat, out_dev, mapped_idx, count, kl0, vl0)
+    return _finish_overlapped(runs, out_dev, mapped_idx, count, kl0, vl0)
 
 
 _BACKENDS = {"cpu": CpuBackend(), "tpu": TpuBackend(), "jax": TpuBackend()}
@@ -905,8 +906,9 @@ def get_backend(name: str):
 
 
 def _concat(runs) -> KVBlock:
-    """The runs as ONE block for the survivor gather to index: a copy of
-    every arena when there is more than one run."""
+    """The runs as ONE block for a survivor gather that cannot index them
+    where they lie (gather_runs' fallback): a copy of every arena when
+    there is more than one run."""
     if len(runs) == 1:
         return runs[0]
     with _TRACE.span("concat", records=sum(b.n for b in runs)):
@@ -971,31 +973,29 @@ def _compact_blocks_impl(blocks, opts: CompactOptions,
     def _cpu_lane() -> KVBlock:
         packed = pack_runs(runs, opts, need_sbytes=True)
         survivors = get_backend("cpu").survivors(packed, *fargs)
-        concat = _concat(runs)
-        with _TRACE.span("gather", records=len(survivors)):
-            return concat.gather(survivors)
+        return gather_runs(runs, survivors, len(survivors))
 
     def _device_lane() -> KVBlock:
         if (device_runs is not None and len(device_runs) == len(runs)
                 and all(d is not None for d in device_runs)):
-            concat = _concat(runs)
             # cheap checks first: uniform_layout() is four O(n) reductions,
             # wasted work whenever value residency is off (the default)
             vl0s = {d.vl0 for d in device_runs} \
                 if all(d.val2d is not None for d in device_runs) else set()
-            uni = concat.uniform_layout() if len(vl0s) == 1 else None
+            uni = _shared_uniform_layout(runs) if len(vl0s) == 1 else None
             if uni is not None and uni[1] == next(iter(vl0s)):
                 # value residency: output values materialize on device
                 mapped, padded, count = backend.survivors_cached_device(
                     device_runs, *fargs, want_padded=True)
-                return materialize_cached_survivors(concat, device_runs,
-                                                    mapped, padded, count)
+                return materialize_cached_survivors(runs, device_runs,
+                                                    mapped, padded, count,
+                                                    *uni)
             dev_idx, count = backend.survivors_cached_device(device_runs,
                                                              *fargs)
-            return gather_device_survivors(concat, dev_idx, count)
+            return gather_runs(runs, dev_idx, count)
         packed = pack_runs(runs, opts, need_sbytes=False)
         dev_idx, count = backend.survivors_device(packed, *fargs)
-        return gather_device_survivors(_concat(runs), dev_idx, count)
+        return gather_runs(runs, dev_idx, count)
 
     if backend.name == "tpu":
         # the lane guard owns every device failure mode: deadline-abandoned

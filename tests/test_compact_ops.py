@@ -666,3 +666,118 @@ def test_sorted_dup_runs_backend_parity_and_stats():
     assert bytes(cpu.block.val_arena) == bytes(tpu.block.val_arena)
     assert b"OLD" not in bytes(cpu.block.val_arena)  # first-wins kept new
     assert cpu.stats["input_records"] == tpu.stats["input_records"] == raw_n
+
+
+# ------------------------------------------- gather by run, no concat (PR 28)
+
+
+def _fill_like_runs(rng, n_runs, n):
+    """Uniform-width runs shaped like the bulk fill: few older versions,
+    little TTL, tombstones that keep the value width, so most rows
+    survive (the chunked index download starts at 65,536 survivors)."""
+    from pegasus_tpu.base.key_schema import generate_key
+    from pegasus_tpu.base.value_schema import SCHEMAS
+
+    runs = []
+    for _ in range(n_runs):
+        rows = {}
+        for hk in rng.integers(0, 10 * n, size=n):
+            expire = int(rng.integers(1, 90)) if rng.random() < 0.1 else 0
+            key = generate_key(b"h%08d" % hk, b"s%03d" % rng.integers(0, 4))
+            rows[key] = (key, SCHEMAS[2].generate_value(
+                expire, 0, b"p%09d" % rng.integers(0, 10**9)), expire,
+                bool(rng.random() < 0.05))
+        runs.append(sort_block(KVBlock.from_records(rows.values())))
+    return runs
+
+
+def _assert_blocks_equal(want: KVBlock, got: KVBlock):
+    for f in ("key_arena", "key_off", "key_len", "val_arena", "val_off",
+              "val_len", "expire_ts", "hash32", "deleted"):
+        np.testing.assert_array_equal(getattr(want, f), getattr(got, f),
+                                      err_msg=f)
+
+
+def _gather_counts():
+    from pegasus_tpu.runtime.perf_counters import counters
+
+    return (counters.number("compact.gather.by_run_count").value(),
+            counters.number("compact.gather.concat_count").value())
+
+
+@pytest.mark.parametrize("n", [400, 24000], ids=["one_chunk", "chunked"])
+def test_uniform_runs_gather_by_run_without_concat(n):
+    """Four uniform runs: the tpu lane (host-packed and over cached device
+    runs) and the cpu lane gather by run — byte-equal to each other and to
+    the construction they replaced (KVBlock.concat + gather by the same
+    survivors), no `concat` stage in the trace, by_run_count moved."""
+    from pegasus_tpu.ops.compact import (get_backend, pack_run_device,
+                                         pack_runs)
+    from pegasus_tpu.runtime.tracing import COMPACT_TRACER
+
+    rng = np.random.default_rng(n)
+    runs = _fill_like_runs(rng, 4, n)
+    assert len({r.uniform_layout() for r in runs}) == 1
+    opts = dict(now=100, bottommost=True, runs_sorted=True)
+    packed = pack_runs(runs, CompactOptions(backend="cpu", **opts),
+                       need_sbytes=True)
+    survivors = get_backend("cpu").survivors(packed, 100, 0, 0, True, True)
+    want = KVBlock.concat(runs).gather(survivors)
+    if n > 400:
+        assert want.n >= 1 << 16   # the chunked index download's floor
+
+    lanes = {"cpu": dict(backend="cpu"), "tpu": dict(backend="tpu"),
+             "tpu_cached": dict(backend="tpu")}
+    for lane, kw in lanes.items():
+        device_runs = ([pack_run_device(b) for b in runs]
+                       if lane == "tpu_cached" else None)
+        by_run0, concat0 = _gather_counts()
+        with COMPACT_TRACER.session() as sess:
+            got = compact_blocks(runs, CompactOptions(**kw, **opts),
+                                 device_runs=device_runs)
+        _assert_blocks_equal(want, got.block)
+        assert "gather" in sess.stages, (lane, sess.stages)
+        assert "concat" not in sess.stages, (lane, sess.stages)
+        by_run1, concat1 = _gather_counts()
+        assert (by_run1 - by_run0, concat1 - concat0) == (1, 0), lane
+
+
+def test_variable_width_runs_still_concat_then_gather():
+    """Variable-width keys and values (the redis proxy's, geo's) cannot be
+    indexed by arithmetic: the `concat` stage closes, concat_count moves,
+    by_run_count does not, and both lanes still agree byte for byte."""
+    from pegasus_tpu.runtime.tracing import COMPACT_TRACER
+
+    rng = np.random.default_rng(17)
+    runs = [sort_block(make_block(_adversarial_records(rng, 150)))
+            for _ in range(4)]
+    assert any(r.uniform_layout() is None for r in runs)
+    opts = dict(now=100, bottommost=True, runs_sorted=True)
+    outs = {}
+    for backend in ("cpu", "tpu"):
+        by_run0, concat0 = _gather_counts()
+        with COMPACT_TRACER.session() as sess:
+            outs[backend] = compact_blocks(
+                runs, CompactOptions(backend=backend, **opts)).block
+        assert "concat" in sess.stages and "gather" in sess.stages
+        by_run1, concat1 = _gather_counts()
+        assert (by_run1 - by_run0, concat1 - concat0) == (0, 1), backend
+    _assert_blocks_equal(outs["cpu"], outs["tpu"])
+
+
+def test_mixed_width_uniform_runs_fall_back_to_concat():
+    """Every run uniform, but not of ONE width: still the fallback."""
+    rng = np.random.default_rng(23)
+    a = _uniform_runs(rng, n_runs=1, n=200)[0]
+    rows = [(a.key(i) + b"x", a.value(i), int(a.expire_ts[i]),
+             bool(a.deleted[i])) for i in range(a.n)]
+    b = sort_block(KVBlock.from_records(rows))
+    assert a.uniform_layout() and b.uniform_layout()
+    assert a.uniform_layout() != b.uniform_layout()
+    opts = dict(now=100, bottommost=True, runs_sorted=True)
+    by_run0, concat0 = _gather_counts()
+    cpu = compact_blocks([a, b], CompactOptions(backend="cpu", **opts))
+    tpu = compact_blocks([a, b], CompactOptions(backend="tpu", **opts))
+    by_run1, concat1 = _gather_counts()
+    assert (by_run1 - by_run0, concat1 - concat0) == (0, 2)
+    _assert_blocks_equal(cpu.block, tpu.block)

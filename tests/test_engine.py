@@ -328,3 +328,160 @@ def test_values_uncacheable_not_repacked(tmp_path, monkeypatch):
     assert d1.val2d is not None and not sst_u._values_uncacheable
     d2 = sst_u.device_run(cops.DEFAULT_PREFIX_U32, with_values=True)
     assert d2 is d1 and len(calls) == 2
+
+
+# ------------------------- split by views, columns written in place (PR 28)
+
+_FIELDS = ("key_arena", "key_off", "key_len", "val_arena", "val_off",
+           "val_len", "expire_ts", "hash32", "deleted")
+
+
+def _sorted_block(n: int, uniform: bool, seed: int = 0):
+    from pegasus_tpu.engine.block import KVBlock
+
+    rng = np.random.default_rng(seed)
+    recs = [(generate_key(b"h%05d" % i, b"s"),
+             rng.bytes(40 if uniform else int(rng.integers(0, 90))),
+             int(rng.integers(0, 50)), bool(rng.random() < 0.1))
+            for i in range(n)]
+    block = KVBlock.from_records(recs)
+    assert (block.uniform_layout() is not None) == uniform
+    return block
+
+
+@pytest.mark.parametrize("uniform", [True, False],
+                         ids=["uniform", "variable_width"])
+def test_split_block_parts_are_views_equal_to_copies(tmp_path, uniform):
+    """_split_block's parts equal block.gather(arange(s, e)) field for
+    field, cover every row once, alias the block's arenas and columns
+    instead of copying them, and write_sst of a part reads back equal."""
+    from pegasus_tpu.engine.db import _split_block
+    from pegasus_tpu.engine.sstable import read_sst, verify_sst, write_sst
+
+    block = _sorted_block(1000, uniform, seed=3)
+    total = block.key_bytes_total + block.val_bytes_total
+    parts = _split_block(block, total // 5)
+    assert 5 <= len(parts) <= 6
+    assert sum(p.n for p in parts) == block.n
+    s = 0
+    for i, part in enumerate(parts):
+        e = s + part.n
+        want = block.gather(np.arange(s, e, dtype=np.int64))
+        for f in _FIELDS:
+            np.testing.assert_array_equal(getattr(want, f),
+                                          getattr(part, f), err_msg=f)
+        assert part.uniform_layout() == want.uniform_layout()
+        assert np.shares_memory(part.val_arena, block.val_arena)
+        assert np.shares_memory(part.key_arena, block.key_arena)
+        assert np.shares_memory(part.expire_ts, block.expire_ts)
+        path = str(tmp_path / f"part{i}.sst")
+        write_sst(path, part, {"level": 1})
+        assert verify_sst(path) > 0
+        back, header = read_sst(path)
+        assert header["n"] == part.n
+        for f in _FIELDS:
+            np.testing.assert_array_equal(getattr(want, f),
+                                          getattr(back, f), err_msg=f)
+        s = e
+    assert s == block.n
+    # one part: the block itself, as before
+    assert _split_block(block, total)[0] is block
+
+
+def test_split_block_with_arena_gaps_still_copies():
+    """A block whose arena holds more than its rows (a row slice over a
+    shared arena) cannot be cut into dense views: the parts are compacted
+    copies, equal to gather's."""
+    from pegasus_tpu.engine.db import _split_block
+    from pegasus_tpu.ops.compact import _slice_block
+
+    whole = _sorted_block(600, uniform=False, seed=9)
+    # every second row: offsets ascend, the arena keeps the skipped rows
+    from pegasus_tpu.engine.block import KVBlock
+
+    sl = _slice_block(whole, 0, whole.n)
+    gappy = KVBlock(sl.key_arena, sl.key_off[::2], sl.key_len[::2],
+                    sl.val_arena, sl.val_off[::2], sl.val_len[::2],
+                    sl.expire_ts[::2], sl.hash32[::2], sl.deleted[::2])
+    total = gappy.key_bytes_total + gappy.val_bytes_total
+    parts = _split_block(gappy, total // 3)
+    assert len(parts) >= 3 and sum(p.n for p in parts) == gappy.n
+    s = 0
+    for part in parts:
+        want = gappy.gather(np.arange(s, s + part.n, dtype=np.int64))
+        for f in _FIELDS:
+            np.testing.assert_array_equal(getattr(want, f),
+                                          getattr(part, f), err_msg=f)
+        assert not np.shares_memory(part.val_arena, gappy.val_arena)
+        s += part.n
+
+
+def _reference_sst_bytes(block, meta: dict, compression: str) -> bytes:
+    """The file write_sst produced before columns were written in place:
+    every column copied out with tobytes(), then crc'd / deflated."""
+    import json
+    import struct
+    import zlib
+
+    from pegasus_tpu.engine import sstable
+
+    sections, payload, offset = {}, [], 0
+    for name, dtype in sstable._COLUMNS:
+        arr = np.ascontiguousarray(getattr(block, name), dtype=dtype)
+        raw = arr.tobytes()
+        stored = zlib.compress(raw, 1) if compression == "zlib" else raw
+        sections[name] = {"offset": offset, "nbytes": len(stored),
+                          "raw_nbytes": len(raw),
+                          "dtype": np.dtype(dtype).str,
+                          "shape": list(arr.shape),
+                          "compression": compression,
+                          "crc32": zlib.crc32(stored) & 0xFFFFFFFF}
+        payload.append(stored)
+        offset += len(stored)
+    bloom_hex, bloom_log2m = "", 0
+    if block.n:
+        bits, bloom_log2m = sstable._bloom_build(block.hash32)
+        bloom_hex = bits.hex()
+    header = {"sections": sections, "meta": dict(meta), "n": block.n,
+              "min_key": block.key(0).hex() if block.n else None,
+              "max_key": block.key(block.n - 1).hex() if block.n else None,
+              "data_bytes": block.key_bytes_total + block.val_bytes_total,
+              "bloom": bloom_hex, "bloom_log2m": bloom_log2m}
+    hdr = json.dumps(header).encode()
+    return (sstable.MAGIC + struct.pack("<I", len(hdr)) + hdr
+            + b"".join(payload))
+
+
+@pytest.mark.parametrize("source", ["writable", "mmap_readonly", "empty"])
+@pytest.mark.parametrize("compression", ["none", "zlib"])
+def test_write_sst_in_place_is_byte_identical(tmp_path, monkeypatch,
+                                              compression, source):
+    """write_sst crc's and writes each column through its own buffer: the
+    file equals, byte for byte, the one built from tobytes() copies —
+    from writable arrays, from an mmap-backed block's read-only views
+    (what ingest and compaction inputs are), and for a block of no rows."""
+    from pegasus_tpu.engine.block import KVBlock
+    from pegasus_tpu.engine.sstable import read_sst, verify_sst, write_sst
+
+    meta = {"level": 2, "last_flushed_decree": 41}
+    block = (KVBlock.empty() if source == "empty"
+             else _sorted_block(700, uniform=False, seed=21))
+    want = _reference_sst_bytes(block, meta, compression)
+    if source == "mmap_readonly":
+        monkeypatch.setenv("PEGASUS_NATIVE", "1")
+        seed_path = str(tmp_path / "seed.sst")
+        write_sst(seed_path, block)
+        block, _ = read_sst(seed_path)
+        assert not block.val_arena.flags.writeable
+    path = str(tmp_path / "out.sst")
+    header = write_sst(path, block, meta, compression=compression)
+    with open(path, "rb") as f:
+        got = f.read()
+    assert got == want
+    assert not os.path.exists(path + ".tmp")
+    assert verify_sst(path) > 0   # every section's length and crc32
+    back, back_header = read_sst(path)
+    assert back_header == header
+    for f in _FIELDS:
+        np.testing.assert_array_equal(getattr(block, f), getattr(back, f),
+                                      err_msg=f)

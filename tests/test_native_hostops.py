@@ -87,28 +87,82 @@ def test_gather_arena_native_matches_fancy_indexing():
                           np.concatenate([[0], np.cumsum(lens32[idx][:-1])]))
 
 
-def test_gather_block_uniform_native_matches_fancy_indexing():
-    rng = np.random.default_rng(9)
-    n, klen, vlen = 300, 12, 40
-    key_arena = rng.integers(0, 256, size=n * klen, dtype=np.uint8)
-    val_arena = rng.integers(0, 256, size=n * vlen, dtype=np.uint8)
-    expire = rng.integers(0, 1000, size=n, dtype=np.uint32)
-    hash32 = rng.integers(0, 1 << 32, size=n, dtype=np.uint32)
-    deleted = rng.random(n) < 0.2
-    idx = rng.permutation(n)[:150].astype(np.int32)
+# ------------------------------------------------------- by-run gather
+
+
+def _uniform_run(rng, n, klen, vlen):
+    from pegasus_tpu.engine.block import KVBlock
+
+    return KVBlock(
+        rng.integers(0, 256, size=n * klen, dtype=np.uint8),
+        np.arange(n, dtype=np.int64) * klen, np.full(n, klen, np.int32),
+        rng.integers(0, 256, size=n * vlen, dtype=np.uint8),
+        np.arange(n, dtype=np.int64) * vlen, np.full(n, vlen, np.int32),
+        rng.integers(0, 1000, size=n, dtype=np.uint32),
+        rng.integers(0, 1 << 32, size=n, dtype=np.uint32),
+        rng.random(n) < 0.2)
+
+
+def _gather_by_run(runs, klen, vlen, idx, use_native, with_vals=True):
     m = len(idx)
-    out_k = np.empty(m * klen, np.uint8)
-    out_v = np.empty(m * vlen, np.uint8)
-    out_e = np.empty(m, np.uint32)
-    out_h = np.empty(m, np.uint32)
-    out_d = np.empty(m, np.bool_)
-    assert native.gather_block_uniform(key_arena, klen, val_arena, vlen,
-                                       expire, hash32, deleted, idx,
-                                       out_k, out_v, out_e, out_h, out_d)
-    assert np.array_equal(out_k.reshape(m, klen),
-                          key_arena.reshape(n, klen)[idx])
-    assert np.array_equal(out_v.reshape(m, vlen),
-                          val_arena.reshape(n, vlen)[idx])
-    assert np.array_equal(out_e, expire[idx])
-    assert np.array_equal(out_h, hash32[idx])
-    assert np.array_equal(out_d, deleted[idx])
+    out_k = np.zeros((m, klen), np.uint8)
+    out_v = np.zeros((m, vlen), np.uint8) if with_vals else None
+    out_e = np.zeros(m, np.uint32)
+    out_h = np.zeros(m, np.uint32)
+    out_d = np.zeros(m, np.bool_)
+    native.gather_runs_uniform(runs, klen, vlen, idx, out_k, out_v, out_e,
+                               out_h, out_d, use_native=use_native)
+    return out_k, out_v, out_e, out_h, out_d
+
+
+@pytest.mark.parametrize("use_native", [True, False],
+                         ids=["native", "numpy_twin"])
+@pytest.mark.parametrize("k", [1, 4, 9, 40])
+def test_gather_runs_uniform_matches_concat_gather(k, use_native):
+    """The by-run gather (native loop and numpy twin; the counted scan at
+    K <= 8, the binary search above) equals KVBlock.concat(runs).gather(idx)
+    column for column, first and last row of every run among the indices;
+    the keys-only half leaves the values alone."""
+    from pegasus_tpu.engine.block import KVBlock
+
+    rng = np.random.default_rng(100 + k)
+    klen, vlen = 12, 40
+    runs = [_uniform_run(rng, int(rng.integers(1, 90)), klen, vlen)
+            for _ in range(k)]
+    starts = np.cumsum([0] + [r.n for r in runs])
+    edges = np.concatenate([starts[:-1], starts[1:] - 1])
+    idx = np.concatenate([edges, rng.integers(0, starts[-1], size=300)])
+    idx = rng.permutation(idx).astype(np.int32)
+    want = KVBlock.concat(runs).gather(idx)
+    out_k, out_v, out_e, out_h, out_d = _gather_by_run(
+        runs, klen, vlen, idx, use_native)
+    assert np.array_equal(out_k.reshape(-1), want.key_arena)
+    assert np.array_equal(out_v.reshape(-1), want.val_arena)
+    assert np.array_equal(out_e, want.expire_ts)
+    assert np.array_equal(out_h, want.hash32)
+    assert np.array_equal(out_d, want.deleted)
+    out_k, out_v, out_e, out_h, out_d = _gather_by_run(
+        runs, klen, vlen, idx, use_native, with_vals=False)
+    assert out_v is None
+    assert np.array_equal(out_k.reshape(-1), want.key_arena)
+    assert np.array_equal(out_e, want.expire_ts)
+    assert np.array_equal(out_h, want.hash32)
+    assert np.array_equal(out_d, want.deleted)
+
+
+@pytest.mark.parametrize("use_native", [True, False],
+                         ids=["native", "numpy_twin"])
+@pytest.mark.parametrize("bad", [-1, "total", 1 << 40])
+def test_gather_runs_uniform_refuses_out_of_range_index(bad, use_native):
+    """The 'device pipeline bug' check, against the runs' total: an index
+    outside [0, total) raises before any pointer arithmetic (an int64 one
+    before it could wrap into range as int32)."""
+    rng = np.random.default_rng(5)
+    runs = [_uniform_run(rng, n, 8, 16) for n in (7, 3, 11)]
+    total = sum(r.n for r in runs)
+    idx = np.array([0, total - 1, total if bad == "total" else bad],
+                   np.int64)
+    with pytest.raises(ValueError, match="device pipeline bug"):
+        _gather_by_run(runs, 8, 16, idx, use_native)
+    # in range, the same call gathers
+    _gather_by_run(runs, 8, 16, idx[:2], use_native)

@@ -82,9 +82,8 @@ def ensure(quiet: bool = False, force: bool = False) -> dict:
     status in {fresh, rebuilt, missing-compiler, build-failed,
     missing-source}. Never raises: any failure means the pure-Python
     twins serve (loudly, unless quiet). force=True rebuilds regardless
-    of mtimes (chip_smoke.py: the artifacts are gitignored leftovers a
-    fresh checkout does not have, and a copied tree's mtimes prove
-    nothing)."""
+    of mtimes (chip_smoke.py: a copied tree's mtimes prove nothing, of
+    the committed libhostops.so or of a gitignored leftover)."""
     statuses = {}
     for label, src, out, cc in _targets():
         if not os.path.exists(src):
