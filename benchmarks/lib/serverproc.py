@@ -37,8 +37,14 @@ def profiler_switch(control: str) -> None:
     markers.put(p("trace.stopped"), repr(time.time()))
     window_s = float(markers.wait(p("trace.reduce")))
     try:
+        t0 = time.monotonic()
         planes = tracered.load(p("trace"))
+        t1 = time.monotonic()
         out = tracered.reduce(planes, window_s)
+        out["load_s"], out["reduce_s"] = t1 - t0, time.monotonic() - t1
+        print(f"[bench] trace of {window_s:.1f}s: tracered.load "
+              f"{out['load_s']:.1f}s, tracered.reduce {out['reduce_s']:.1f}s",
+              flush=True)
         tracered.debug_dump(planes)
     except Exception as e:  # noqa: BLE001 - told to the parent, which fails the run
         out = {"error": repr(e)}

@@ -17,6 +17,7 @@ in for it so the same code runs.
 import glob
 import os
 import re
+from bisect import bisect_left, bisect_right
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 HOST_PLANE = "/host:CPU"
@@ -101,6 +102,7 @@ def reduce(planes: list, window_s: float) -> dict:
            "spans": _totals([(n[6:], d) for n, _, d in bench])}
     if not chips:
         return out
+    index = _HostIndex(host)
     busy, op_s, gaps = 0.0, {}, {}
     for ops, modules in chips:
         merged = union([(s, s + d) for _, s, d in ops if d > 0])
@@ -113,28 +115,68 @@ def reduce(planes: list, window_s: float) -> dict:
                                            {"count": 0, "total_s": 0.0})
             p["count"] += 1
             p["total_s"] += d / 1e9
-        for a, b in zip(merged, merged[1:]):
-            what = _host_doing(a[1], b[0], bench, host)
-            gaps[what] = gaps.get(what, 0.0) + (b[0] - a[1]) / 1e9
+        idle = [(a[1], b[0]) for a, b in zip(merged, merged[1:])]
+        for (start, end), what in zip(idle, index.doing(idle)):
+            gaps[what] = gaps.get(what, 0.0) + (end - start) / 1e9
     out["busy_s"] = busy / len(chips)
     out["device_ops"] = _top(op_s)
     out["idle_gaps"] = _top(gaps)
     return out
 
 
-def _host_doing(start: float, end: float, bench: list, host: list) -> str:
-    """The benchmark's innermost span over the gap's middle, else the host
-    event that overlaps most of it, else `unattributed`."""
-    mid = (start + end) / 2
-    over = [e for e in bench if e[1] <= mid < e[1] + e[2]]
-    if over:
-        return min(over, key=lambda e: e[2])[0]
-    best, best_s = "unattributed", 0.0
-    for name, s, d in host:
-        lap = min(end, s + d) - max(start, s)
-        if lap > best_s:
-            best, best_s = name, lap
-    return best
+class _HostIndex:
+    """What the host was doing in an idle gap, found without walking every
+    host event for every gap. Built once per `reduce`: the host events in
+    order of their start, each with its place in the trace's own order
+    (which breaks ties, as a plain walk over the trace would). `doing`
+    then sweeps the gaps, which come in order of time, and keeps beside it
+    only the events that are open at the moment it looks at."""
+
+    def __init__(self, host: list):
+        tagged = sorted((s, s + d, d, i, name)
+                        for i, (name, s, d) in enumerate(host))
+        self.events = tagged
+        self.starts = [e[0] for e in tagged]
+        self.bench = [e for e in tagged if e[4].startswith("bench:")]
+        self.pegasus = [e for e in tagged if e[4].startswith("pegasus:")]
+
+    def doing(self, gaps: list) -> list:
+        """-> a name for each (start, end) of `gaps`, which are disjoint
+        and ascending: the benchmark's innermost span over the gap's
+        middle; else the program's innermost stage span (`pegasus:`) over
+        it; else the host event that overlaps most of the gap; else
+        `unattributed`."""
+        mids = [(a + b) / 2 for a, b in gaps]
+        names = []
+        for (a, b), bench, stage, live in zip(
+                gaps, _open_at(self.bench, mids), _open_at(self.pegasus, mids),
+                _open_at(self.events, [a for a, _ in gaps])):
+            over = bench or stage
+            if over:        # innermost: the shortest, the trace's first of equals
+                names.append(min(over, key=lambda e: (e[2], e[3]))[4])
+                continue
+            # overlap > 0 needs start < b and end > a: open at a, or
+            # starting inside the gap
+            lo, hi = bisect_right(self.starts, a), bisect_left(self.starts, b)
+            best, best_s, best_i = "unattributed", 0.0, -1
+            for s, e, _, i, name in live + self.events[lo:hi]:
+                lap = min(b, e) - max(a, s)
+                if lap > best_s or (lap == best_s and i < best_i):
+                    best, best_s, best_i = name, lap, i
+            names.append(best)
+        return names
+
+
+def _open_at(events: list, times: list):
+    """For each of the ascending `times`, the events (sorted by start)
+    with start <= t < end, as a list the caller must not keep."""
+    live, i = [], 0
+    for t in times:
+        while i < len(events) and events[i][0] <= t:
+            live.append(events[i])
+            i += 1
+        live = [e for e in live if e[1] > t]
+        yield live
 
 
 def _totals(pairs) -> dict:

@@ -30,6 +30,7 @@ import sys
 import tempfile
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 
 from benchmarks.lib import datagen, device, markers, reference
 
@@ -120,6 +121,7 @@ class Deployment:
         self.shell_out = io.StringIO()
         self.shell = Shell(self.metas, out=self.shell_out)
         self.server, self.nodes, self.children = None, [], []
+        self.sweep_wrong = 0
 
     # ---- plumbing over sockets
 
@@ -388,11 +390,119 @@ class Deployment:
         self.rate_thread = threading.Thread(target=run, daemon=True)
         self.rate_thread.start()
 
+    def sweep(self) -> None:
+        """Where the cell's `warm_up` has a `sweep`: the read kernels are
+        compiled per (SST shape, size of a coalesced batch), and a shape
+        or size that closed-loop traffic forms once a minute must not be
+        met first inside the window. So, before the passes: at hashkeys
+        spread evenly over every partition's share of the loaded key space
+        (so over each of its SSTs), as many clients as a step says read
+        different sortkeys of that ONE hashkey at the same moment, again
+        with other sortkeys until the server's own spans show one lookup
+        call of at least the step's `keys_in_a_call`; the whole
+        sweep again until one leaves the read lane's compile_behind and the
+        compile report's `compiled` unmoved. Writes nothing; every answer
+        is checked like any read of the window."""
+        sw = self.ctx.workload["warm_up"].get("sweep")
+        if not sw:
+            return
+        from pegasus_tpu.base import key_schema
+
+        per, parts = self.table["sortkeys"], self.table["partitions"]
+        size = self.table["value_bytes"]
+        by_part = [[] for _ in range(parts)]
+        for h in range(self.table["hashkeys"]):     # ascending = key order
+            hk = datagen.record_key(self.seed, h * per, per)[0]
+            by_part[key_schema.hash_key_hash(hk) % parts].append(h)
+        n = sw["hashkeys_per_partition"]
+        hashkeys = [hs[(2 * j + 1) * len(hs) // (2 * n)] for hs in by_part
+                    for j in range(min(n, len(hs)))]
+        rng = random.Random(self.seed)
+        wrong, failed = [], []      # appended to by the pool's threads
+        clients = [self.client()
+                   for _ in range(max(st["threads"] for st in sw["steps"]))]
+        pool = ThreadPoolExecutor(len(clients))
+
+        def read(cli, i: int) -> None:
+            """One get, judged like a read of the window: an answer that
+            is not the record's is wrong, a call that raises has failed."""
+            try:
+                value = cli.get(*datagen.record_key(self.seed, i, per))
+            except Exception as e:  # noqa: BLE001 - counted and told
+                failed.append(repr(e))
+                return
+            if datagen.check_value(self.seed, i, value, size) is None:
+                wrong.append(i)
+
+        def together(h: int, k: int) -> None:
+            """k clients, one sortkey each of hashkey h, released at once."""
+            ids = [h * per + j for j in rng.sample(range(per), k)]
+            gate = threading.Barrier(k)
+
+            def one(c: int) -> None:
+                gate.wait(60)
+                read(clients[c], ids[c])
+
+            list(pool.map(one, range(k)))
+
+        def largest_call(since: float) -> int:
+            """The most keys that one lookup call on one SST took since
+            `since` (this host's clock): the program's ring of closed stage
+            spans keeps each `read.device` with the keys it carried."""
+            text = self.shell._node_command(self.nodes[0],
+                                            "compact-trace-dump", ["200"])
+            return max((int(n) for ts, n in re.findall(
+                r"(?m)^(\d+\.\d+) +read\.device \d+us records=(\d+)", text)
+                if float(ts) >= since), default=0)
+
+        def still(health: dict) -> tuple:
+            return (health["read_lane"]["compile_behind"],
+                    health["compile"]["compiled"])
+
+        try:
+            for cli in clients:     # connect one by one: SYNs in a burst
+                for hs in by_part:  # wait a second behind the listen queue
+                    read(cli, hs[0] * per)
+            for n_sweep in range(1, sw["max_sweeps"] + 1):
+                t0, before = time.monotonic(), still(self.health())
+                told = []
+                for st in sw["steps"]:
+                    need = st["keys_in_a_call"]
+                    proven = rounds = 0
+                    for h in hashkeys:
+                        for _ in range(sw["tries"]):
+                            since = time.time()
+                            together(h, min(st["threads"], per))
+                            rounds += 1
+                            if largest_call(since) >= need:
+                                proven += 1
+                                break
+                    told.append(dict(st, hashkeys_proven=proven,
+                                     rounds=rounds))
+                after = still(self.wait_compiles(f"sweep {n_sweep}"))
+                self.ctx.say(
+                    f"sweep {n_sweep}: {len(hashkeys)} hashkeys in "
+                    f"{time.monotonic() - t0:.1f}s left compile_behind at "
+                    f"{after[0]} (+{after[0] - before[0]}), compiled at "
+                    f"{after[1]} (+{after[1] - before[1]})", steps=told,
+                    reads_failed=len(failed), errors=failed[:3])
+                if after == before:
+                    return
+            check(False, f"no sweep of {sw['max_sweeps']} left the read "
+                         f"kernels as it found them")
+        finally:
+            self.sweep_wrong = len(wrong)
+            pool.shutdown()
+            for cli in clients:
+                cli.close()
+
     def warm_up(self) -> None:
-        """The window's own mix in short passes, until one leaves the read
-        lane's compile_behind unmoved: every read kernel 16 clients can
-        ask for is then compiled (or loaded from the cache)."""
+        """A sweep, where the cell asks for one; then the window's own mix
+        in short passes, until one leaves the read lane's compile_behind
+        unmoved: every read kernel 16 clients can ask for is then compiled
+        (or loaded from the cache)."""
         wl = self.ctx.workload
+        self.sweep()
         self.warm = []
         for n in range(1, wl["warm_up"]["max_passes"] + 1):
             before = self.health()["read_lane"]["compile_behind"]
@@ -439,6 +549,12 @@ class Deployment:
             time.sleep(0.1)
         out = json.loads(markers.wait(self.marker("trace.json")))
         check("error" not in out, "trace reduction failed", out)
+        self.ctx.say(f"trace of {self.traced_s:.1f}s reduced: tracered.load "
+                     f"{out['load_s']:.1f}s, tracered.reduce "
+                     f"{out['reduce_s']:.1f}s, "
+                     f"{time.monotonic() - t0:.1f}s after the window",
+                     programs={k: v["count"]
+                               for k, v in out["programs"].items()})
         return out
 
     # ---- the comparison
@@ -650,7 +766,7 @@ def run(ctx) -> dict:
         "device": dict(ident, memory_peak_bytes=int(
             memory.get("peak_bytes_in_use") or 0)),
         "compared": [
-            ("reads_wrong", ops["wrong"], 0),
+            ("reads_wrong", ops["wrong"] + dep.sweep_wrong, 0),
             ("updates_lost", back["updates_lost"], 0),
             ("untouched_changed", back["untouched_changed"], 0),
             ("replicas_differing", audit["replicas_differing"], 0),

@@ -5,6 +5,7 @@ own sizes (on the chip's host, three seeds):
 
     python3 benchmarks/tests/controls.py compact10m 3
     python3 benchmarks/tests/controls.py ycsb1kb 3
+    python3 benchmarks/tests/controls.py ycsb1kb 3 c     (the mix of ycsb1kb.c)
 """
 
 import json
@@ -55,13 +56,18 @@ def compaction_control(seed: int, fill: dict, now: int, broken: str) -> dict:
                 reference.differing_rows(flat(want), flat(want))}
 
 
+MIX_A = {"read": 0.5, "update": 0.5}
+
+
 def served_control(seed: int, broken, hashkeys: int, seconds: float,
                    threads: int = 4, sortkeys: int = 100,
-                   value_bytes: int = 1000) -> dict:
+                   value_bytes: int = 1000, mix: dict = MIX_A) -> dict:
     """The cell's own client threads and judge against a ReferenceStore.
     `stale_reads`: a read does not see the newest acknowledged write.
     `lose_every`: an acknowledged write is stored nowhere. `alter_every`:
-    an answer's bytes are not the bytes written."""
+    an answer's bytes are not the bytes written. A mix that never updates
+    (`ycsb1kb.c`) has acknowledged writes only in its load, so there the
+    load goes through the store's `set`, where `lose_every` loses them."""
     knobs = {None: {}, "stale_reads": {"stale_reads": True},
              "lose_every": {"lose_every": 5},
              "alter_every": {"alter_every": 7}}[broken]
@@ -69,11 +75,15 @@ def served_control(seed: int, broken, hashkeys: int, seconds: float,
     records = hashkeys * sortkeys
     for i in range(records):
         hk, sk = datagen.record_key(seed, i, sortkeys)
-        store._rows[(hk, sk)] = datagen.make_value(seed, i, 0, 0, value_bytes)
+        value = datagen.make_value(seed, i, 0, 0, value_bytes)
+        if mix["update"]:
+            store._rows[(hk, sk)] = value
+        else:
+            store.set(hk, sk, value)
     spec = {"seed": seed, "process": 0, "threads": threads, "writer_base": 1,
             "records": records, "sortkeys": sortkeys,
             "value_bytes": value_bytes, "theta": 0.99,
-            "mix": {"read": 0.5, "update": 0.5}, "timeout_s": 10.0}
+            "mix": mix, "timeout_s": 10.0}
     workers = [clientproc.Worker(spec, t, cli=store) for t in range(threads)]
     start = time.monotonic() + 0.01
     pool = [threading.Thread(target=w.loop, args=(start, start + seconds))
@@ -98,6 +108,11 @@ def served_control(seed: int, broken, hashkeys: int, seconds: float,
 def main() -> int:
     name, n_seeds = sys.argv[1], int(sys.argv[2])
     cfg = load_config(name)
+    mix = MIX_A
+    if len(sys.argv) > 3:
+        with open(os.path.join(ROOT, "benchmarks", "workloads",
+                               f"{name}.{sys.argv[3]}.json")) as f:
+            mix = json.load(f)["mix"]
     for seed in [2_147_483_900 + 7 * k for k in range(n_seeds)]:
         t = time.monotonic()
         if cfg["runner"] == "engine_compact":
@@ -108,10 +123,12 @@ def main() -> int:
                     f"{time.monotonic() - t:.0f}s", flush=True)
         else:
             for broken in (None, "stale_reads", "lose_every", "alter_every"):
-                print(name, seed, broken, json.dumps(served_control(
+                if broken == "stale_reads" and not mix["update"]:
+                    continue        # nothing is ever newer than the load
+                print(name, mix, seed, broken, json.dumps(served_control(
                     seed, broken, cfg["hashkeys"], 20.0, threads=16,
                     sortkeys=cfg["table"]["sortkeys"],
-                    value_bytes=cfg["table"]["value_bytes"])),
+                    value_bytes=cfg["table"]["value_bytes"], mix=mix)),
                     f"{time.monotonic() - t:.0f}s", flush=True)
     return 0
 

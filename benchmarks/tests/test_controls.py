@@ -28,3 +28,20 @@ def test_served_control_is_not_correct(seed, broken):
     bad = controls.served_control(seed, broken, hashkeys=20, seconds=0.4)
     assert (bad["reads_wrong"] + bad["updates_lost"]
             + bad["untouched_changed"]) > 0
+
+
+@pytest.mark.parametrize("seed", SEEDS)
+@pytest.mark.parametrize("broken", ["lose_every", "alter_every"])
+def test_read_only_control_is_not_correct(seed, broken):
+    """`ycsb1kb.c`'s mix: the only acknowledged writes are the load's."""
+    mix = {"read": 1.0, "update": 0.0}
+    sound = controls.served_control(seed, None, hashkeys=20, seconds=0.4,
+                                    mix=mix)
+    assert sound["reads_wrong"] == sound["updates_lost"] == 0
+    assert sound["untouched_changed"] == 0 and sound["operations"] > 100
+    bad = controls.served_control(seed, broken, hashkeys=20, seconds=0.4,
+                                  mix=mix)
+    assert bad["updates_lost"] == 0        # by construction: no update
+    assert bad["reads_wrong"] > 0
+    if broken == "lose_every":
+        assert bad["untouched_changed"] > 0

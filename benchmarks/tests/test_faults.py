@@ -3,8 +3,8 @@ to come out false. The harness's look for a chip is skipped (--rehearsal);
 everything else is the run as the driver makes it.
 
 Faults each cell can have: a step that returns its state unchanged, half
-of the batch left out, an answer altered where it is produced. (Neither
-cell exchanges anything between chips.)
+of the batch left out, an answer altered where it is produced. (No cell
+exchanges anything between chips.)
 """
 
 import json
@@ -93,16 +93,16 @@ def test_compact_an_answer_altered_where_it_is_produced(monkeypatch, capsys):
         failing(line) == {"rows_differing", "point_reads_wrong"}
 
 
-# ---- ycsb1kb.a: the engine is in the server process; lib/serverproc.py
-# plants the fault there when BENCH_FAULT is set
+# ---- ycsb1kb.a and .c: the engine is in the server process;
+# lib/serverproc.py plants the fault there when BENCH_FAULT is set
 
 
-def run_served(seed: int, fault: str = None) -> dict:
+def run_served(seed: int, fault: str = None, cell: str = "ycsb1kb.a") -> dict:
     env = dict(os.environ)
     if fault:
         env["BENCH_FAULT"] = fault
     proc = subprocess.run(
-        [sys.executable, RUN, "--workload", "ycsb1kb.a", "--seed", str(seed),
+        [sys.executable, RUN, "--workload", cell, "--seed", str(seed),
          "--seconds", "4", "--trace", "0", "--rehearsal"],
         cwd=ROOT, env=env, capture_output=True, text=True, timeout=300)
     assert proc.returncode == 0, proc.stderr[-2000:]
@@ -124,3 +124,18 @@ def test_served_answer_altered_where_it_is_produced():
     line = run_served(2_147_483_812, "alter_answer")
     assert line["correct"] is False
     assert "reads_wrong" in failing(line)
+
+
+def test_read_only_cell_answer_altered_where_it_is_produced():
+    """`ycsb1kb.c`: the sweep's and the window's reads are all it has."""
+    line = run_served(2_147_483_813, "alter_answer", "ycsb1kb.c")
+    assert line["correct"] is False
+    assert "reads_wrong" in failing(line)
+
+
+def test_read_only_cell_whose_load_left_the_state_unchanged():
+    """`ycsb1kb.c` writes only in its load: a decree applied as empty there
+    is a record that no read finds and that the audit does not count."""
+    line = run_served(2_147_483_814, "drop_update", "ycsb1kb.c")
+    assert line["correct"] is False
+    assert {"reads_wrong", "untouched_changed"} <= failing(line)

@@ -3,8 +3,11 @@ percentile, the plain reference on rows worked by hand."""
 
 import json
 import os
+import random
+import time
 
 import numpy as np
+import pytest
 
 from benchmarks.lib import datagen, reference, roofline, tracered
 
@@ -16,21 +19,7 @@ def test_union_merges_overlapping_and_touching_intervals():
 
 
 def test_reduce_on_a_hand_made_trace():
-    planes = [
-        {"name": "/device:TPU:0", "lines": [
-            {"name": "XLA Ops", "events": [
-                ("%fusion.1 = s32[8] fusion(...)", 100.0, 50.0),
-                ("%sort = (s32[8]) sort(...)", 120.0, 80.0),      # overlaps
-                ("%fusion.1 = s32[8] fusion(...)", 1000.0, 100.0)]},
-            {"name": "XLA Modules", "events": [
-                ("jit_pegasus_merge_cached(123)", 100.0, 100.0),
-                ("jit_pegasus_lookup(9)", 1000.0, 100.0)]},
-            {"name": "Steps", "events": [("0", 0.0, 5000.0)]}]},
-        {"name": "/host:CPU", "lines": [
-            {"name": "python3", "events": [
-                ("bench:step", 0.0, 2000.0),
-                ("bench:ingest", 150.0, 700.0)]}]},
-    ]
+    planes = hand_made_planes()
     out = tracered.reduce(planes, window_s=2e-6)
     assert out["busy_s"] == (100 + 100) / 1e9       # union, not sum
     assert out["programs"] == {
@@ -66,6 +55,181 @@ def test_reduce_on_the_recorded_chip_trace():
                - total) < 1e-12
     assert any(name.startswith("pegasus_") for name in out["programs"])
     assert 0 < out["busy_s"] < 5.0
+
+
+def host_doing_slow(start, end, bench, host, stages=()):
+    """The reduction's rule as it stood before PR 29, every host event
+    walked for every gap: the oracle the index is held to. `stages` (the
+    program's `pegasus:` spans) is the one step PR 29 put between."""
+    mid = (start + end) / 2
+    for spans in (bench, stages):
+        over = [e for e in spans if e[1] <= mid < e[1] + e[2]]
+        if over:
+            return min(over, key=lambda e: e[2])[0]
+    best, best_s = "unattributed", 0.0
+    for name, s, d in host:
+        lap = min(end, s + d) - max(start, s)
+        if lap > best_s:
+            best, best_s = name, lap
+    return best
+
+
+def reduce_slow(planes, window_s, stages=False):
+    """`tracered.reduce` with the oracle in the index's place."""
+    class Walk:
+        def __init__(self, host):
+            self.host = host
+            self.bench = [e for e in host if e[0].startswith("bench:")]
+            self.stages = [e for e in host if e[0].startswith("pegasus:")
+                           ] if stages else []
+
+        def doing(self, gaps):
+            return [host_doing_slow(a, b, self.bench, self.host, self.stages)
+                    for a, b in gaps]
+
+    index, tracered._HostIndex = tracered._HostIndex, Walk
+    try:
+        return tracered.reduce(planes, window_s)
+    finally:
+        tracered._HostIndex = index
+
+
+def hand_made_planes():
+    return [
+        {"name": "/device:TPU:0", "lines": [
+            {"name": "XLA Ops", "events": [
+                ("%fusion.1 = s32[8] fusion(...)", 100.0, 50.0),
+                ("%sort = (s32[8]) sort(...)", 120.0, 80.0),      # overlaps
+                ("%fusion.1 = s32[8] fusion(...)", 1000.0, 100.0)]},
+            {"name": "XLA Modules", "events": [
+                ("jit_pegasus_merge_cached(123)", 100.0, 100.0),
+                ("jit_pegasus_lookup(9)", 1000.0, 100.0)]},
+            {"name": "Steps", "events": [("0", 0.0, 5000.0)]}]},
+        {"name": "/host:CPU", "lines": [
+            {"name": "python3", "events": [
+                ("bench:step", 0.0, 2000.0),
+                ("bench:ingest", 150.0, 700.0)]}]},
+    ]
+
+
+def recorded_planes():
+    with open(os.path.join(HERE, "recorded_trace.json")) as f:
+        return json.load(f)
+
+
+def random_planes(seed, chips=2, threads=6, events=400):
+    """Nested host spans on a few threads (some the benchmark's, some the
+    program's, some jax's own, some of equal length and start) under
+    device operations with gaps between them."""
+    rng = random.Random(seed)
+    planes = []
+    for c in range(chips):
+        t, ops = 0.0, []
+        for _ in range(events // 4):
+            t += rng.choice([0.0, 3.0, 40.0, 900.0])
+            d = rng.choice([1.0, 5.0, 60.0])
+            ops.append((f"%op.{rng.randrange(5)} = f32[] add()", t, d))
+            t += d * rng.choice([0.5, 1.0])
+        planes.append({"name": f"/device:TPU:{c}", "lines": [
+            {"name": "XLA Ops", "events": ops},
+            {"name": "XLA Modules", "events": [
+                (f"jit_pegasus_k{i % 3}({i})", s, d)
+                for i, (_, s, d) in enumerate(ops)]}]})
+    lines = []
+    for th in range(threads):
+        evs = []
+        for _ in range(events // threads):
+            s = float(rng.randrange(0, 60_000))
+            d = float(rng.choice([0, 2, 2, 30, 500, 20_000]))
+            kind = rng.choice(["bench:", "pegasus:", "pegasus:", "", "", ""])
+            if kind == "bench:" and rng.random() < 0.8:
+                kind = ""           # most gaps fall through to the later rules
+            evs.append((f"{kind}t{th}.{rng.randrange(8)}", s, d))
+        lines.append({"name": f"thread-{th}", "events": evs})
+    planes.append({"name": "/host:CPU", "lines": lines})
+    return planes
+
+
+@pytest.mark.parametrize("planes,stages", [
+    (hand_made_planes, False), (recorded_planes, False)]
+    + [(lambda seed=seed: random_planes(seed), True) for seed in range(12)])
+def test_indexed_reduction_equals_the_walk_over_every_event(planes, stages):
+    """Every number of `reduce`, idle gaps and their order included, as
+    the old quadratic function gives it (with the `pegasus:` step put in,
+    where the trace has such spans)."""
+    planes = planes()
+    got = tracered.reduce(planes, 5.0)
+    want = reduce_slow(planes, 5.0, stages)
+    assert got == want
+    assert got["idle_gaps"]
+    assert json.dumps(got, sort_keys=True) == json.dumps(want, sort_keys=True)
+
+
+def test_a_stage_span_wins_over_a_longer_jax_event_and_bench_over_both():
+    def planes(*host):
+        return [
+            {"name": "/device:TPU:0", "lines": [{"name": "XLA Ops", "events": [
+                ("%a = f32[] add()", 0.0, 10.0),
+                ("%a = f32[] add()", 110.0, 10.0)]}]},
+            {"name": "/host:CPU", "lines": [
+                {"name": "t", "events": list(host)}]}]
+
+    jax_event = ("PjitFunction(pegasus_lookup)", 0.0, 200.0)
+    outer = ("pegasus:engine.get", 20.0, 90.0)
+    inner = ("pegasus:read.device", 40.0, 50.0)     # over the middle, 60
+    aside = ("pegasus:read.gather", 70.0, 30.0)     # in the gap, not over 60
+    bench = ("bench:window", 5.0, 150.0)
+    gap = 100 / 1e9
+    assert tracered.reduce(planes(jax_event), 1.0)["idle_gaps"] == [
+        ["PjitFunction(pegasus_lookup)", gap]]
+    assert tracered.reduce(planes(jax_event, outer, inner, aside), 1.0)[
+        "idle_gaps"] == [["pegasus:read.device", gap]]
+    assert tracered.reduce(planes(jax_event, aside), 1.0)["idle_gaps"] == [
+        ["PjitFunction(pegasus_lookup)", gap]]
+    assert tracered.reduce(planes(jax_event, outer, inner, bench), 1.0)[
+        "idle_gaps"] == [["bench:window", gap]]
+    assert tracered.reduce(planes(), 1.0)["idle_gaps"] == [
+        ["unattributed", gap]]
+
+
+def test_reduce_20000_gaps_over_300000_host_events_in_seconds():
+    """A served cell's trace: 80 device lookups a second and every request
+    span of 16 clients on the host. The old walk made 6e9 comparisons."""
+    rng = random.Random(29)
+    ops = [("%lookup = s32[16] fusion()", 1000.0 * i, 70.0)
+           for i in range(20_001)]
+    lines = []
+    for th in range(30):
+        evs, t = [], 0.0
+        for _ in range(2_500):                      # 4 nested spans each
+            d = rng.choice([800.0, 2_000.0, 13_000.0])
+            evs += [("pegasus:rpc.server.GET" if th % 3 else "jaxwork",
+                     t, d),
+                    ("pegasus:engine.get", t + 10, d - 20),
+                    ("pegasus:read.batch", t + 20, d - 40),
+                    ("PjitFunction(pegasus_lookup)", t + 30, d - 60)]
+            t += d + rng.choice([50.0, 4_000.0])
+        lines.append({"name": f"thread-{th}", "events": evs})
+    lines.append({"name": "main", "events": [("idle wait", 0.0, 2.1e7)]})
+    planes = [{"name": "/device:TPU:0", "lines": [
+        {"name": "XLA Ops", "events": ops}]},
+        {"name": "/host:CPU", "lines": lines}]
+    assert sum(len(ln["events"]) for ln in lines) > 300_000
+    t0 = time.monotonic()
+    out = tracered.reduce(planes, 20.0)
+    took = time.monotonic() - t0
+    assert took < 10.0, took
+    assert out["busy_s"] == pytest.approx(20_001 * 70 / 1e9)
+    assert sum(s for _, s in out["idle_gaps"]) == pytest.approx(
+        20_000 * 930 / 1e9)
+    # spot checks against the walk, which takes a second for 40 gaps
+    host = [e for ln in lines for e in ln["events"]]
+    stages = [e for e in host if e[0].startswith("pegasus:")]
+    index = tracered._HostIndex(host)
+    gaps = [(1000.0 * i + 70.0, 1000.0 * (i + 1))
+            for i in range(0, 20_000, 500)]
+    assert index.doing(gaps) == [host_doing_slow(a, b, [], host, stages)
+                                 for a, b in gaps]
 
 
 def test_merge_least_bytes_against_hand_worked_shapes():
