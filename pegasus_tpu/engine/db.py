@@ -50,6 +50,7 @@ from ..base.value_schema import check_if_ts_expired
 from ..runtime.fail_points import fail_point
 from ..runtime import events, lockrank
 from ..ops.compact import CompactOptions, compact_blocks, sort_block
+from ..ops.packing import DEFAULT_PREFIX_U32
 from .block import KVBlock
 from .memtable import Memtable
 from .sstable import CorruptionError, SSTable, verify_sst, write_sst
@@ -66,6 +67,12 @@ _C_RANGE_BATCH = _counters.number("read.range.batch_count")
 _C_RANGE_ROWS = _counters.number("read.range.rows")
 _C_RANGE_DEVICE = _counters.number("read.range.device_count")
 _C_RANGE_HOST = _counters.number("read.range.host_count")
+# (range, SST) bounds walked by SSTable.lower_bound, in whichever batch:
+# device_count above counts every range of a batch that was ELIGIBLE for
+# the device, and an SST with fewer candidates than device_read_min_batch
+# resolves here all the same. The device's own share is
+# read.range.device_ranges (ops/device_lookup.py)
+_C_RANGE_HOST_RANGES = _counters.number("read.range.host_ranges")
 _C_RANGE_REV_HOST = _counters.number("read.range.reverse_host_count")
 # monotonic totals of the two quiet device bypasses this module owns: a
 # failed residency prime (file stays host-packed) and a mesh that would
@@ -98,7 +105,10 @@ class EngineOptions:
     memtable_bytes: int = 64 << 20
     l0_compaction_trigger: int = 4
     backend: str = "cpu"            # compaction_backend: "cpu" | "tpu"
-    prefix_u32: int = 8
+    # the CAP on a run's key window, in u32 lanes (ops/packing.py): a run
+    # packs what its longest key needs, so 26 B keys take 7 lanes, the geo
+    # index table's 51 B keys 13; only keys over 64 B need suffix ranks
+    prefix_u32: int = DEFAULT_PREFIX_U32
     data_version: int = 2
     pidx: int = 0
     partition_mask: int = 0         # >0 enables split stale-key GC in compaction
@@ -831,6 +841,8 @@ class LsmEngine:
                     raise
                 lo = sst.lower_bound(start_key) if start_key else 0
                 hi = sst.lower_bound(stop_key) if stop_key is not None else b.n
+                if start_key or stop_key is not None:
+                    _C_RANGE_HOST_RANGES.increment()
             rng = range(hi - 1, lo - 1, -1) if reverse else range(lo, hi)
             for i in rng:
                 yield b.key(i), b.value(i), int(b.expire_ts[i]), bool(b.deleted[i])
@@ -957,6 +969,7 @@ class LsmEngine:
                     hi = sst.lower_bound(stop_key) \
                         if stop_key is not None else sst.n
                     bounds[qi][id(sst)] = (lo, hi)
+                _C_RANGE_HOST_RANGES.increment(len(cand))
             except CorruptionError as e:
                 self._notify_corruption(e)
                 raise

@@ -151,8 +151,13 @@ class _ReadCoalescer:
         with self._lock:
             self._queue.extend(slots)
         wait = self._wait_span()   # one span however often it parks
-        while not all(s.done for s in slots):
-            pending = next(s for s in slots if not s.done)
+        while True:
+            # ONE look at the slots decides both "all served?" and "park on
+            # which?": a slot served between two looks must not turn into
+            # a StopIteration inside a GET or scan answer
+            pending = next((s for s in slots if not s.done), None)
+            if pending is None:
+                break
             with self._lock:
                 lead = not self._draining and bool(self._queue)
                 if lead:

@@ -22,6 +22,7 @@ import threading
 from concurrent.futures import ThreadPoolExecutor
 
 from ..client import PegasusClient
+from ..runtime.tracing import REQUEST_TRACER
 from . import cells
 from .latlng_codec import LatlngCodec
 
@@ -126,20 +127,27 @@ class GeoClient:
 
     def _scan_one(self, ghk: bytes, start_sk: bytes, stop_sk: bytes,
                   lat: float, lng: float, radius_m: float) -> list:
-        out = []
-        for _, gsk, value in self.geo.get_scanner(
+        # geo.scan: one range streamed to its end (the scanner's RPCs and
+        # the rows' decoding); geo.filter: the distance test over its rows.
+        # Both close in the scan pool's threads, beside geo.search
+        with REQUEST_TRACER.span("geo.scan") as sp:
+            rows = list(self.geo.get_scanner(
                 ghk, start_sort_key=start_sk, stop_sort_key=stop_sk,
-                batch_size=500):
-            latlng = self.codec.decode(value)
-            if latlng is None:
-                continue
-            d = cells.haversine_m(lat, lng, latlng[0], latlng[1])
-            if d > radius_m:
-                continue
-            keys = _split_geo_sort_key(gsk)
-            if keys is None:
-                continue
-            out.append((d, keys[0], keys[1], value))
+                batch_size=500))
+            sp["rows"] = len(rows)
+        out = []
+        with REQUEST_TRACER.span("geo.filter", rows=len(rows)):
+            for _, gsk, value in rows:
+                latlng = self.codec.decode(value)
+                if latlng is None:
+                    continue
+                d = cells.haversine_m(lat, lng, latlng[0], latlng[1])
+                if d > radius_m:
+                    continue
+                keys = _split_geo_sort_key(gsk)
+                if keys is None:
+                    continue
+                out.append((d, keys[0], keys[1], value))
         return out
 
     def search_radial(self, lat: float, lng: float, radius_m: float,
@@ -151,9 +159,20 @@ class GeoClient:
         gen_start/stop_sort_key, geo_client.cpp:433-454), and the range
         scans run concurrently (the reference's parallel cell scans,
         geo_client.cpp:257-330)."""
+        with REQUEST_TRACER.span("geo.search") as sp:
+            out = self._search_radial(lat, lng, radius_m)
+            sp["found"] = len(out)
+        if sort_by_distance:
+            out.sort(key=lambda t: t[0])
+        if count > 0:
+            out = out[:count]
+        return out
+
+    def _search_radial(self, lat: float, lng: float, radius_m: float) -> list:
         tasks = []
-        ranges = cells.covering_ranges(lat, lng, radius_m,
-                                       self.min_level, self.max_level)
+        with REQUEST_TRACER.span("geo.cover"):
+            ranges = cells.covering_ranges(lat, lng, radius_m,
+                                           self.min_level, self.max_level)
         for cid, spans in sorted(ranges.items()):
             ghk = cells.cell_token(cid, self.min_level)
             if spans is None:
@@ -170,10 +189,6 @@ class GeoClient:
         else:
             out = [r for t in tasks
                    for r in self._scan_one(*t, lat, lng, radius_m)]
-        if sort_by_distance:
-            out.sort(key=lambda t: t[0])
-        if count > 0:
-            out = out[:count]
         return out
 
     def search_radial_by_key(self, hash_key: bytes, sort_key: bytes,

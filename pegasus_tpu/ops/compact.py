@@ -43,7 +43,8 @@ from ..runtime.fail_points import inject as _inject
 from ..runtime.perf_counters import counters as _counters
 from ..runtime.tracing import COMPACT_TRACER as _TRACE
 from .kernel import DeviceKernel
-from .packing import DEFAULT_PREFIX_U32, compute_suffix_ranks, pack_key_prefixes, pack_sbytes
+from .packing import (DEFAULT_PREFIX_U32, compute_suffix_ranks,
+                      pack_key_prefixes, pack_sbytes, window_lanes)
 
 _U32_MAX = np.uint32(0xFFFFFFFF)
 _MIN_BUCKET = 256  # pad runs to pow2 buckets >= this to bound jit recompiles
@@ -151,7 +152,7 @@ def _pack_runs_impl(runs, opts: CompactOptions, need_sbytes: bool) -> PackedRuns
     max_klen = max(int(b.key_len.max()) for b in runs)
     if max_klen >= 1 << 24:
         raise ValueError("keys >= 16MiB unsupported")
-    w = max(1, min(-(-min(max_klen, 4 * opts.prefix_u32) // 4), opts.prefix_u32))
+    w = window_lanes(max_klen, opts.prefix_u32)
     has_rank = max_klen > 4 * w
     ranks_all = None
     if has_rank:
@@ -303,9 +304,10 @@ class DeviceRun:
     """One run's cacheable device-resident packed columns — the engine's
     'HBM-resident key blocks' (SURVEY §5.7c): an SSTable packs + uploads
     these ONCE (flush prime or first device compaction) and every later
-    compaction it joins reads HBM, not PCIe. Runs whose keys exceed the
-    prefix window (suffix-rank merges) are not cacheable: ranks are global
-    to a merge set.
+    compaction it joins reads HBM, not PCIe. A run's window is as wide as
+    its longest key (packing.window_lanes); only runs with a key over the
+    cap (64 B: suffix-rank merges) are not cacheable: ranks are global to
+    a merge set.
 
     EVERY column is padded to the pow2 bucket so the jitted merge is keyed
     only on (padded_lens, run widths) — real lengths travel as traced
@@ -343,8 +345,8 @@ class DeviceRun:
 
 def pack_run_device(block, prefix_u32: int = DEFAULT_PREFIX_U32,
                     with_values: bool = False):
-    """-> DeviceRun, or None when this run cannot be cached (keys longer
-    than the prefix window need per-merge suffix ranks). The run must be
+    """-> DeviceRun, or None when this run cannot be cached (a key longer
+    than the window's cap needs per-merge suffix ranks). The run must be
     sorted (SSTs are born sorted). with_values additionally pins the value
     rows in HBM when the layout is uniform (value residency)."""
     import jax.numpy as jnp
@@ -352,7 +354,7 @@ def pack_run_device(block, prefix_u32: int = DEFAULT_PREFIX_U32,
     if block.n == 0:
         return None
     max_klen = int(block.key_len.max())
-    w = max(1, min(-(-min(max_klen, 4 * prefix_u32) // 4), prefix_u32))
+    w = window_lanes(max_klen, prefix_u32)
     if max_klen > 4 * w:
         # production policy (long keys need per-merge suffix ranks), but
         # this file will never be HBM-resident nor device-read: count it

@@ -23,10 +23,10 @@ Two pieces:
      does not already hold.)
   2. A batched lookup kernel (`lookup_batch`): queries are packed into
      the run's uint32 prefix lanes (the same packing the merge sort
-     keys use — DeviceRun runs hold the FULL key in their lanes, so
-     lane+klen equality IS full-key equality), fenced, then resolved
-     with a fixed-depth vectorized binary search. Returns each query's
-     row index in the run, or -1.
+     keys use — DeviceRun runs hold the FULL key in their lanes, up to
+     the window's 64-byte cap, so lane+klen equality IS full-key
+     equality), fenced, then resolved with a fixed-depth vectorized
+     binary search. Returns each query's row index in the run, or -1.
   3. A batched range kernel (`range_batch`): the same fence-bounded
      lower_bound run over a batch of (start, stop) bounds, resolving
      each range query to the run's contiguous row interval [lo, hi) in
@@ -65,6 +65,10 @@ _C_HITS = counters.number("read.device.hits")
 # SST whose candidate set is under the min-batch floor resolves on the
 # host inside a "device" query)
 _C_RANGE_DISPATCH = counters.number("read.range.dispatch_count")
+# (range, SST) bounds the range kernel really resolved: what a dispatch
+# carried. Its host twin, read.range.host_ranges (engine/db.py), counts
+# the bounds SSTable.lower_bound walked, inside a "device" batch or not
+_C_RANGE_DEVICE_RANGES = counters.number("read.range.device_ranges")
 # monotonic total of runs left host-served by a failed fence build
 _C_FENCE_FAIL = counters.number("read.device.fence_fail_count")
 
@@ -278,17 +282,27 @@ def range_batch(dr, ranges) -> np.ndarray:
     stops = [(t if t is not None else b"") for _, t in ranges]
     open_stop = np.fromiter((t is None for _, t in ranges),
                             dtype=bool, count=nq)
+    # the three parts of one call, each a span of its own: the host packs
+    # both bounds into the run's lanes, uploads them and dispatches the
+    # program (asynchronous), then waits for the 8 bytes a range comes to
     with _TRACE.span("read.range", records=nq):
         _inject("read.range")
-        scols, sklen = pack_queries(starts, dr.w)
-        tcols, tklen = pack_queries(stops, dr.w)
-        fn = _compiled_range(dr.padded_len, dr.w, dr.fence_len, len(sklen))
-        out = fn(tuple(dr.cols), dr.klen, dr.fence,
-                 jnp.int32(dr.n), jnp.int32(dr.fence_step),
-                 tuple(jnp.asarray(c) for c in scols), jnp.asarray(sklen),
-                 tuple(jnp.asarray(c) for c in tcols), jnp.asarray(tklen))
-        iv = np.asarray(out)[:, :nq].T.copy()
+        with _TRACE.span("read.range.pack", records=nq):
+            scols, sklen = pack_queries(starts, dr.w)
+            tcols, tklen = pack_queries(stops, dr.w)
+        with _TRACE.span("read.range.dispatch", records=nq):
+            fn = _compiled_range(dr.padded_len, dr.w, dr.fence_len,
+                                 len(sklen))
+            out = fn(tuple(dr.cols), dr.klen, dr.fence,
+                     jnp.int32(dr.n), jnp.int32(dr.fence_step),
+                     tuple(jnp.asarray(c) for c in scols),
+                     jnp.asarray(sklen),
+                     tuple(jnp.asarray(c) for c in tcols),
+                     jnp.asarray(tklen))
+        with _TRACE.span("read.range.download", records=nq):
+            iv = np.asarray(out)[:, :nq].T.copy()
     _C_RANGE_DISPATCH.increment()
+    _C_RANGE_DEVICE_RANGES.increment(nq)
     # a None stop packed as b"" would lower_bound to 0; patch to run end
     iv[open_stop, 1] = dr.n
     return iv

@@ -27,7 +27,20 @@ import struct
 
 import numpy as np
 
-DEFAULT_PREFIX_U32 = 8  # 32-byte prefix window
+# The CAP on the prefix window, in u32 lanes (64 bytes). A run's own window
+# is what its longest key needs, `window_lanes(max key length)`: 7 lanes for
+# the 26-byte YCSB / bulk-fill keys, 13 for the geo index table's 51-byte
+# ones. Up to the cap a run holds its FULL keys in its lanes (no suffix
+# ranks, HBM-resident, device-read); only beyond it does the suffix-rank
+# path below take over.
+DEFAULT_PREFIX_U32 = 16
+
+
+def window_lanes(max_key_len: int, cap_u32: int = DEFAULT_PREFIX_U32) -> int:
+    """Lanes a run of keys up to `max_key_len` bytes packs into: enough
+    for its longest key, at most the cap. The window follows the data, so
+    runs of short keys keep their narrow programs whatever the cap is."""
+    return max(1, min(-(-max_key_len // 4), cap_u32))
 
 # ---------------------------------------------------------------- run wire
 # The pack/serialize boundary for shipping whole runs between processes

@@ -28,7 +28,8 @@ from ..ops.compact import (CompactOptions, CompactResult, _apply_default_ttl,
                            _pow2ceil, _stats, apply_post_filters, merge_body,
                            sort_block)
 from ..ops.kernel import DeviceKernel
-from ..ops.packing import compute_suffix_ranks, pack_key_prefixes
+from ..ops.packing import (compute_suffix_ranks, pack_key_prefixes,
+                           window_lanes)
 from ..runtime.fail_points import inject as _inject
 from ..runtime.lane_guard import LANE_GUARD
 from ..runtime.tracing import COMPACT_TRACER as _TRACE
@@ -129,7 +130,8 @@ def sharded_compact(blocks, mesh, opts: CompactOptions, axis: str = "shard",
     block = runs[0] if len(runs) == 1 else KVBlock.concat(runs)
     prio = np.repeat(np.arange(len(runs), dtype=np.uint32), [b.n for b in runs])
     n = block.n
-    w = opts.prefix_u32
+    # as wide as the longest key needs, up to the cap (ops/packing.py)
+    w = window_lanes(int(block.key_len.max()), opts.prefix_u32)
     n_loc = _next_bucket(-(-n // nsh))
     n_pad = n_loc * nsh
 

@@ -99,15 +99,16 @@ def test_explicit_cpu_platform_opens_and_is_reported(monkeypatch, fresh_gate,
 
 
 def test_long_key_run_bypass_is_counted():
-    """A run holding a key over the 32 B prefix window is refused HBM
-    residency (production policy) — and that is now countable."""
+    """A run holding a key over the window's 64 B cap is refused HBM
+    residency (production policy) — and that is countable. (Up to the cap
+    the window follows the run's longest key: tests/test_geo_deployment.py.)"""
     from pegasus_tpu.engine.block import KVBlock
     from pegasus_tpu.ops.compact import pack_run_device
     from pegasus_tpu.runtime.perf_counters import counters
 
     c = counters.number("engine.hbm.long_key_bypass_count")
     before = c.value()
-    blk = KVBlock.from_records([(b"\x00\x02hk" + b"s" * 40, b"v", 0, False)])
+    blk = KVBlock.from_records([(b"\x00\x02hk" + b"s" * 80, b"v", 0, False)])
     assert pack_run_device(blk) is None
     assert c.value() == before + 1
 

@@ -128,15 +128,17 @@ def test_default_ttl_short_value_guarded():
 
 
 def _adversarial_records(rng, n):
-    """Keys engineered to stress prefix windows: shared 32+ byte prefixes,
-    trailing zeros, strict-prefix pairs, empty hash/sort keys."""
+    """Keys engineered to stress prefix windows: a shared prefix longer
+    than the window's 64-byte cap (the suffix-rank path), trailing zeros,
+    strict-prefix pairs, empty hash/sort keys, 62-byte keys (the widest
+    windows that still hold a whole key)."""
     recs = []
-    long_prefix = b"P" * 40
+    long_prefix = b"P" * 72
     for i in range(n):
         mode = i % 6
         if mode == 0:
             hk, sk = rng.bytes(4), rng.bytes(rng.integers(0, 6))
-        elif mode == 1:  # long keys sharing a 40-byte prefix
+        elif mode == 1:  # keys over the cap sharing a 72-byte prefix
             hk, sk = long_prefix, rng.bytes(rng.integers(0, 8))
         elif mode == 2:  # trailing zero bytes
             hk, sk = b"z", b"\x00" * rng.integers(0, 5)
@@ -192,7 +194,7 @@ def test_cpu_output_matches_python_reference_model():
 
 
 def test_prefix_collision_suffix_ranks():
-    base = b"C" * 36
+    base = b"C" * 68          # over the default window's 64 bytes
     recs = [(base, bytes([b]), b"v", 0, False) for b in [3, 1, 2, 0xFF, 0]]
     recs.append((base, b"", b"v", 0, False))  # strict prefix of the others
     blk = make_block(recs)
@@ -458,8 +460,8 @@ def test_blockwise_merge_long_keys_rank_path():
     rng = np.random.default_rng(43)
     recs = []
     for i in range(1200):
-        # 60+B hashkeys: longer than 4*prefix_u32(8)=32 bytes
-        hk = b"verylonghashkeyprefix-%038d" % rng.integers(0, 400)
+        # 80+B hashkeys: longer than the window's cap, 4*16 = 64 bytes
+        hk = b"verylonghashkeyprefix-%058d" % rng.integers(0, 400)
         recs.append((hk, b"s%d" % (i % 3), b"v%d" % i, 0, False))
     runs = [sort_block(make_block(part), CompactOptions(backend="cpu"))
             for part in (recs[:600], recs[600:])]

@@ -26,7 +26,7 @@ from pegasus_tpu.rpc.transport import RpcConnection, RpcServer
 
 class MiniCluster:
     def __init__(self, root, n_nodes=3, serve_groups=0, remote_clusters=None,
-                 cluster_id=1, fd_grace_seconds=60):
+                 cluster_id=1, fd_grace_seconds=60, options_factory=None):
         self.meta = MetaServer(str(root / "meta.json"),
                                fd_grace_seconds=fd_grace_seconds)
         self.rpc = RpcServer().start()
@@ -47,6 +47,7 @@ class MiniCluster:
         else:
             self.stubs = [ReplicaStub(str(root / f"n{i}"),
                                       [self.meta_addr],
+                                      options_factory=options_factory,
                                       remote_clusters=remote_clusters,
                                       cluster_id=cluster_id).start(0.2)
                           for i in range(n_nodes)]
