@@ -333,13 +333,17 @@ class DeviceRun:
     fence: object = None   # jnp.uint32[fence_len] or None
     fence_step: int = 0
     fence_len: int = 0
+    # n and fence_step as the device scalars the fence build made of
+    # them: every read call passes these two, made once a prime
+    n_dev: object = None
+    step_dev: object = None
 
     def nbytes(self) -> int:
         base = (len(self.cols) + 3) * 4 * self.padded_len + self.padded_len
         if self.val2d is not None:
             base += self.padded_len * self.vl0
         if self.fence is not None:
-            base += 4 * self.fence_len
+            base += 4 * self.fence_len + 8
         return base
 
 

@@ -33,6 +33,15 @@ Two pieces:
      one dispatch — the device half of engine scan_range_batch
      (multi_get hash ranges, sortkey_count, scanner batches).
 
+ONE UPLOAD A CALL (`_device_call`): a read call hands the chip one host
+array, the packed query image `uint32[(w + 1), qpad]` (rows 0..w-1 the
+lanes, row w the lengths; a range call stacks its two bounds), and
+launches one program. The run's two scalars (`n`, the fence step) were
+made on the device with the fence and stay on the `DeviceRun`. A round
+through the runtime costs a handler thread the GIL, which sixteen others
+want back (PERF.md section 5), so what a call costs is how many rounds
+it makes, not the bytes that cross.
+
 The kernel returns INDICES only; the host materializes values from the
 SST's cached block exactly like the host binary search does, so the
 device path is byte-identical to `SSTable.find` by construction. Every
@@ -133,11 +142,13 @@ def _compiled_fence_build(padded_len: int, fence_len: int):
 
 def build_fence_index(dr) -> bool:
     """Attach the fence-pointer index to a DeviceRun in place (fields
-    `fence`, `fence_step`, `fence_len`). Computed on device from the
-    resident first key lane — the compaction/flush pass calls this right
-    after the upload, so the index is a byproduct of work already done.
-    Returns False (and leaves the run index-less, i.e. host-served) on
-    any backend failure."""
+    `fence`, `fence_step`, `fence_len`, and the device scalars `n_dev`,
+    `step_dev` the build made of `n` and the step: every read call of
+    the run's life passes those two, so none makes its own). Computed on
+    device from the resident first key lane — the compaction/flush pass
+    calls this right after the upload, so the index is a byproduct of
+    work already done. Returns False (and leaves the run index-less,
+    i.e. host-served) on any backend failure."""
     import jax.numpy as jnp
 
     if dr is None or dr.n == 0:
@@ -146,15 +157,22 @@ def build_fence_index(dr) -> bool:
     step = -(-dr.n // fence_len)  # ceil: fence_len * step >= n
     try:
         fn = _compiled_fence_build(dr.padded_len, fence_len)
-        dr.fence = fn(dr.cols[0], jnp.int32(dr.n), jnp.int32(step))
+        dr.n_dev, dr.step_dev = jnp.int32(dr.n), jnp.int32(step)
+        dr.fence = fn(dr.cols[0], dr.n_dev, dr.step_dev)
         dr.fence_step = step
         dr.fence_len = fence_len
         return True
     except Exception as e:  # noqa: BLE001 - an index-less run is just host-served
         _C_FENCE_FAIL.increment()
         print(f"[device-lookup] fence build failed: {e!r}", flush=True)
-        dr.fence = None
+        dr.fence = dr.n_dev = dr.step_dev = None
         return False
+
+
+def _image_rows(image, w: int):
+    """Trace-time: one packed query image `uint32[(w + 1), qpad]` -> the
+    (qcols, qklen) that _fence_lower_bound takes."""
+    return [image[j] for j in range(w)], image[w]
 
 
 @functools.lru_cache(maxsize=256)
@@ -170,9 +188,10 @@ def _compiled_lookup(padded_len: int, w: int, fence_len: int, qpad: int):
 
     steps = max(1, padded_len.bit_length())
 
-    def fn(cols, klen, fence, n, step, qcols, qklen):
+    def fn(cols, klen, fence, n, step, image):
         import jax
 
+        qcols, qklen = _image_rows(image, w)
         lo = _fence_lower_bound(jnp, lex_less, padded_len, w, fence_len,
                                 steps, cols, klen, fence, n, step,
                                 qcols, qklen)
@@ -187,13 +206,13 @@ def _compiled_lookup(padded_len: int, w: int, fence_len: int, qpad: int):
     return DeviceKernel(fn, "lookup")
 
 
-def pack_queries(keys, w: int):
-    """Host-side packing of query keys into a run's lane layout:
-    -> (list of w uint32[qpad] lanes, uint32[qpad] klen), zero-padded to
-    the pow2 query bucket. A query longer than the run's 4*w-byte window
-    truncates in the lanes but keeps its true klen — it can never equal
-    a resident key (all <= 4*w bytes), so the equality check still
-    returns -1 for it, which is the correct answer."""
+def pack_queries(keys, w: int) -> np.ndarray:
+    """Host-side packing of query keys into a run's lane layout: -> ONE
+    uint32[(w + 1), qpad] image, rows 0..w-1 the lanes and row w the key
+    lengths, zero-padded to the pow2 query bucket. A query longer than
+    the run's 4*w-byte window truncates in the lanes but keeps its true
+    klen — it can never equal a resident key (all <= 4*w bytes), so the
+    equality check still returns -1 for it, which is the correct answer."""
     n = len(keys)
     arena = np.frombuffer(b"".join(keys), dtype=np.uint8).copy() \
         if n else np.zeros(0, np.uint8)
@@ -201,16 +220,34 @@ def pack_queries(keys, w: int):
     offs = np.zeros(n, dtype=np.int64)
     if n:
         np.cumsum(lens[:-1], out=offs[1:])
-    pref = pack_key_prefixes(arena, offs, lens, w)
-    qpad = _pow2ceil(max(1, n), _QUERY_MIN_BUCKET)
-    qcols = []
-    for j in range(w):
-        col = np.zeros(qpad, np.uint32)
-        col[:n] = pref[:, j]
-        qcols.append(col)
-    qklen = np.zeros(qpad, np.uint32)
-    qklen[:n] = lens
-    return qcols, qklen
+    image = np.zeros((w + 1, _pow2ceil(max(1, n), _QUERY_MIN_BUCKET)),
+                     np.uint32)
+    image[:w, :n] = pack_key_prefixes(arena, offs, lens, w).T
+    image[w, :n] = lens
+    return image
+
+
+def _upload(image: np.ndarray):
+    """The one host->device transfer of a read call."""
+    import jax
+
+    return jax.device_put(image)
+
+
+def _device_call(stage: str, dr, compiled, nq: int, pack) -> np.ndarray:
+    """The three parts of one device read call, each a span of its own
+    under the caller's `stage` span: the host packs the queries into one
+    image (`pack()`), uploads it and launches the program (asynchronous),
+    then waits for the few bytes a query comes to. Nothing else crosses:
+    the run's columns, fence and scalars are resident."""
+    with _TRACE.span(stage + ".pack", records=nq):
+        image = pack()
+    with _TRACE.span(stage + ".dispatch", records=nq):
+        fn = compiled(dr.padded_len, dr.w, dr.fence_len, image.shape[-1])
+        out = fn(tuple(dr.cols), dr.klen, dr.fence, dr.n_dev, dr.step_dev,
+                 _upload(image))
+    with _TRACE.span(stage + ".download", records=nq):
+        return np.asarray(out)
 
 
 def lookup_batch(dr, keys) -> np.ndarray:
@@ -219,19 +256,12 @@ def lookup_batch(dr, keys) -> np.ndarray:
     exact match, -1 for absent keys. Raises on device failure — the
     caller (engine/db.py get_batch) runs this under READ_LANE_GUARD with
     the host binary-search walk as the byte-identical fallback."""
-    import jax.numpy as jnp
-
     if not keys or dr is None or dr.fence is None:
         return np.full(len(keys), -1, np.int32)
     with _TRACE.span("read.device", records=len(keys)):
         _inject("read.device")
-        qcols, qklen = pack_queries(keys, dr.w)
-        fn = _compiled_lookup(dr.padded_len, dr.w, dr.fence_len,
-                              len(qklen))
-        out = fn(tuple(dr.cols), dr.klen, dr.fence,
-                 jnp.int32(dr.n), jnp.int32(dr.fence_step),
-                 tuple(jnp.asarray(c) for c in qcols), jnp.asarray(qklen))
-        rows = np.asarray(out)[: len(keys)]
+        rows = _device_call("read.device", dr, _compiled_lookup, len(keys),
+                            lambda: pack_queries(keys, dr.w))[: len(keys)]
     _C_LOOKUPS.increment()
     _C_KEYS.increment(len(keys))
     _C_HITS.increment(int((rows >= 0).sum()))
@@ -242,22 +272,23 @@ def lookup_batch(dr, keys) -> np.ndarray:
 def _compiled_range(padded_len: int, w: int, fence_len: int, qpad: int):
     """Jitted batched range resolve for one (run shape, query bucket):
     the point kernel's fence-bounded lower_bound run TWICE — once over
-    the start keys, once over the stop keys — in one program, yielding
-    each query's contiguous row interval [lo, hi). Keyed on the padded
-    bucket lengths like _compiled_lookup so live sizes share programs."""
+    the start keys, once over the stop keys, the two images of one
+    uint32[2, w + 1, qpad] — in one program, yielding each query's
+    contiguous row interval [lo, hi). Keyed on the padded bucket lengths
+    like _compiled_lookup so live sizes share programs."""
     import jax.numpy as jnp
 
     from .device_sort import lex_less
 
     steps = max(1, padded_len.bit_length())
 
-    def fn(cols, klen, fence, n, step, scols, sklen, tcols, tklen):
-        lo = _fence_lower_bound(jnp, lex_less, padded_len, w, fence_len,
-                                steps, cols, klen, fence, n, step,
-                                scols, sklen)
-        hi = _fence_lower_bound(jnp, lex_less, padded_len, w, fence_len,
-                                steps, cols, klen, fence, n, step,
-                                tcols, tklen)
+    def fn(cols, klen, fence, n, step, images):
+        def bound(image):
+            return _fence_lower_bound(jnp, lex_less, padded_len, w,
+                                      fence_len, steps, cols, klen, fence,
+                                      n, step, *_image_rows(image, w))
+
+        lo, hi = bound(images[0]), bound(images[1])
         # a stop below the start (empty/inverted range) clamps to empty
         return jnp.stack([lo, jnp.maximum(hi, lo)])
 
@@ -268,13 +299,11 @@ def range_batch(dr, ranges) -> np.ndarray:
     """Resolve each (start_key, stop_key) query against one HBM-resident
     run: -> np.int32[(len(ranges), 2)], each row the run's contiguous
     row interval [lo, hi) holding exactly the keys in [start, stop).
-    stop_key None means "to the end of the run". Both bounds resolve in
-    ONE kernel dispatch and ONE coalesced download per run per batch.
-    Raises on device failure — the caller (engine/db.py
-    scan_range_batch) runs this under READ_LANE_GUARD with the host
-    SSTable.lower_bound walk as the byte-identical fallback."""
-    import jax.numpy as jnp
-
+    stop_key None means "to the end of the run". Both bounds cross in
+    ONE upload, resolve in ONE kernel dispatch and come back in ONE
+    download per run per batch. Raises on device failure — the caller
+    (engine/db.py scan_range_batch) runs this under READ_LANE_GUARD with
+    the host SSTable.lower_bound walk as the byte-identical fallback."""
     nq = len(ranges)
     if not nq or dr is None or dr.fence is None:
         return np.zeros((nq, 2), np.int32)
@@ -282,25 +311,12 @@ def range_batch(dr, ranges) -> np.ndarray:
     stops = [(t if t is not None else b"") for _, t in ranges]
     open_stop = np.fromiter((t is None for _, t in ranges),
                             dtype=bool, count=nq)
-    # the three parts of one call, each a span of its own: the host packs
-    # both bounds into the run's lanes, uploads them and dispatches the
-    # program (asynchronous), then waits for the 8 bytes a range comes to
     with _TRACE.span("read.range", records=nq):
         _inject("read.range")
-        with _TRACE.span("read.range.pack", records=nq):
-            scols, sklen = pack_queries(starts, dr.w)
-            tcols, tklen = pack_queries(stops, dr.w)
-        with _TRACE.span("read.range.dispatch", records=nq):
-            fn = _compiled_range(dr.padded_len, dr.w, dr.fence_len,
-                                 len(sklen))
-            out = fn(tuple(dr.cols), dr.klen, dr.fence,
-                     jnp.int32(dr.n), jnp.int32(dr.fence_step),
-                     tuple(jnp.asarray(c) for c in scols),
-                     jnp.asarray(sklen),
-                     tuple(jnp.asarray(c) for c in tcols),
-                     jnp.asarray(tklen))
-        with _TRACE.span("read.range.download", records=nq):
-            iv = np.asarray(out)[:, :nq].T.copy()
+        iv = _device_call(
+            "read.range", dr, _compiled_range, nq,
+            lambda: np.stack([pack_queries(starts, dr.w),
+                              pack_queries(stops, dr.w)]))[:, :nq].T.copy()
     _C_RANGE_DISPATCH.increment()
     _C_RANGE_DEVICE_RANGES.increment(nq)
     # a None stop packed as b"" would lower_bound to 0; patch to run end

@@ -703,3 +703,328 @@ def test_range_batch_intervals_match_host_lower_bound(tmp_path):
                 (start, stop)
     finally:
         eng.close()
+
+
+# --------------------------------------------- one upload a call (ISSUE 31)
+#
+# A device read call hands the chip ONE host array (the packed query
+# image) and launches ONE program; the run's scalars are resident with
+# its fence. Held at every window width the benchmark's tables pack
+# (7 lanes: 26 B keys, 13: the geo index's 51 B, 16: the cap) and on both
+# sides of each query bucket (8, 16, 32).
+
+LANES = (7, 13, 16)
+QUERIES = (1, 8, 9, 16, 17)
+
+
+def _lane_block(w, n, tag=b"k", ragged=True):
+    """A sorted run of n distinct keys whose longest packs exactly w
+    lanes; every third key is shorter (unless not `ragged`), so lengths
+    break lane ties."""
+    from pegasus_tpu.engine.block import KVBlock
+    from pegasus_tpu.ops.compact import sort_block
+
+    def sort_key(i):
+        short = 5 if ragged and i % 3 == 0 else 0
+        return (tag + b"%06d" % (i * 7)).ljust(4 * w - 4 - short, b".")
+
+    keys = {key_schema.generate_key(b"h%d" % (i % 5), sort_key(i))
+            for i in range(n)}
+    assert len(keys) == n and max(map(len, keys)) == 4 * w
+    return sort_block(KVBlock.from_records(
+        [(k, V + b"v", 0, False) for k in keys]))
+
+
+def _lane_sst(w, n=300, ragged=True):
+    """An in-memory SST over such a run, primed as a flush primes it."""
+    from pegasus_tpu.engine.sstable import SSTable
+
+    sst = SSTable.from_block("mem-%d-%d" % (w, n),
+                             _lane_block(w, n, ragged=ragged))
+    sst.device_run(16)
+    assert sst.device_index is not None and sst.device_index.w == w
+    return sst
+
+
+@pytest.fixture(scope="module")
+def lane_ssts():
+    return {w: _lane_sst(w) for w in LANES}
+
+
+def _query_pool(sst):
+    """Present keys, absent ones on both sides of a row, keys longer than
+    any window (64 B), a bare prefix, the empty key."""
+    k = [sst.block().key(i) for i in range(sst.n)]
+    return [k[0], k[7] + b"x" * 70, k[11] + b"\x00", k[-1], k[40][:-1],
+            k[5], k[200][:9], b"", k[-1] + b"\xff", k[123], b"\xff" * 80,
+            k[17], k[2] + b"y" * 3]
+
+
+def _rotations(pool, nq):
+    """Every call of exactly nq queries that starts at another kind."""
+    return [[pool[(r + i) % len(pool)] for i in range(nq)]
+            for r in range(len(pool))]
+
+
+def _old_pack_queries(keys, w):
+    """The per-column packing `pack_queries` had before ISSUE 31, kept as
+    the reference: -> (w uint32[qpad] lanes, uint32[qpad] lengths)."""
+    from pegasus_tpu.ops.compact import _pow2ceil
+    from pegasus_tpu.ops.packing import pack_key_prefixes
+
+    n = len(keys)
+    arena = np.frombuffer(b"".join(keys), dtype=np.uint8).copy() \
+        if n else np.zeros(0, np.uint8)
+    lens = np.fromiter((len(k) for k in keys), dtype=np.int32, count=n)
+    offs = np.zeros(n, dtype=np.int64)
+    if n:
+        np.cumsum(lens[:-1], out=offs[1:])
+    pref = pack_key_prefixes(arena, offs, lens, w)
+    qpad = _pow2ceil(max(1, n), 8)
+    qcols = []
+    for j in range(w):
+        col = np.zeros(qpad, np.uint32)
+        col[:n] = pref[:, j]
+        qcols.append(col)
+    qklen = np.zeros(qpad, np.uint32)
+    qklen[:n] = lens
+    return qcols, qklen
+
+
+@pytest.mark.parametrize("nq", QUERIES)
+@pytest.mark.parametrize("w", LANES)
+def test_pack_queries_is_one_image_whose_rows_are_the_old_columns(
+        lane_ssts, w, nq):
+    from pegasus_tpu.ops.device_lookup import pack_queries
+
+    for keys in _rotations(_query_pool(lane_ssts[w]), nq):
+        image = pack_queries(keys, w)
+        assert isinstance(image, np.ndarray) and image.dtype == np.uint32
+        assert image.shape == (w + 1, max(8, 1 << (nq - 1).bit_length()))
+        assert image.flags.c_contiguous
+        qcols, qklen = _old_pack_queries(keys, w)
+        for j in range(w):
+            assert np.array_equal(image[j], qcols[j]), j
+        assert np.array_equal(image[w], qklen)
+        assert [int(n) for n in image[w, :nq]] == [len(k) for k in keys]
+
+
+@pytest.mark.parametrize("nq", QUERIES)
+@pytest.mark.parametrize("w", LANES)
+def test_lookup_rows_equal_sstable_find(lane_ssts, w, nq):
+    from pegasus_tpu.ops.device_lookup import lookup_batch
+
+    sst = lane_ssts[w]
+    for keys in _rotations(_query_pool(sst), nq):
+        rows = lookup_batch(sst.device_index, keys)
+        assert rows.shape == (nq,)
+        assert [int(r) for r in rows] == [sst.find(k) for k in keys], keys
+
+
+@pytest.mark.parametrize("nq", QUERIES)
+@pytest.mark.parametrize("w", LANES)
+def test_range_intervals_equal_lower_bound_pairs(lane_ssts, w, nq):
+    from pegasus_tpu.ops.device_lookup import range_batch
+
+    sst = lane_ssts[w]
+    k = [sst.block().key(i) for i in range(sst.n)]
+    pool = [(k[3], k[90]), (b"", None), (k[10] + b"\x00", k[30] + b"zz"),
+            (k[50] + b"q" * 70, None),           # longer than any window
+            (k[99], k[4]),                       # inverted
+            (k[8], k[8]),                        # empty
+            (k[-1] + b"\xff", None),             # past the end
+            (b"", k[20][:9]), (k[0], k[5] + b"w" * 70), (k[150], None),
+            (k[60][:-1], k[61])]
+    for ranges in _rotations(pool, nq):
+        iv = range_batch(sst.device_index, ranges)
+        assert iv.shape == (nq, 2)
+        for (start, stop), (lo, hi) in zip(ranges, iv):
+            want_lo = sst.lower_bound(start)
+            want_hi = sst.n if stop is None else sst.lower_bound(stop)
+            assert (int(lo), int(hi)) == (want_lo, max(want_hi, want_lo)), \
+                (start, stop)
+
+
+def _one_call(kind, sst, nq=5):
+    from pegasus_tpu.ops import device_lookup as dl
+
+    k = [sst.block().key(i) for i in range(sst.n)]
+    if kind == "lookup":
+        return dl.lookup_batch(sst.device_index, k[:nq])
+    return dl.range_batch(sst.device_index,
+                          [(k[i], k[i + 9]) for i in range(nq - 1)]
+                          + [(k[4], None)])
+
+
+@pytest.mark.parametrize("w", LANES)
+@pytest.mark.parametrize("kind", ("lookup", "range"))
+def test_a_read_call_is_one_upload_and_one_launch(lane_ssts, monkeypatch,
+                                                  kind, w):
+    """Counted BOTH ways. Through the helper's one upload seam
+    (`_upload`), and through `jax.transfer_guard_host_to_device`, which
+    XLA:CPU honours: under "disallow_explicit" every host->device
+    transfer raises, a `jax.device_put`, a numpy argument and the Python
+    number inside a `jnp.int32(...)` alike (the two eager
+    `convert_element_type` programs a call used to launch were such
+    numbers), so the call only passes if its one transfer is the seam's.
+    Launches are counted at DeviceKernel, the one door to a program, and
+    every argument of the launch has to be on the device already."""
+    import jax
+
+    from pegasus_tpu.ops import device_lookup as dl
+    from pegasus_tpu.ops.kernel import DeviceKernel
+
+    sst = lane_ssts[w]
+    want = _one_call(kind, sst)          # compiled before the guard
+    uploads, launches = [], []
+    real_upload, real_call = dl._upload, DeviceKernel.__call__
+
+    def upload(image):
+        uploads.append(image)
+        with jax.transfer_guard_host_to_device("allow"):
+            return real_upload(image)
+
+    def call(self, *args):
+        launches.append(self.name)
+        assert all(isinstance(a, jax.Array)
+                   for a in jax.tree_util.tree_leaves(args))
+        return real_call(self, *args)
+
+    monkeypatch.setattr(dl, "_upload", upload)
+    monkeypatch.setattr(DeviceKernel, "__call__", call)
+    with jax.transfer_guard_host_to_device("disallow_explicit"):
+        got = _one_call(kind, sst)
+        with pytest.raises(Exception, match="host-to-device"):
+            jax.numpy.int32(sst.n)       # the guard does bite here
+    assert np.array_equal(got, want)
+    assert launches == [kind]
+    assert len(uploads) == 1
+    assert isinstance(uploads[0], np.ndarray)
+    assert uploads[0].shape == ((w + 1, 8) if kind == "lookup"
+                                else (2, w + 1, 8))
+
+
+@pytest.mark.parametrize("kind,parent", (("lookup", "read.device"),
+                                         ("range", "read.range")))
+def test_one_call_closes_its_parent_and_three_inner_spans_once(
+        lane_ssts, kind, parent):
+    from pegasus_tpu.runtime.tracing import COMPACT_TRACER
+
+    names = [parent] + [parent + part
+                        for part in (".pack", ".dispatch", ".download")]
+
+    def totals():
+        return {n: (counters.number(f"stage.{n}.n").value(),
+                    counters.number(f"stage.{n}.us").value())
+                for n in names}
+
+    _one_call(kind, lane_ssts[13])       # compiled outside the session
+    before = totals()
+    with COMPACT_TRACER.session() as sess:
+        _one_call(kind, lane_ssts[13])
+    after = totals()
+    assert {n: sess.stages[n]["calls"] for n in names} == \
+        dict.fromkeys(names, 1)
+    assert {n: sess.stages[n]["records"] for n in names} == \
+        dict.fromkeys(names, 5)
+    for n in names:
+        assert after[n][0] == before[n][0] + 1, n
+        assert after[n][1] >= before[n][1]
+    # the parts lie inside their parent
+    inner = sum(after[n][1] - before[n][1] for n in names[1:])
+    assert inner <= after[parent][1] - before[parent][1]
+
+
+# ------------------------------------ the run's scalars live with the run
+
+
+def test_scalars_are_made_with_the_fence_and_counted_in_nbytes(lane_ssts):
+    for w, sst in lane_ssts.items():
+        dr = sst.device_index
+        assert (int(dr.n_dev), int(dr.step_dev)) == (dr.n, dr.fence_step)
+        assert (dr.n_dev.dtype, dr.n_dev.shape) == (np.int32, ())
+        assert (dr.step_dev.dtype, dr.step_dev.shape) == (np.int32, ())
+        cols = (w + 3) * 4 * dr.padded_len + dr.padded_len
+        assert dr.nbytes() == cols + 4 * dr.fence_len + 8
+
+
+def test_scalars_follow_a_run_primed_again():
+    """Residency dropped and primed again (here: the value-residency
+    upgrade re-packs the file) makes a fresh fence AND fresh scalars."""
+    from pegasus_tpu.ops.device_lookup import lookup_batch
+
+    sst = _lane_sst(7, ragged=False)    # one layout: values can be pinned
+    first = sst.device_index
+    again = sst.device_run(16, with_values=True)
+    assert again is not first and again.val2d is not None
+    assert again.n_dev is not first.n_dev
+    assert (int(again.n_dev), int(again.step_dev)) == (sst.n,
+                                                       again.fence_step)
+    keys = [sst.block().key(i) for i in range(0, sst.n, 11)]
+    assert [int(r) for r in lookup_batch(sst.device_index, keys)] == \
+        [sst.find(k) for k in keys]
+
+
+def test_scalars_follow_the_run_a_compaction_put_in_the_same_bucket(
+        tmp_path):
+    """300 rows, then 450 after a compaction: one padded_len (512), one
+    window, ONE program for both runs, so only the resident `n` and
+    fence step tell the new run's last 150 rows from padding."""
+    from pegasus_tpu.ops import device_lookup as dl
+
+    def key(i):
+        return key_schema.generate_key(b"h%d" % (i % 3), b"s%05d" % i)
+
+    eng = LsmEngine(str(tmp_path / "db"), _engine_opts(device_reads=True))
+    try:
+        seen = []
+        for n in (300, 450):
+            for i in range(n):
+                eng.put(key(i), V + b"v%d" % i)
+            eng.flush()
+            eng.manual_compact(now=NOW)
+            (sst,) = [s for s in _prime_all(eng) if s.n]
+            dr = sst.device_index
+            assert (dr.n, dr.padded_len, int(dr.n_dev)) == (n, 512, n)
+            assert int(dr.step_dev) == dr.fence_step
+            seen.append((dl._compiled_lookup(dr.padded_len, dr.w,
+                                             dr.fence_len, 8), dr))
+            probe = [key(i) for i in (0, 299, 300, 449, 450)]
+            rows = dl.lookup_batch(dr, probe)
+            assert [int(r) for r in rows] == [sst.find(k) for k in probe]
+            assert [int(r) >= 0 for r in rows] == [i < n for i in
+                                                   (0, 299, 300, 449, 450)]
+            looked = counters.number("read.device.keys").value()
+            keys = [key(i) for i in range(0, 460, 9)]
+            assert eng.get_batch(keys, now=NOW) == [eng.get(k, now=NOW)
+                                                    for k in keys]
+            assert counters.number("read.device.keys").value() > looked
+        (fn_a, dr_a), (fn_b, dr_b) = seen
+        assert fn_a is fn_b and dr_a is not dr_b
+        assert dr_a.fence_step != dr_b.fence_step
+    finally:
+        eng.close()
+
+
+def test_a_failed_fence_build_leaves_the_run_host_served(monkeypatch):
+    from pegasus_tpu.engine.sstable import SSTable
+    from pegasus_tpu.ops import device_lookup as dl
+
+    def refuse(padded_len, fence_len):
+        raise RuntimeError("no fence today")
+
+    monkeypatch.setattr(dl, "_compiled_fence_build", refuse)
+    fails = counters.number("read.device.fence_fail_count").value()
+    looked = counters.number("read.device.lookup_count").value()
+    sst = SSTable.from_block("mem-nofence", _lane_block(7, 300))
+    dr = sst.device_run(16)
+    assert dr is not None                 # the columns are resident
+    assert (dr.fence, dr.n_dev, dr.step_dev) == (None, None, None)
+    assert dr.nbytes() == (7 + 3) * 4 * dr.padded_len + dr.padded_len
+    assert sst.device_index is None       # so the engine walks the host
+    keys = [sst.block().key(i) for i in range(4)]
+    assert [int(r) for r in dl.lookup_batch(dr, keys)] == [-1] * 4
+    assert dl.range_batch(dr, [(keys[0], None)]).tolist() == [[0, 0]]
+    assert counters.number("read.device.fence_fail_count").value() \
+        == fails + 1
+    assert counters.number("read.device.lookup_count").value() == looked
