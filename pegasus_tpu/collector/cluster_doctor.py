@@ -23,8 +23,8 @@ inflight/backlog, lane/breaker state, HBM residency — but nothing
   ``GET /health/cluster``, the ``cluster-doctor`` remote command on the
   collector, and the shell's ``cluster_doctor``.
 
-Both are pure functions over RPC surfaces: the collector app, the shell,
-``bench.py`` and ``tools/pressure_test.py`` all call the same code.
+Both are pure functions over RPC surfaces: the collector app, the shell
+and ``tools/pressure_test.py`` all call the same code.
 """
 
 import json

@@ -129,8 +129,7 @@ class EngineOptions:
     device_read_min_batch: int = None
     # value residency: pin uniform-layout value rows in HBM alongside the
     # key columns so compaction outputs materialize on device. Off until
-    # a chip run shows the download beating the host gather (ROADMAP S4);
-    # engine_bench measures both.
+    # a chip run shows the download beating the host gather (ROADMAP S4).
     device_values: bool = False
     checkpoint_reserve_min_count: int = 2
     checkpoint_reserve_time_seconds: int = 0  # 0 = no time-based retention

@@ -104,33 +104,6 @@ class ReplicaService:
             h[code] = self._on_write
         return h
 
-    def rpc_batch_handlers(self) -> dict:
-        """Hot read codes the frame reader coalesces (ISSUE 20). Each
-        fn(headers, bodies) returns one result per frame — bytes on
-        success, RpcError/Exception carrying the same error the per-frame
-        handler would have raised, so the transport encodes
-        byte-identical responses either way."""
-        return {
-            RPC_GET: self._on_get_batch,
-            RPC_MULTI_GET: self._batch_loop(self._on_multi_get),
-            RPC_SCAN: self._batch_loop(self._on_scan),
-        }
-
-    @staticmethod
-    def _batch_loop(fn):
-        """Per-frame handler -> batch handler: the storage call stays per
-        frame, but the wave still pays ONE dispatch + ONE vectored reply
-        write instead of len(wave) of each."""
-        def run(headers, bodies):
-            out = []
-            for header, body in zip(headers, bodies):
-                try:
-                    out.append(fn(header, body))
-                except Exception as e:  # noqa: BLE001 - per-frame verdict
-                    out.append(e)
-            return out
-        return run
-
     def _replica_read(self, header) -> PegasusServer:
         """Resolve + charge the read throttle (reference
         replica.read_throttling env; qps units)."""
@@ -167,44 +140,6 @@ class ReplicaService:
     def _on_get(self, header, body) -> bytes:
         req = codec.decode(msg.KeyRequest, body)
         return codec.encode(self._read(header, "on_get", req.key))
-
-    def _on_get_batch(self, headers, bodies) -> list:
-        """RPC_GET over a coalesced wave: per-frame admission (decode,
-        partition resolve, read throttle — each request is still charged
-        individually), then ONE PegasusServer.on_get_batch per distinct
-        replica for everything admitted. Per-frame failures become that
-        frame's result; a group failure becomes every member's result —
-        the exact errors _on_get would have raised."""
-        from .sstable import CorruptionError
-
-        results = [None] * len(headers)
-        groups = {}  # id(srv) -> (srv, [(frame index, key), ...])
-        for i, (header, body) in enumerate(zip(headers, bodies)):
-            try:
-                req = codec.decode(msg.KeyRequest, body)
-                srv = self._replica_read(header)
-            except Exception as e:  # noqa: BLE001 - per-frame verdict
-                results[i] = e
-                continue
-            groups.setdefault(id(srv), (srv, []))[1].append((i, req.key))
-        for srv, members in groups.values():
-            try:
-                resps = srv.on_get_batch([k for _, k in members])
-                for (i, _), resp in zip(members, resps):
-                    results[i] = codec.encode(resp)
-            except CorruptionError as e:
-                if srv.table_ledger is not None:
-                    srv.table_ledger.charge_error()
-                err = RpcError(ERR_INVALID_DATA,
-                               f"on-disk corruption: {e.detail} — replica "
-                               f"{srv.app_id}.{srv.pidx} is being "
-                               f"quarantined; retry after reconfiguration")
-                for i, _ in members:
-                    results[i] = err
-            except Exception as e:  # noqa: BLE001 - per-frame verdict
-                for i, _ in members:
-                    results[i] = e
-        return results
 
     def _on_multi_get(self, header, body) -> bytes:
         req = codec.decode(msg.MultiGetRequest, body)

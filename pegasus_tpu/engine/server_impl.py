@@ -121,26 +121,6 @@ class _ReadCoalescer:
             raise slot.err
         return slot.value
 
-    def get_many(self, keys, now: int):
-        """Batch point reads from ONE caller thread (the native dispatch
-        batch, ISSUE 20): the whole wave joins the coalescer as a slot
-        GROUP — it merges with concurrent readers' slots into shared
-        device batches, and parks a single connection thread instead of
-        one thread per key. Raises the first slot error (the caller
-        treats the wave as one read against one snapshot)."""
-        if not keys:
-            return []
-        if not self.engine._device_reads_on():
-            return self.engine.get_batch(keys, now=[now] * len(keys))
-        slots = [_ReadSlot(k, now) for k in keys]
-        self._join_many(slots)
-        out = []
-        for s in slots:
-            if s.err is not None:
-                raise s.err
-            out.append(s.value)
-        return out
-
     def _join_many(self, slots) -> None:
         """Queue every slot and drive the leader/follower drain until ALL
         are served — the group-commit loop shared with the range twin
@@ -639,44 +619,6 @@ class PegasusServer:
             self.table_ledger.charge_read(elapsed_us, size)
         self._check_slow_query("get", hk, elapsed_us)
         return resp
-
-    def on_get_batch(self, keys, now: int = None) -> list:
-        """on_get over a native dispatch batch (ISSUE 20): ONE coalescer
-        slot-group join (or one engine.get_batch when device reads are
-        off) serves the whole wave, then the per-key bookkeeping runs
-        exactly as on_get runs it — same counters, same CU charges, same
-        abnormal-size/slow-query tracing, byte-identical ReadResponses.
-        Latency samples share the batch's elapsed time (the wave IS one
-        storage operation)."""
-        t0 = time.perf_counter()
-        now = epoch_now() if now is None else now
-        with REQUEST_TRACER.span("engine.get", batch=len(keys)):
-            raws = self._read_coalescer.get_many(keys, now)
-        out = []
-        elapsed_us = int((time.perf_counter() - t0) * 1e6)
-        for key, raw in zip(keys, raws):
-            resp = msg.ReadResponse(app_id=self.app_id,
-                                    partition_index=self.pidx,
-                                    server=self.server)
-            if raw is None:
-                resp.error = Status.NOT_FOUND
-            else:
-                resp.value = self._schema.extract_user_data(raw)
-            try:
-                hk, _ = key_schema.restore_key(key)
-            except ValueError:
-                hk = key  # malformed client key: still account, never raise
-            self.cu_calculator.add_get_cu(hk, key, resp.value)
-            size = len(key) + len(resp.value)
-            self._check_abnormal_size("get", hk, size,
-                                      self._abnormal_get_size)
-            self._c_get_qps.increment()
-            self._c_get_latency.set(elapsed_us)
-            if self.table_ledger is not None:
-                self.table_ledger.charge_read(elapsed_us, size)
-            self._check_slow_query("get", hk, elapsed_us)
-            out.append(resp)
-        return out
 
     def _check_abnormal_size(self, op: str, hash_key: bytes, size: int,
                              size_thr: int, rows: int = 0,

@@ -655,36 +655,24 @@ static PyTypeObject Plan_Type = {
  * touching Python until the finished (header, body) list is returned.
  */
 
-#define FR_MAX_HOT 8 /* hot-code bins per reader (3 used today) */
-
 typedef struct {
     PyObject_HEAD
     PlanObject *plan;   /* RpcHeader plan (strong) */
-    PyObject *hot;      /* tuple of hot code strs (strong), may be NULL */
     unsigned char *buf; /* unparsed bytes */
     Py_ssize_t len, cap, pos;
 } FrameReaderObject;
 
-static PyObject *str_code; /* interned "code" attr name (module init) */
-
 static PyObject *FrameReader_new(PyTypeObject *type, PyObject *args,
                                  PyObject *kw)
 {
-    PyObject *plan, *hot = NULL;
-    if (!PyArg_ParseTuple(args, "O!|O!", &Plan_Type, &plan, &PyTuple_Type,
-                          &hot))
+    PyObject *plan;
+    if (!PyArg_ParseTuple(args, "O!", &Plan_Type, &plan))
         return NULL;
-    if (hot && PyTuple_GET_SIZE(hot) > FR_MAX_HOT) {
-        RAISE("too many hot codes");
-        return NULL;
-    }
     FrameReaderObject *self = (FrameReaderObject *)type->tp_alloc(type, 0);
     if (!self)
         return NULL;
     Py_INCREF(plan);
     self->plan = (PlanObject *)plan;
-    Py_XINCREF(hot);
-    self->hot = hot;
     self->buf = NULL;
     self->len = self->cap = self->pos = 0;
     return (PyObject *)self;
@@ -693,7 +681,6 @@ static PyObject *FrameReader_new(PyTypeObject *type, PyObject *args,
 static void FrameReader_dealloc(FrameReaderObject *self)
 {
     Py_XDECREF(self->plan);
-    Py_XDECREF(self->hot);
     PyMem_Free(self->buf);
     Py_TYPE(self)->tp_free((PyObject *)self);
 }
@@ -797,77 +784,6 @@ static int fr_parse_frames(FrameReaderObject *self, PyObject *out)
     }
 }
 
-/* The dispatch variant: every complete frame parsed AND binned by hot
- * task code. Output entries are (code str, [(header, body), ...]) in
- * first-arrival order; frames whose code is in self->hot coalesce into
- * the entry opened by their first frame, every other frame gets its own
- * singleton entry — so Python dispatches hot read codes once per BATCH
- * instead of once per frame. */
-static int fr_parse_frames_binned(FrameReaderObject *self, PyObject *out)
-{
-    PyObject *bins[FR_MAX_HOT]; /* borrowed: each list lives in `out` */
-    Py_ssize_t nhot = self->hot ? PyTuple_GET_SIZE(self->hot) : 0;
-    for (Py_ssize_t i = 0; i < nhot; i++)
-        bins[i] = NULL;
-    for (;;) {
-        PyObject *pair;
-        int rc = fr_parse_one(self, &pair);
-        if (rc <= 0)
-            return rc;
-        PyObject *code = PyObject_GetAttr(PyTuple_GET_ITEM(pair, 0),
-                                          str_code);
-        if (!code) {
-            Py_DECREF(pair);
-            return -1;
-        }
-        Py_ssize_t hot_idx = -1;
-        for (Py_ssize_t i = 0; i < nhot; i++) {
-            int eq = PyObject_RichCompareBool(
-                code, PyTuple_GET_ITEM(self->hot, i), Py_EQ);
-            if (eq < 0) {
-                Py_DECREF(code);
-                Py_DECREF(pair);
-                return -1;
-            }
-            if (eq) {
-                hot_idx = i;
-                break;
-            }
-        }
-        if (hot_idx >= 0 && bins[hot_idx]) {
-            rc = PyList_Append(bins[hot_idx], pair);
-            Py_DECREF(code);
-            Py_DECREF(pair);
-            if (rc < 0)
-                return -1;
-            continue;
-        }
-        PyObject *lst = PyList_New(0);
-        if (!lst || PyList_Append(lst, pair) < 0) {
-            Py_XDECREF(lst);
-            Py_DECREF(code);
-            Py_DECREF(pair);
-            return -1;
-        }
-        Py_DECREF(pair);
-        PyObject *entry = PyTuple_Pack(2, code, lst);
-        Py_DECREF(code);
-        if (!entry) {
-            Py_DECREF(lst);
-            return -1;
-        }
-        rc = PyList_Append(out, entry);
-        Py_DECREF(entry);
-        if (rc < 0) {
-            Py_DECREF(lst);
-            return -1;
-        }
-        if (hot_idx >= 0)
-            bins[hot_idx] = lst; /* borrowed; `out` keeps it alive */
-        Py_DECREF(lst);
-    }
-}
-
 /* one recv() with the GIL released into the (pre-reserved) buffer tail;
  * 0 ok (len advanced), -1 = Python error already set */
 static int fr_recv(FrameReaderObject *self, long fd)
@@ -897,8 +813,7 @@ static int fr_recv(FrameReaderObject *self, long fd)
     return 0;
 }
 
-static PyObject *fr_read_loop(FrameReaderObject *self, PyObject *arg,
-                              int (*parse)(FrameReaderObject *, PyObject *))
+static PyObject *FrameReader_read_wave(FrameReaderObject *self, PyObject *arg)
 {
     long fd = PyLong_AsLong(arg);
     if (fd == -1 && PyErr_Occurred())
@@ -907,7 +822,7 @@ static PyObject *fr_read_loop(FrameReaderObject *self, PyObject *arg,
     if (!out)
         return NULL;
     for (;;) {
-        if (parse(self, out) < 0) {
+        if (fr_parse_frames(self, out) < 0) {
             Py_DECREF(out);
             return NULL;
         }
@@ -920,25 +835,11 @@ static PyObject *fr_read_loop(FrameReaderObject *self, PyObject *arg,
     }
 }
 
-static PyObject *FrameReader_read_wave(FrameReaderObject *self, PyObject *arg)
-{
-    return fr_read_loop(self, arg, fr_parse_frames);
-}
-
-static PyObject *FrameReader_read_wave_binned(FrameReaderObject *self,
-                                              PyObject *arg)
-{
-    return fr_read_loop(self, arg, fr_parse_frames_binned);
-}
-
 static PyMethodDef FrameReader_methods[] = {
     {"feed", (PyCFunction)FrameReader_feed, METH_O,
      "feed(bytes): preload already-read bytes into the buffer"},
     {"read_wave", (PyCFunction)FrameReader_read_wave, METH_O,
      "read_wave(fd) -> [(header, body), ...]; blocks for >=1 frame"},
-    {"read_wave_binned", (PyCFunction)FrameReader_read_wave_binned, METH_O,
-     "read_wave_binned(fd) -> [(code, [(header, body), ...]), ...];\n"
-     "frames with a hot code coalesce into one entry per wave"},
     {NULL, NULL, 0, NULL},
 };
 
@@ -1105,9 +1006,6 @@ static struct PyModuleDef fastcodec_module = {
 PyMODINIT_FUNC PyInit_fastcodec(void)
 {
     if (PyType_Ready(&Plan_Type) < 0 || PyType_Ready(&FrameReader_Type) < 0)
-        return NULL;
-    str_code = PyUnicode_InternFromString("code");
-    if (!str_code)
         return NULL;
     PyObject *m = PyModule_Create(&fastcodec_module);
     if (!m)

@@ -28,7 +28,7 @@ from ..runtime.fail_points import inject as _inject
 from ..runtime.lane_guard import LANE_GUARD
 from ..runtime.tracing import COMPACT_TRACER as _TRACE
 from .compact import (CompactOptions, _make_cached_fn, apply_post_filters,
-                      gather_device_survivors)
+                      gather_runs)
 from .kernel import DeviceKernel
 
 
@@ -231,8 +231,7 @@ def _run_group(jobs, idxs, sig, opts, now, mesh, outs, post_opts=None,
         for row, j in enumerate(idxs):
             runs = jobs[j][0]
             concat = runs[0] if len(runs) == 1 else KVBlock.concat(runs)
-            out = gather_device_survivors(concat, out_idx[row],
-                                          int(counts[row]))
+            out = gather_runs([concat], out_idx[row], int(counts[row]))
             group_outs[j] = apply_post_filters(
                 out, post_opts[j] if post_opts else opts, now)
         return group_outs

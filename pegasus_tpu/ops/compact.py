@@ -20,8 +20,9 @@ over KVBlock columns:
 Both backends implement identical semantics on the same total order, so
 output SSTs are byte-stable across cpu/tpu — the determinism requirement
 that lets learner checksums and backup digests agree (SURVEY.md §7 hard
-part d). tests/test_compact_ops.py asserts byte equality, and bench.py
-asserts it at bench scale.
+part d). tests/test_compact_ops.py asserts byte equality; the
+`compact10m.fill_compact` cell (benchmarks/run.py) holds the device
+lane's output to a plain reference at 10M records.
 
 The kernels return the survivor indices (into the concatenated input) in
 sorted order. Variable-length key/value bytes never touch the device: the
@@ -50,7 +51,7 @@ _U32_MAX = np.uint32(0xFFFFFFFF)
 _MIN_BUCKET = 256  # pad runs to pow2 buckets >= this to bound jit recompiles
 
 # monotonic total of runs refused HBM residency for a key over the prefix
-# window (device-health's `bypass` block; chip_smoke.py requires zero)
+# window (device-health's `bypass` block)
 _C_LONG_KEY_BYPASS = _counters.number("engine.hbm.long_key_bypass_count")
 # monotonic totals, one a survivor gather: by pointer arithmetic over the
 # runs as they are, or (layouts not uniform) over a KVBlock.concat copy
@@ -586,7 +587,7 @@ def materialize_device_survivors(concat: KVBlock, dev_vals: DeviceVals,
     uni = _shared_uniform_layout([concat])
     if uni is None or dev_vals is None or dev_vals.n != concat.n \
             or uni[1] != dev_vals.vl0:
-        return gather_device_survivors(concat, dev_idx, count)
+        return gather_runs([concat], dev_idx, count)
     kl0, vl0 = uni
     bucket = min(_pow2ceil(count, 1 << 16), int(dev_idx.shape[0]))
     fn = _compiled_val_gather(dev_vals.n, vl0, bucket)
@@ -666,13 +667,6 @@ def _gather_runs_impl(runs, idx, count: int, chunks: int,
                                    out_k[a:b], out_v[a:b], out_e[a:b],
                                    out_h[a:b], out_d[a:b])
     return KVBlock.uniform(kl0, vl0, out_k, out_v, out_e, out_h, out_d)
-
-
-def gather_device_survivors(concat: KVBlock, dev_idx, count: int,
-                            chunks: int = 8) -> KVBlock:
-    """gather_runs over one block that is already whole (the batched and
-    blockwise merges, bench lanes)."""
-    return gather_runs([concat], dev_idx, count, chunks)
 
 
 def _pad_to(a: np.ndarray, n: int) -> np.ndarray:
@@ -1165,7 +1159,7 @@ def _compact_blockwise_pipelined(jobs, opts: CompactOptions,
             dev_idx, count = disp
             concat = (range_runs[0] if len(range_runs) == 1
                       else KVBlock.concat(range_runs))
-            out = gather_device_survivors(concat, dev_idx, count)
+            out = gather_runs([concat], dev_idx, count)
             return apply_post_filters(out, opts, now)
 
         return pipe.map(jobs, _prefetch, _dispatch, _finish)

@@ -23,16 +23,11 @@ Counters (one registry with everything else — /metrics serves them):
   compact.watchdog.probe_us                       percentile
   compact.watchdog.wedged                         gauge (0/1)
 
-start() arms a background loop that re-probes every interval_s and, when
-status_path is set, heartbeats the state there as JSON (atomic replace).
-The bench parent reads that file when it has to abandon a wedged child,
-so the degraded JSON line can name the wedged stage across the process
-boundary. probe_fn is injectable for tests (a deliberately-hung fake
-backend exercises the timeout path without hardware).
+start() arms a background loop that re-probes every interval_s.
+probe_fn is injectable for tests (a deliberately-hung fake backend
+exercises the timeout path without hardware).
 """
 
-import json
-import os
 import threading
 import time
 
@@ -64,23 +59,16 @@ def _default_probe() -> bool:
 class DeviceHealthWatchdog:
     def __init__(self, probe_timeout_s: float = 10.0,
                  interval_s: float = 5.0, probe_fn=None,
-                 tracer=COMPACT_TRACER, status_path: str = None,
-                 fail_threshold: int = 2):
+                 tracer=COMPACT_TRACER, fail_threshold: int = 2):
         self.probe_timeout_s = probe_timeout_s
         self.interval_s = interval_s
         self.probe_fn = probe_fn or _default_probe
         self.tracer = tracer
-        self.status_path = status_path
         # one slow-but-healthy kernel can legitimately starve a probe past
         # its timeout (device work serializes); only consecutive failures
         # flip the wedged state, so a single starved probe records an
         # error without a false wedge verdict
         self.fail_threshold = fail_threshold
-        # False = heartbeat-only: the loop skips probes but keeps writing
-        # status. bench.py disarms until ITS thread has initialized the
-        # backend — a probe starved behind a healthy-but-slow init would
-        # report a false wedge
-        self.probes_armed = True
         self._lock = threading.Lock()
         self._probe_thread = None  # in-flight (possibly hung) probe
         self._consec_failures = 0
@@ -166,7 +154,7 @@ class DeviceHealthWatchdog:
         out["open_stages"] = {str(tid): stages for tid, stages
                               in self.tracer.open_stages().items()}
         # what every health surface this state feeds (the device-health
-        # remote command, /compact/trace, the status-file heartbeat) must
+        # remote command, /compact/trace) must
         # be able to answer without reaching into the process: WHICH
         # device the kernels run on, where the persistent compile cache
         # is, what compilation cost and whether any is in flight, both
@@ -184,24 +172,10 @@ class DeviceHealthWatchdog:
                          for name in BYPASS_COUNTERS}
         return out
 
-    def write_status(self) -> None:
-        """Heartbeat the state to status_path (atomic tmp+replace) so a
-        PARENT process can read where this one wedged after abandoning it."""
-        if not self.status_path:
-            return
-        payload = dict(self.state(), ts=time.time())
-        tmp = f"{self.status_path}.{os.getpid()}.tmp"
-        try:
-            with open(tmp, "w") as f:
-                json.dump(payload, f)
-            os.replace(tmp, self.status_path)
-        except OSError:
-            pass  # a failed heartbeat must never fail the pipeline
-
     # ----------------------------------------------------------- lifecycle
 
     def start(self):
-        """Arm the background probe+heartbeat loop (idempotent)."""
+        """Arm the background probe loop (idempotent)."""
         with self._lock:
             if self._loop_thread is not None and self._loop_thread.is_alive():
                 return self
@@ -218,19 +192,17 @@ class DeviceHealthWatchdog:
         self._stop.set()
 
     def _loop(self):
-        # first heartbeat immediately: a wedge during device init should be
+        # first probe immediately: a wedge during device init should be
         # attributable even if it happens before the first interval elapses
         while True:
             try:
-                if self.probes_armed:
-                    self.probe()
+                self.probe()
             except Exception as e:  # noqa: BLE001 - loop must survive
                 print(f"[device-watchdog] probe crashed: {e!r}", flush=True)
-            self.write_status()
             if self._stop.wait(self.interval_s):
                 return
 
 
-# process-wide instance: the manual-compact service probes it around tpu
-# compactions, bench.py's lane child arms its loop with a status file
+# process-wide instance: the manual-compact service arms its loop around
+# tpu compactions, the lane guards' breakers re-probe the device through it
 WATCHDOG = DeviceHealthWatchdog()

@@ -100,8 +100,8 @@ class DeviceKernel:
 
         # the jitted function's name is the program's name: in profiler
         # traces, and as the `jit_pegasus_<name>-<hash>` file the
-        # persistent compile cache keeps it under (chip_smoke.py tells the
-        # package's kernels from jax's own small eager programs by it)
+        # persistent compile cache keeps it under (which tells the
+        # package's kernels from jax's own small eager programs)
         kernel.__name__ = kernel.__qualname__ = f"pegasus_{name}"
         self._jit = jax.jit(kernel)
         self.name = name

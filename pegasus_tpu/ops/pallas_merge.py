@@ -54,15 +54,12 @@ Mosaic's memory spaces.
 
 Gated by PEGASUS_PALLAS (default OFF; =1 enables). The TPU body lowers
 through Mosaic on a v5e (jax 0.9.0, libtpu 0.0.34) and its output is
-byte-equal to CpuBackend's at 1M and 10M records (chip runs of PR 21;
-chip_smoke.py's compact phase repeats the check on every run). The
-default stays off: turning it on is a performance change, to be made
-with paired measurements (ROADMAP D2). bench.py's TPU lane trials the
-kernel self-validatingly — byte-equality asserted against the XLA lane's
-output — and reports it only when it lowers, matches, and wins.
-Correctness is pinned against device_sort.merge_two_sorted by
-tests/test_pallas_merge.py (interpret mode) and on the chip by
-chip_smoke.py.
+byte-equal to CpuBackend's at 1M and 10M records (chip runs of PR 21).
+The default stays off: turning it on is a performance change, to be made
+with paired measurements (ROADMAP D2, S6), which also owe the on-chip
+byte-identity check. Correctness is pinned against
+device_sort.merge_two_sorted by tests/test_pallas_merge.py (interpret
+mode).
 
 Reference seam: the comparator loop inside RocksDB CompactRange
 (reference src/server/pegasus_server_impl.cpp:2814-2891).

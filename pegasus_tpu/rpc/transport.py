@@ -92,12 +92,9 @@ def _send_frame(sock, header: RpcHeader, body: bytes, lock=None) -> None:
 
 
 # the native read data plane's attribution counters (ISSUE 20): waves
-# drained by the C reader, frames that arrived pre-binned into hot-code
-# batches, and vectored sends. With PEGASUS_NATIVE=0 all four flatline —
-# the bench A/B and the metric-history fallback regression both read
-# these.
+# drained by the C reader and vectored sends. With PEGASUS_NATIVE=0 all
+# three flatline — the metric-history fallback regression reads these.
 _C_WAVE = counters.rate("native.wave_count")
-_C_BATCH_FRAMES = counters.rate("native.batch_frames")
 _C_WRITEV = counters.rate("native.writev_count")
 _C_WRITEV_BYTES = counters.rate("native.writev_bytes")
 
@@ -146,24 +143,17 @@ def _send_encoded_frames(sock, enc, lock=None) -> None:
         sock.sendall(buf)
 
 
-def _send_frames(sock, pairs, lock=None) -> None:
-    """_send_encoded_frames over [(RpcHeader, body), ...]."""
-    _send_encoded_frames(sock, [(codec.encode(h), b) for h, b in pairs],
-                         lock=lock)
-
-
 class _FrameReader:
     """Buffered framing for a socket with a SINGLE reader thread: one
     kernel recv typically yields several pipelined frames (length word +
     header + body used to cost 2+ recv syscalls per frame)."""
 
-    __slots__ = ("sock", "buf", "pos", "hot")
+    __slots__ = ("sock", "buf", "pos")
 
-    def __init__(self, sock, initial: bytes = b"", hot=()):
+    def __init__(self, sock, initial: bytes = b""):
         self.sock = sock
         self.buf = bytearray(initial)
         self.pos = 0
-        self.hot = frozenset(hot)
 
     def _fill(self, need: int) -> None:
         buf = self.buf
@@ -212,25 +202,6 @@ class _FrameReader:
             out.append(self.frame())
         return out
 
-    def wave_batched(self):
-        """wave() binned by hot task code — the pure-Python twin of
-        fastcodec's read_wave_binned, same coalescing semantics: frames
-        whose code is in `hot` join ONE (code, frames) entry opened at
-        their first frame's arrival position; every other frame gets a
-        singleton entry in arrival order."""
-        out, bins = [], {}
-        for header, body in self.wave():
-            code = header.code
-            lst = bins.get(code)
-            if lst is not None:
-                lst.append((header, body))
-                continue
-            lst = [(header, body)]
-            if code in self.hot:
-                bins[code] = lst
-            out.append((code, lst))
-        return out
-
 
 class _NativeFrameReader:
     """fastcodec.FrameReader wrapper: drains a pipelined frame wave in ONE
@@ -239,9 +210,9 @@ class _NativeFrameReader:
 
     __slots__ = ("sock", "fr")
 
-    def __init__(self, fc, sock, initial: bytes = b"", hot=()):
+    def __init__(self, fc, sock, initial: bytes = b""):
         self.sock = sock
-        self.fr = fc.FrameReader(codec._plan_of(RpcHeader), tuple(hot))
+        self.fr = fc.FrameReader(codec._plan_of(RpcHeader))
         if initial:
             self.fr.feed(initial)
 
@@ -261,34 +232,21 @@ class _NativeFrameReader:
         _C_WAVE.increment()
         return wave
 
-    def wave_batched(self):
-        """Binned dispatch wave: header parse + hot-code binning both
-        happen in C; Python sees [(code, [(header, body), ...]), ...]."""
-        wave = self.fr.read_wave_binned(self._fd())
-        _C_WAVE.increment()
-        for _, frames in wave:
-            if len(frames) > 1:
-                _C_BATCH_FRAMES.increment(len(frames))
-        return wave
 
-
-def make_frame_reader(sock, initial: bytes = b"", hot=()):
+def make_frame_reader(sock, initial: bytes = b""):
     """Best available frame reader for a blocking socket: the C wave
-    drainer when PEGASUS_NATIVE is on, fastcodec is importable (with the
-    binned-wave entry point — an older .so without it must not be half
-    used) AND the RpcHeader plan compiled to a C plan (a Python-plan
-    header would hand the C reader an incompatible object), else the
-    buffered Python reader. `hot` is the task codes to coalesce into
-    per-code batches in wave_batched()."""
+    drainer when PEGASUS_NATIVE is on, fastcodec is importable AND the
+    RpcHeader plan compiled to a C plan (a Python-plan header would hand
+    the C reader an incompatible object), else the buffered Python
+    reader."""
     from .. import native
 
     if native.native_on():
         fc = native.fastcodec()
         if fc is not None and hasattr(fc, "FrameReader") \
-                and hasattr(fc.FrameReader, "read_wave_binned") \
                 and isinstance(codec._plan_of(RpcHeader), fc.Plan):
-            return _NativeFrameReader(fc, sock, initial, hot)
-    return _FrameReader(sock, initial, hot)
+            return _NativeFrameReader(fc, sock, initial)
+    return _FrameReader(sock, initial)
 
 
 class RpcServer:
@@ -316,11 +274,6 @@ class RpcServer:
 
     def __init__(self, host: str = "127.0.0.1", port: int = 0):
         self._handlers = {}
-        # hot read codes with a BATCH handler: fn(headers, bodies) ->
-        # per-frame results (bytes | RpcError | Exception). The frame
-        # reader coalesces these codes in C (ISSUE 20) and dispatch
-        # enters Python once per batch instead of once per frame.
-        self._batch_handlers = {}
         self._middlewares = []   # fn(code, header, body, next) -> body
         from ..runtime.tasking import tracked_executor
 
@@ -363,19 +316,10 @@ class RpcServer:
         with self._conn_lock:
             self._conns.add(sock)
         try:
-            # always bin hot codes — the C wave amortization holds even
-            # when middlewares (tracer/profiler/fault toollets) are
-            # installed, because _dispatch_batch routes those batches
-            # back through the per-frame path, middleware chain intact
-            hot = tuple(self._batch_handlers)
-            reader = make_frame_reader(sock, initial, hot)
+            reader = make_frame_reader(sock, initial)
             while True:
-                for code, frames in reader.wave_batched():
-                    if len(frames) == 1:
-                        header, body = frames[0]
-                        dispatch(sock, wlock, header, body)
-                    else:
-                        self._dispatch_batch(sock, wlock, code, frames)
+                for header, body in reader.wave():
+                    dispatch(sock, wlock, header, body)
         except (ConnectionError, OSError):
             pass
         finally:
@@ -400,23 +344,10 @@ class RpcServer:
     def register(self, code: str, handler) -> None:
         self._handlers[code] = handler
 
-    def register_batch(self, code: str, handler) -> None:
-        """Register a batch handler: fn(headers, bodies) -> one result
-        per frame, each bytes (success), RpcError, or any Exception
-        (encoded exactly like the per-frame path encodes them). The code
-        MUST also have a per-frame handler — singleton frames, traced
-        frames, middleware'd connections and the serve.native fallback
-        all still route per frame."""
-        self._batch_handlers[code] = handler
-
     def register_serverlet(self, obj) -> None:
-        """Register every (code, fn) pair from obj.rpc_handlers(), plus
-        obj.rpc_batch_handlers() when the serverlet provides them."""
+        """Register every (code, fn) pair from obj.rpc_handlers()."""
         for code, fn in obj.rpc_handlers().items():
             self.register(code, fn)
-        for code, fn in getattr(obj, "rpc_batch_handlers",
-                                dict)().items():
-            self.register_batch(code, fn)
 
     def add_middleware(self, mw) -> None:
         """mw(code, header, body, next_fn) -> response body. The rDSN
@@ -538,113 +469,6 @@ class RpcServer:
         with REQUEST_TRACER.span_in(ctx, "rpc.reply", bytes=len(out)):
             try:
                 _send_frame(sock, resp, out, lock=wlock)
-            except (ConnectionError, OSError):
-                pass
-
-    def _dispatch_batch(self, sock, wlock, code: str, frames) -> None:
-        """Dispatch a hot-code batch the reader coalesced: ONE pool task,
-        ONE handler call, ONE vectored reply write for the whole batch.
-        Falls back to per-frame dispatch when the serve.native fail point
-        triggers mid-wave, when any frame carries a trace context (spans
-        must attach per request), or when middlewares are installed
-        (tracer/profiler/fault toollets wrap per-frame handlers; the C
-        wave binning still amortizes the read side) — the per-frame twin
-        produces byte-identical responses, so the fallback is invisible
-        on the wire."""
-        batch_ok = True
-        try:
-            if fail_point("serve.native") is not None:
-                batch_ok = False
-        except FailPointError:
-            batch_ok = False
-        if (not batch_ok or self._middlewares
-                or code not in self._batch_handlers
-                or any(h.trace_id for h, _ in frames)):
-            for header, body in frames:
-                self._dispatch(sock, wlock, header, body)
-            return
-        # serve.dispatch fires once per batch — the batch IS one dispatch
-        try:
-            fail_point("serve.dispatch")
-        except FailPointError as e:
-            err = counters.rate("rpc.server.error_count")
-            pairs = []
-            for header, _ in frames:
-                pairs.append((RpcHeader(
-                    seq=header.seq, code=header.code, is_response=True,
-                    error=ERR_BUSY, error_text=str(e)), b""))
-                err.increment()
-                if header.app_id:
-                    from ..runtime.table_stats import TABLE_STATS
-
-                    TABLE_STATS.charge_app_error(header.app_id)
-            try:
-                _send_frames(sock, pairs, lock=wlock)
-            except (ConnectionError, OSError):
-                pass
-            return
-        with self._busy_lock:
-            self._busy += 1
-            depth = self._busy - self.POOL_WORKERS
-        if depth > 0:
-            self._depth_gauge.set(depth)
-        try:
-            self._pool.submit(self._serve_batch_pooled, sock, wlock, code,
-                              frames, time.perf_counter())
-        except RuntimeError:   # server stopping: pool already shut down
-            with self._busy_lock:
-                self._busy -= 1
-
-    def _serve_batch_pooled(self, sock, wlock, code, frames,
-                            parsed) -> None:
-        try:
-            self._serve_batch(sock, wlock, code, frames, parsed)
-        finally:
-            with self._busy_lock:
-                self._busy -= 1
-                depth = self._busy - self.POOL_WORKERS
-            self._depth_gauge.set(max(0, depth))
-
-    def _serve_batch(self, sock, wlock, code: str, frames,
-                     parsed: float) -> None:
-        t0 = time.perf_counter()
-        REQUEST_TRACER.event("rpc.queue", int((t0 - parsed) * 1e6),
-                             batch=len(frames))
-        headers = [h for h, _ in frames]
-        bodies = [b for _, b in frames]
-        # a batch is ONE dispatch: one rpc.server.<code> close for all of
-        # its frames (untraced by construction, see _dispatch_batch)
-        with REQUEST_TRACER.serve(None, code):
-            try:
-                results = self._batch_handlers[code](headers, bodies)
-            except Exception as e:  # handler bug -> errors, not a dead conn
-                results = [e] * len(frames)
-        pairs, n_err = [], 0
-        for header, res in zip(headers, results):
-            resp = RpcHeader(seq=header.seq, code=header.code,
-                             is_response=True)
-            out = b""
-            if isinstance(res, RpcError):
-                resp.error, resp.error_text = res.err, res.text
-            elif isinstance(res, BaseException):
-                resp.error, resp.error_text = ERR_INVALID_DATA, repr(res)
-            else:
-                out = res
-            if resp.error:
-                n_err += 1
-            pairs.append((resp, out))
-        # same counter cardinality as the per-frame path: one qps tick
-        # and one latency sample PER FRAME (the batch shares its elapsed)
-        elapsed = int((time.perf_counter() - t0) * 1e6)
-        counters.rate("rpc.server.qps").increment(len(frames))
-        lat = counters.percentile("rpc.server.latency_us")
-        for _ in frames:
-            lat.set(elapsed)
-        if n_err:
-            counters.rate("rpc.server.error_count").increment(n_err)
-        with REQUEST_TRACER.span("rpc.reply", batch=len(frames)):
-            try:
-                _send_frames(sock, pairs, lock=wlock)
             except (ConnectionError, OSError):
                 pass
 

@@ -1,11 +1,10 @@
 """Lane guard: the ONE failure policy for every device-backed compaction.
 
-A wedged device call used to be survivable only by bench.py's
-out-of-process lane kill; PR 1's watchdog can name the wedged stage, but
-in-process the server still hung forever, and the engine handled device
-failure with scattered ad-hoc ``except Exception: degrade`` branches.
-Both compaction backends guarantee byte-identical output
-(tests/test_compact_ops.py, bench digest handshake), so the TPU lane is
+The watchdog (ops/device_watchdog.py) can name the stage a device call
+wedged in, but without this module the server still hung on it forever,
+and the engine handled device failure with scattered ad-hoc ``except
+Exception: degrade`` branches. Both compaction backends guarantee
+byte-identical output (tests/test_compact_ops.py), so the TPU lane is
 an *optimization* that must never be an availability risk — LUDA
 (PAPERS.md) makes the same argument for GPU compaction offload. This
 module centralizes that contract:
@@ -41,9 +40,8 @@ module centralizes that contract:
 
 Call sites: ops/compact.py (single merge), ops/batched_compact.py (one
 vmapped dispatch per shape group), parallel/sharded_compact.py (multi-chip
-all_to_all merge), bench.py's timed lane (fallback disabled there — a
-bench must report the device number or fail loudly, never silently time
-the cpu path as "tpu").
+all_to_all merge). A caller that passes no fallback_fn gets the device's
+result or the failure, never the cpu path under the device's name.
 
 Counters (process registry -> /metrics, perf-counters*, collector):
   compact.lane.fallback_count / retry_count /
@@ -52,8 +50,8 @@ Counters (process registry -> /metrics, perf-counters*, collector):
   compact.lane.breaker_open                                    gauge (0/1)
 
 Monotonic totals (rate counters reset on read) live in state(), which
-rides in the device-health remote command, /compact/trace, the watchdog
-status-file heartbeat, query_compact_state, and bench's detail.lane.
+rides in the device-health remote command, /compact/trace and
+query_compact_state.
 
 Env knobs (read once at import for the process-wide LANE_GUARD):
   PEGASUS_LANE_DEADLINE_S / PEGASUS_LANE_MAX_RETRIES /

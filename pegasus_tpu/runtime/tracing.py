@@ -25,8 +25,8 @@ a stage entered recursively (blockwise range decomposition re-enters
 the nested time once per level — read `calls` alongside `s`.
 
 A TraceSession aggregates every span closed in its thread while active;
-bench.py and the manual-compact service record per-stage breakdowns from
-it (the `trace` detail in BENCH_*.json).
+the manual-compact service records per-stage breakdowns from it
+(`manual_compact`'s `stats["trace"]`).
 """
 
 import collections

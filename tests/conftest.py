@@ -14,7 +14,7 @@ import os
 # group-worker / chaos test doubles as a lock-order regression test.
 # Must happen before any pegasus_tpu import (locks are created at class
 # init with the env read per factory call); subprocesses (group workers,
-# killed-node oneboxes, bench children) inherit both knobs and report
+# killed-node oneboxes) inherit both knobs and report
 # violations into the shared file.
 os.environ.setdefault("PEGASUS_LOCKRANK", "1")
 _LOCKRANK_FILE_PRESET = "PEGASUS_LOCKRANK_FILE" in os.environ

@@ -209,6 +209,16 @@ def test_env_knobs_expands_prefix_families(tmp_path):
     assert {"PEGASUS_ALPHA_TIMEOUT_S", "PEGASUS_BETA_TIMEOUT_S"} <= knobs
 
 
+def test_env_knobs_scanned_set_does_not_grow():
+    """The distinct PEGASUS_* names the code reads: 81 after PR 32 took
+    out the 42 that only the pre-ledger bench tools read (123 before).
+    Every name is a configuration somebody must test; raising this
+    number is a reviewed act, not a side effect."""
+    from tools.analyze.env_knobs import source_knobs
+
+    assert len(source_knobs(Repo())) <= 81
+
+
 def test_env_knobs_ignores_docstring_mentions(tmp_path):
     repo = make_repo(tmp_path, {"m.py": '''
     """Docs may mention PEGASUS_FANTASY freely — docs are not reads."""
@@ -294,6 +304,8 @@ SPANS_OK = """
         with JOB_TRACER.hop("engine.merge", where="local"):
             JOB_TRACER.note("sched.decide", gpid="1.0")
         self._trace(job, "offload.svc.merge", ms=3)
+        with COMPACT_TRACER.span_in(None, "rpc.reply", bytes=0):
+            pass
 """
 
 SPAN_README = """
@@ -304,6 +316,7 @@ SPAN_README = """
     | `pack` | stage | columnarization |
     | `engine.merge` / `sched.decide` | job | merge hop; the minting decision |
     | `offload.svc.merge` | job (service-side) | the remote merge |
+    | `rpc.reply` | request | a span under a given context |
 """
 
 
@@ -324,7 +337,8 @@ def test_span_names_pass_both_directions(tmp_path):
     assert "undoc:ghost.hop" in keys
     assert "stale-row:stale.span" in keys
     assert not any(k.endswith((":pack", ":engine.merge", ":sched.decide",
-                               ":offload.svc.merge")) for k in keys)
+                               ":offload.svc.merge", ":rpc.reply"))
+                   for k in keys)
 
 
 def test_span_names_pass_requires_table(tmp_path):
@@ -344,6 +358,37 @@ def test_span_names_pass_exempts_dynamic_names(tmp_path):
             pass
     """}, readme=SPAN_README)
     assert run_pass("span_names", repo) == []
+
+
+# ------------------------------------------------------------- doc_paths
+
+def test_doc_paths_clean_on_tree():
+    """Every source file README.md names exists — and the pass really
+    reads the README's three kinds of mention."""
+    from tools.analyze.doc_paths import readme_paths
+
+    assert run_pass("doc_paths", Repo()) == []
+    named = readme_paths(Repo())
+    assert {"tools/pressure_test.py", "ops/compact.py",
+            "benchmarks/run.py"} <= set(named)
+
+
+def test_doc_paths_flags_planted_missing_path(tmp_path):
+    repo = make_repo(tmp_path, {"m.py": "X = 1\n",
+                                "ops/merge.py": "Y = 2\n"}, readme="""
+    The merge lives in `ops/merge.py` (`pegasus_tpu/m.py::X` configures
+    it); `tools/check_*.py` and `<dir>/trace.py` are not paths.
+
+    ```bash
+    python ghost_entry.py --n 10
+    python tools/ghost.py
+    ```
+
+    Gone too: `ops/ghost.py`, `pegasus_tpu/ghost/`.
+    """)
+    keys = [f.key for f in run_pass("doc_paths", repo)]
+    assert keys == ["missing:ghost_entry.py", "missing:tools/ghost.py",
+                    "missing:ops/ghost.py", "missing:pegasus_tpu/ghost/"]
 
 
 # -------------------------------------------------------------- lockrank
@@ -616,7 +661,8 @@ def test_repo_clean():
     lines = [f.render() for f in report.findings] + [
         f"STALE baseline: {p}:{k}" for p, k in report.stale_baseline]
     assert report.clean, "\n".join(lines)
-    assert set(report.ran) == {"env_knobs", "events", "fail_points",
+    assert set(report.ran) == {"doc_paths", "env_knobs", "events",
+                               "fail_points",
                                "lock_discipline", "metric_names",
                                "remote_commands", "span_names",
                                "thread_lifecycle"}

@@ -499,7 +499,7 @@ def _uniform_runs(rng, n_runs=3, n=400):
 def test_materialize_device_survivors_matches_host_gather():
     """Value-residency materialization (device value gather + host key
     gather, overlapped) is byte-identical to the host fused gather."""
-    from pegasus_tpu.ops.compact import (TpuBackend, gather_device_survivors,
+    from pegasus_tpu.ops.compact import (TpuBackend, gather_runs,
                                          materialize_device_survivors,
                                          pack_runs, prepare_values)
 
@@ -513,7 +513,7 @@ def test_materialize_device_survivors_matches_host_gather():
     dev_idx, cnt = backend.survivors_device(prep, 100, 0, 0, True, True)
     assert cnt > 0
     concat = KVBlock.concat(runs)
-    base = gather_device_survivors(concat, dev_idx, cnt)
+    base = gather_runs([concat], dev_idx, cnt)
     dev_vals = prepare_values(concat)
     assert dev_vals is not None
     out = materialize_device_survivors(concat, dev_vals, dev_idx, cnt)

@@ -8,7 +8,6 @@ open the circuit breaker, which re-probes via the watchdog before closing.
 Everything is seeded-RNG deterministic and runs in tier-1 (not slow).
 """
 
-import json
 import os
 import subprocess
 import sys
@@ -113,7 +112,13 @@ def test_cold_kernel_is_served_by_the_host_lane_and_counted(guard):
     assert st["compile_behind"] == 1 and st["compile_wait_timeouts"] == 0
     assert st["fallbacks"] == st["retries"] == st["device_failures"] == 0
     assert st["deadline_abandons"] == 0 and not st["breaker_open"]
-    time.sleep(0.8)  # the compile pool finishes the program
+    # the compile pool finishes the program (0.5 s of lowering, seconds
+    # when the whole suite loads the box)
+    from pegasus_tpu.ops.kernel import compile_report
+
+    give_up = time.monotonic() + 20
+    while compile_report()["inflight"] and time.monotonic() < give_up:
+        time.sleep(0.05)
     out = guard.run(lambda: np.asarray(kernel(x)), lambda: "cpu", op="t",
                     deadline_s=0.2)
     np.testing.assert_array_equal(out, x + 1)
@@ -763,22 +768,3 @@ def test_fail_point_lint_clean():
         [sys.executable, os.path.join(REPO, "tools", "check_fail_points.py")],
         capture_output=True, text=True, timeout=60)
     assert proc.returncode == 0, proc.stderr
-
-
-def test_bench_failure_diagnostics_carry_lane_state():
-    """bench.py: a run whose device lane wedged FAILS (non-zero, no result
-    line), and the stderr diagnostics carry the stopped child's watchdog
-    heartbeat — the surface the lane guard's totals ride out on."""
-    env = dict(os.environ)
-    env.update({"JAX_PLATFORMS": "cpu", "PEGASUS_BENCH_N": "20000",
-                "PEGASUS_BENCH_REPS": "1",
-                "PEGASUS_BENCH_FAKE_LANE": "wedge",
-                "PEGASUS_BENCH_LANE_S": "4"})
-    proc = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
-                          capture_output=True, text=True, timeout=120,
-                          env=env, cwd=REPO)
-    assert proc.returncode != 0
-    assert not [l for l in proc.stdout.splitlines() if l.startswith("{")]
-    diag = [json.loads(l) for l in proc.stderr.splitlines()
-            if l.startswith("{")][-1]
-    assert diag["watchdog"]["wedged_at_stage"] == "device"

@@ -1,4 +1,4 @@
-"""Native read data plane (ISSUE 20): C dispatch waves, vectored reply
+"""Native read data plane (ISSUE 20): C frame waves, vectored wave
 writes, zero-copy mmap SSTs — and the byte-identical Python twins.
 
 Three pinned properties:
@@ -9,16 +9,18 @@ Three pinned properties:
     the same poison;
   * byte identity: the same pipelined get/multi_get/scanner wave against
     a PEGASUS_NATIVE=0 server and a =1 server produces identical wire
-    bytes per sequence number, including when the serve.native fail
-    point forces the Python fallback MID-wave;
+    bytes per sequence number, with the serve.native fail point armed
+    or not;
   * mmap lifetime: an SST loaded through the zero-copy path stays
     readable after the file is unlinked (compaction deletes its inputs
     while readers may still hold their blocks).
 """
 
+import contextlib
 import os
 import socket
 import struct
+import threading
 
 import numpy as np
 import pytest
@@ -33,8 +35,8 @@ from pegasus_tpu.engine.replica_service import (RPC_GET, RPC_GET_SCANNER,
 from pegasus_tpu.engine.server_impl import PegasusServer
 from pegasus_tpu.rpc import codec
 from pegasus_tpu.rpc import messages as msg
-from pegasus_tpu.rpc.transport import (RpcServer, RpcHeader, _FrameReader,
-                                       make_frame_reader)
+from pegasus_tpu.rpc.transport import (RpcConnection, RpcHeader, RpcServer,
+                                       _FrameReader, make_frame_reader)
 from pegasus_tpu.runtime import fail_points
 from pegasus_tpu.runtime.perf_counters import counters
 
@@ -44,6 +46,7 @@ pytestmark = pytest.mark.skipif(
 
 APP_ID = 9
 N_PARTITIONS = 2
+TABLE = "native9"
 
 
 def _frame(seq, code, body, pidx=0):
@@ -52,57 +55,66 @@ def _frame(seq, code, body, pidx=0):
     return struct.pack("<II", 4 + len(h) + len(body), len(h)) + h + body
 
 
-def _c_reader(hot=()):
+def _c_reader():
     fc.register_error(codec.CodecError)
     plan = codec._fast_plan(RpcHeader, fc)
     assert isinstance(plan, fc.Plan)
-    return fc.FrameReader(plan, tuple(hot))
+    return fc.FrameReader(plan)
 
 
 # ------------------------------------------------------------ wave parity
 
 
-def test_wave_batched_binning_matches_python():
-    """C read_wave_binned and the Python twin produce the same entry
-    structure: hot codes coalesce at first arrival, others stay
-    singleton, arrival order preserved."""
-    frames = [
-        _frame(1, RPC_GET, b"a"), _frame(2, "RPC_RRDB_RRDB_PUT", b"w"),
-        _frame(3, RPC_GET, b"b"), _frame(4, RPC_SCAN, b"s"),
-        _frame(5, RPC_GET, b"c"), _frame(6, RPC_SCAN, b"t"),
-        _frame(7, "RPC_RRDB_RRDB_PUT", b"x"),
-    ]
-    blob = b"".join(frames)
-    hot = (RPC_GET, RPC_SCAN)
+def _drain(reader_wave, n):
+    """Waves until n frames arrived -> [(encoded header, body), ...]."""
+    got = []
+    while len(got) < n:
+        got += [(codec.encode(h), body) for h, body in reader_wave()]
+    return got
 
-    a, b = socket.socketpair()
-    try:
-        r = _c_reader(hot)
-        a.sendall(blob)
-        c_wave = r.read_wave_binned(b.fileno())
-    finally:
-        a.close()
-        b.close()
 
-    a2, b2 = socket.socketpair()
-    try:
-        py = _FrameReader(b2, hot=hot)
-        a2.sendall(blob)
-        py_wave = py.wave_batched()
-    finally:
-        a2.close()
-        b2.close()
+_PUT = "RPC_RRDB_RRDB_PUT"
 
-    def shape(wave):
-        return [(code, [(h.seq, body) for h, body in fs])
-                for code, fs in wave]
 
-    assert shape(c_wave) == shape(py_wave) == [
-        (RPC_GET, [(1, b"a"), (3, b"b"), (5, b"c")]),
-        ("RPC_RRDB_RRDB_PUT", [(2, b"w")]),
-        (RPC_SCAN, [(4, b"s"), (6, b"t")]),
-        ("RPC_RRDB_RRDB_PUT", [(7, b"x")]),
-    ]
+@pytest.mark.parametrize("name,frames,sends", [
+    ("one_frame", [(RPC_GET, b"a")], 1),
+    ("nine_of_one_read_code", [(RPC_GET, b"k%d" % i) for i in range(9)], 1),
+    ("codes_interleaved",
+     [(RPC_GET, b"a"), (_PUT, b"w"), (RPC_MULTI_GET, b"m"), (RPC_GET, b"b"),
+      (RPC_SCAN, b"s"), (RPC_GET, b"c"), (RPC_SCAN, b"t"), (_PUT, b"x")], 1),
+    ("frame_split_across_two_sends",
+     [(RPC_GET, b"a"), (RPC_SCAN, b"s" * 300), (RPC_GET, b"b")], 2),
+])
+def test_read_wave_matches_python_wave(name, frames, sends):
+    """The C read_wave and the Python wave() hand dispatch the same
+    (header, body) pairs in arrival order, whatever the codes and
+    however the bytes were cut into sends."""
+    blob = b"".join(_frame(i + 1, code, body)
+                    for i, (code, body) in enumerate(frames))
+    # two sends: the cut falls inside the second frame's body, and the
+    # rest leaves only after the reader has parked on the first part
+    cut = len(blob) if sends == 1 else len(_frame(1, *frames[0])) + 40
+
+    def arrivals(make_wave):
+        a, b = socket.socketpair()
+        late = threading.Timer(0.05, a.sendall, (blob[cut:],))
+        try:
+            a.sendall(blob[:cut])
+            late.start()
+            return _drain(make_wave(b), len(frames))
+        finally:
+            if late.ident is not None:
+                late.join()
+            a.close()
+            b.close()
+
+    c_reader = _c_reader()
+    c_got = arrivals(lambda b: lambda: c_reader.read_wave(b.fileno()))
+    py_got = arrivals(lambda b: _FrameReader(b).wave)
+    assert c_got == py_got, name
+    assert [body for _, body in c_got] == [body for _, body in frames]
+    assert [codec.decode(RpcHeader, h).seq for h, _ in c_got] \
+        == list(range(1, len(frames) + 1))
 
 
 def test_sendmsg_frames_matches_python_concat():
@@ -207,20 +219,23 @@ def test_trailing_bytes_after_header_differential():
 # --------------------------------------------------------- byte identity
 
 
-def _run_leg(tmp_path, leg, request_frames):
-    """Boot a fresh 1-node/2-partition replica server, load fixed data,
-    fire `request_frames` as one pipelined wave over a raw socket, and
-    return {seq: raw response frame bytes}."""
+@contextlib.contextmanager
+def _serving(tmp_path, leg):
+    """A fresh 1-node/2-partition replica server with fixed data loaded;
+    -> (rpc server, [PegasusServer per partition])."""
     root = tmp_path / leg
     svc = ReplicaService()
     rpc = RpcServer().start()
     try:
+        servers = []
         for pidx in range(N_PARTITIONS):
             ps = PegasusServer(str(root / f"p{pidx}"), app_id=APP_ID,
                                pidx=pidx,
                                options=EngineOptions(backend="cpu"),
                                server="node0")
+            ps.set_table_name(TABLE)
             svc.add_replica(ps, N_PARTITIONS)
+            servers.append(ps)
         rpc.register_serverlet(svc)
         resolver = StaticResolver(APP_ID,
                                   [rpc.address] * N_PARTITIONS)
@@ -231,29 +246,40 @@ def _run_leg(tmp_path, leg, request_frames):
             client.multi_set(b"multi", {b"a": b"1", b"b": b"2", b"c": b"3"})
         finally:
             client.close()
-
-        s = socket.create_connection(rpc.address)
-        try:
-            s.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
-            s.sendall(b"".join(request_frames))
-            got, buf = {}, bytearray()
-            while len(got) < len(request_frames):
-                chunk = s.recv(1 << 16)
-                assert chunk, "server closed mid-response"
-                buf += chunk
-                while len(buf) >= 8:
-                    plen, hlen = struct.unpack_from("<II", buf, 0)
-                    if len(buf) < 4 + plen:
-                        break
-                    frame = bytes(buf[: 4 + plen])
-                    header = codec.decode(RpcHeader, frame[8: 8 + hlen])
-                    got[header.seq] = frame
-                    del buf[: 4 + plen]
-        finally:
-            s.close()
-        return got
+        yield rpc, servers
     finally:
         rpc.stop()
+
+
+def _one_send(addr, request_frames):
+    """Fire `request_frames` as ONE send on a fresh raw connection and
+    return {seq: raw response frame bytes}."""
+    s = socket.create_connection(addr)
+    try:
+        s.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
+        s.sendall(b"".join(request_frames))
+        got, buf = {}, bytearray()
+        while len(got) < len(request_frames):
+            chunk = s.recv(1 << 16)
+            assert chunk, "server closed mid-response"
+            buf += chunk
+            while len(buf) >= 8:
+                plen, hlen = struct.unpack_from("<II", buf, 0)
+                if len(buf) < 4 + plen:
+                    break
+                frame = bytes(buf[: 4 + plen])
+                header = codec.decode(RpcHeader, frame[8: 8 + hlen])
+                got[header.seq] = frame
+                del buf[: 4 + plen]
+        return got
+    finally:
+        s.close()
+
+
+def _run_leg(tmp_path, leg, request_frames):
+    """One pipelined wave against a fresh server -> {seq: response}."""
+    with _serving(tmp_path, leg) as (rpc, _):
+        return _one_send(rpc.address, request_frames)
 
 
 def _identity_wave():
@@ -298,14 +324,12 @@ def test_byte_identity_native_vs_python(tmp_path, monkeypatch):
     assert set(py_frames) == set(nat_frames) == set(range(1, len(wave) + 1))
     for seq in py_frames:
         assert nat_frames[seq] == py_frames[seq], f"seq {seq} diverged"
-    # the wave really exercised the batch plane: >= 8 gets coalesced
     assert len(wave) > 10
 
 
 def test_byte_identity_midwave_fallback(tmp_path, monkeypatch):
-    """serve.native armed to trigger a finite number of times: some
-    batches/writes take the Python twin, later ones the native path —
-    the wire must not be able to tell."""
+    """serve.native (the vectored writer's fallback switch) armed in the
+    serving process: the same wave gets the same bytes."""
     wave = _identity_wave()
     monkeypatch.setenv("PEGASUS_NATIVE", "0")
     py_frames = _run_leg(tmp_path, "python", wave)
@@ -320,17 +344,80 @@ def test_byte_identity_midwave_fallback(tmp_path, monkeypatch):
         assert nat_frames[seq] == py_frames[seq], f"seq {seq} diverged"
 
 
-def test_batch_dispatch_counters(tmp_path, monkeypatch):
-    """A pipelined get wave through the native plane moves the
-    native.{wave_count,batch_frames,writev_count,writev_bytes} series."""
+@pytest.mark.parametrize("k", [2, 9, 17, 33])
+def test_pipelined_gets_answered_and_charged_once_a_frame(tmp_path,
+                                                          monkeypatch, k):
+    """k RPC_GET frames in one send on one connection: every reply
+    carries its own seq and is byte-identical to the reply the same
+    frame gets alone, and get_qps, the read CU charge and the table
+    ledger's read count each rise by exactly k — the per-request
+    bookkeeping runs once a frame, however the frames arrived."""
     monkeypatch.setenv("PEGASUS_NATIVE", "1")
-    names = ("native.wave_count", "native.batch_frames",
+    hks = [b"hk%d" % i for i in range(8)] + [b"nope"]   # 8 hits, a miss
+    frames = []
+    for i in range(k):
+        key = key_schema.generate_key(hks[i % len(hks)], b"sk")
+        frames.append(_frame(
+            i + 1, RPC_GET, codec.encode(msg.KeyRequest(key=key)),
+            pidx=key_schema.key_hash(key) % N_PARTITIONS))
+
+    def over_partitions(name):
+        return sum(counters.rate(f"app.{APP_ID}.{p}.{name}").total()
+                   for p in range(N_PARTITIONS))
+
+    def charged():
+        return (over_partitions("get_qps"), over_partitions("recent_read_cu"),
+                counters.rate(f"table.{TABLE}.read_qps").total())
+
+    with _serving(tmp_path, f"pipelined{k}") as (rpc, _):
+        alone = {}
+        for f in frames:
+            alone.update(_one_send(rpc.address, [f]))
+        before = charged()
+        together = _one_send(rpc.address, frames)
+        after = charged()
+    assert set(together) == set(alone) == set(range(1, k + 1))
+    for seq in alone:
+        assert together[seq] == alone[seq], f"seq {seq} diverged"
+    # a 1-unit read each (values far under the 4 KiB CU size)
+    assert [a - b for a, b in zip(after, before)] == [k, k, k]
+
+
+def test_batch_dispatch_counters(tmp_path, monkeypatch):
+    """The native plane's attribution series: a pipelined wave drained by
+    the server's C reader moves native.wave_count, and a client
+    call_many wave (one vectored send) moves native.writev_{count,bytes}."""
+    monkeypatch.setenv("PEGASUS_NATIVE", "1")
+    names = ("native.wave_count",
              "native.writev_count", "native.writev_bytes")
     base = {n: counters.rate(n).total() for n in names}
     _run_leg(tmp_path, "counters", _identity_wave())
-    after = {n: counters.rate(n).total() for n in names}
-    for n in names:
-        assert after[n] > base[n], n
+    rpc = RpcServer().start()
+    try:
+        rpc.register("RPC_ECHO", lambda header, body: body)
+        conn = RpcConnection(rpc.address)
+        calls = [("RPC_ECHO", b"x%d" % i) for i in range(4)]
+        try:
+            got = conn.call_many(calls)
+            assert [body for _, body in got] == [b for _, b in calls]
+            after = {n: counters.rate(n).total() for n in names}
+            for n in names:
+                assert after[n] > base[n], n
+            # serve.native forces the Python twin of the vectored write:
+            # same answers, and the writev series stands still
+            fail_points.setup()
+            try:
+                fail_points.cfg("serve.native", "1*return()")
+                got = conn.call_many(calls)
+            finally:
+                fail_points.teardown()
+            assert [body for _, body in got] == [b for _, b in calls]
+            assert counters.rate("native.writev_count").total() \
+                == after["native.writev_count"]
+        finally:
+            conn.close()
+    finally:
+        rpc.stop()
 
 
 # ---------------------------------------------------------- mmap lifetime
@@ -412,7 +499,7 @@ def test_make_frame_reader_respects_knob(monkeypatch):
         monkeypatch.setenv("PEGASUS_NATIVE", "0")
         assert isinstance(make_frame_reader(a), _FrameReader)
         monkeypatch.setenv("PEGASUS_NATIVE", "1")
-        r = make_frame_reader(a, hot=(RPC_GET,))
+        r = make_frame_reader(a)
         assert not isinstance(r, _FrameReader)
     finally:
         a.close()

@@ -1,8 +1,8 @@
 """Pallas merge-path kernel: interpret-mode equivalence with the XLA merge.
 
 Runs on the CPU mesh in pallas interpret mode (the correctness pin that
-needs no chip); the Mosaic-lowered TPU body is compiled and byte-compared
-on the chip by chip_smoke.py's compact phase. PEGASUS_PALLAS=1 turns the
+needs no chip); the Mosaic-lowered TPU body's byte comparison on the chip
+belongs to ROADMAP S6's pairs. PEGASUS_PALLAS=1 turns the
 kernel on (default off; =1 means interpret mode on CPU).
 """
 

@@ -296,6 +296,10 @@ def test_dispatch_queue_depth_gauge_exports():
         gate.set()
         for conn, pend in pends:
             conn.call_many_collect(pend, [("SLOW", b"x")], timeout=20.0)
+        # a worker sends its reply before it leaves the pool's count
+        deadline = time.monotonic() + 20.0
+        while srv._busy and time.monotonic() < deadline:
+            time.sleep(0.02)
         with srv._busy_lock:
             assert srv._busy == 0
         assert counters.number(

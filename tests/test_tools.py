@@ -616,30 +616,6 @@ def test_throttling_controller_parse_and_consume():
     assert not t.enabled
 
 
-def test_trace_overhead_bench_smoke():
-    """tools/trace_overhead_bench.py (ROADMAP: quantify tracing overhead
-    before revisiting PEGASUS_TRACE_SAMPLE_EVERY): runs at a tiny N and
-    emits sane per-span costs. The real numbers + guidance live in
-    README's Observability section."""
-    import tools.trace_overhead_bench as tob
-
-    out = tob.run(n=500)
-    assert set(out) == {"n", "stage_span_us", "stage_span_in_session_us",
-                        "stage_event_us", "request_trace_us",
-                        "table_ledger_us", "event_emit_us",
-                        "history_sample_us"}
-    for k, v in out.items():
-        assert v > 0, (k, v)
-    # a stage span must stay far below the stages it wraps (>=10ms each):
-    # even on a loaded CI box, 1ms/span would mean the probe is broken
-    assert out["stage_span_us"] < 1000, out
-    # the flight recorder's emit rides transition edges of hot paths and
-    # stays on in tier-1 — counter-increment territory, not span territory
-    assert out["event_emit_us"] < 100, out
-    # the tenant ledger bills every served request — same territory
-    assert out["table_ledger_us"] < 100, out
-
-
 def test_metric_lint_reverse_pass_flags_stale_rows(monkeypatch):
     """The reverse direction of tools/check_metric_names.py: README rows
     parse into wildcard name variants, and a row whose counter was

@@ -146,7 +146,7 @@ def test_open_stages_and_innermost_open():
 
 def test_compact_pipeline_emits_stage_spans():
     """The real cpu pipeline threads pack/device/gather spans through the
-    process-wide tracer — the breakdown bench.py records."""
+    process-wide tracer — the breakdown manual_compact's stats carry."""
     from pegasus_tpu.ops import CompactOptions, compact_blocks
 
     blk = _make_block(64)
@@ -239,28 +239,6 @@ def test_watchdog_probe_error_is_a_failure_not_a_crash():
                               tracer=StageTracer(prefix="t_wd3"))
     assert wd.probe() is False
     assert "device reset" in wd.state()["last_error"]
-
-
-def test_watchdog_loop_heartbeats_status_file(tmp_path):
-    """start() probes + heartbeats on its interval; the status file is the
-    cross-process channel bench.py's parent reads after abandoning a
-    wedged lane child."""
-    path = tmp_path / "wd.status"
-    wd = DeviceHealthWatchdog(interval_s=0.05, probe_fn=lambda: True,
-                              tracer=StageTracer(prefix="t_wd4"),
-                              status_path=str(path))
-    wd.start()
-    try:
-        deadline = time.monotonic() + 10
-        while not path.exists():
-            assert time.monotonic() < deadline
-            time.sleep(0.02)
-        payload = json.loads(path.read_text())
-        assert payload["last_ok"] is not None
-        assert payload["wedged_at_stage"] is None
-        assert "ts" in payload
-    finally:
-        wd.stop()
 
 
 # ---------------------------------------------- service-app round trip

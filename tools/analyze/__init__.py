@@ -17,6 +17,7 @@ passes the review rounds kept doing by hand:
   thread_lifecycle  raw Thread/ThreadPoolExecutor spawns must route
                     through runtime/tasking's tracked helpers
   env_knobs         every PEGASUS_* env read <-> README knob table
+  doc_paths         a source file the README names exists
 
 Run everything:  python -m tools.analyze  (exit 0 = clean; --json for
 machine-readable findings). Individual passes: --pass NAME (repeat).
@@ -120,9 +121,8 @@ class Repo:
         return out
 
     def package_files(self) -> list:
-        """The runtime package + the bench entry (what the original
-        lints scanned)."""
-        return self._glob(["pegasus_tpu/**/*.py", "bench.py"])
+        """The runtime package."""
+        return self._glob(["pegasus_tpu/**/*.py"])
 
     def tool_files(self) -> list:
         return self._glob(["tools/*.py"])
@@ -181,9 +181,9 @@ def pass_names() -> list:
 
 
 def _load_passes() -> None:
-    from . import (env_knobs, events, fail_points,  # noqa: F401
-                   lock_discipline, metric_names, remote_commands,
-                   span_names, thread_lifecycle)
+    from . import (doc_paths, env_knobs, events,  # noqa: F401
+                   fail_points, lock_discipline, metric_names,
+                   remote_commands, span_names, thread_lifecycle)
 
 
 def run_pass(name: str, repo: Repo = None) -> list:

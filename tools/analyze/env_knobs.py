@@ -30,7 +30,7 @@ is documentation, not configuration surface):
   * ``#: env_knob NAME [NAME...]`` declares knobs the walker cannot see
     (none today; the escape hatch for future dynamic composition).
 
-Scanned: pegasus_tpu/, tools/*.py, bench.py, tests/conftest.py (the
+Scanned: pegasus_tpu/, tools/*.py, tests/conftest.py (the
 test harness reads real knobs like PEGASUS_TEST_TPU).
 """
 

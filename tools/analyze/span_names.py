@@ -1,8 +1,9 @@
 """Span/hop-name cross-check pass (ISSUE 16 satellite).
 
 Every LITERAL span/hop name opened at a tracer call site — the stage
-and request tracers' ``<tracer>.span("name", ...)``
-(runtime/tracing.py), the job tracer's ``JOB_TRACER.hop/note("name",
+and request tracers' ``<tracer>.span("name", ...)`` and
+``<tracer>.span_in(ctx, "name", ...)`` (runtime/tracing.py), the job
+tracer's ``JOB_TRACER.hop/note("name",
 ...)`` (runtime/job_trace.py), and the offload service's job-span
 recorder ``self._trace(job, "name", ...)`` — must be DOCUMENTED in
 README.md's '### Span-name table', and every table row must still have
@@ -18,19 +19,21 @@ import re
 
 from . import Finding, Repo, register
 
-# literal-name span/hop call sites; group(1) = the name. Three shapes:
+# literal-name span/hop call sites; group(1) = the name. Four shapes:
 #   <tracer>.span("name"          stage + request tracers
 #   <tracer>.hop("name" / .note("name"    the job tracer
+#   <tracer>.span_in(ctx, "name"  a request span under a given context
 #   self._trace(job, "name"       the offload service's job recorder
 _SPAN_RE = re.compile(r"\.(?:span|hop|note)\(\s*\"([^\"]+)\"")
-_SVC_RE = re.compile(r"\b_trace\(\s*\w+\s*,\s*\"([^\"]+)\"")
+_SECOND_ARG_RE = re.compile(
+    r"(?:\b_trace|\.span_in)\(\s*\w+\s*,\s*\"([^\"]+)\"")
 
 
 def source_span_names(repo: Repo) -> set:
     names = set()
     for sf in repo.package_files():
         names.update(_SPAN_RE.findall(sf.text))
-        names.update(_SVC_RE.findall(sf.text))
+        names.update(_SECOND_ARG_RE.findall(sf.text))
     return names
 
 

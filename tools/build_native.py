@@ -12,8 +12,8 @@ always runs against the current C.
 
 A missing compiler degrades LOUDLY to the pure-Python twins (the
 loaders return None and every native call site has a byte-identical
-fallback) — the message names what was skipped so a "why is the bench
-slow" hunt starts in the right place.
+fallback) — the message names what was skipped so a "why is it slow"
+hunt starts in the right place.
 """
 
 import os
@@ -77,20 +77,18 @@ def _build(src: str, out: str, cc: list) -> str:
     return "rebuilt"
 
 
-def ensure(quiet: bool = False, force: bool = False) -> dict:
+def ensure(quiet: bool = False) -> dict:
     """Rebuild every stale native artifact. -> {label: status} with
     status in {fresh, rebuilt, missing-compiler, build-failed,
     missing-source}. Never raises: any failure means the pure-Python
-    twins serve (loudly, unless quiet). force=True rebuilds regardless
-    of mtimes (chip_smoke.py: a copied tree's mtimes prove nothing, of
-    the committed libhostops.so or of a gitignored leftover)."""
+    twins serve (loudly, unless quiet)."""
     statuses = {}
     for label, src, out, cc in _targets():
         if not os.path.exists(src):
             statuses[label] = "missing-source"
             continue
         try:
-            fresh = (not force and os.path.exists(out)
+            fresh = (os.path.exists(out)
                      and os.path.getmtime(out) >= os.path.getmtime(src))
         except OSError:
             fresh = False
