@@ -74,6 +74,10 @@ _C_RANGE_HOST = _counters.number("read.range.host_count")
 # read.range.device_ranges (ops/device_lookup.py)
 _C_RANGE_HOST_RANGES = _counters.number("read.range.host_ranges")
 _C_RANGE_REV_HOST = _counters.number("read.range.reverse_host_count")
+# which way _scan_over served a range that had rows to read: as slices of
+# its one SST's block, or through the k-way heap merge
+_C_RANGE_SLICE = _counters.number("read.range.slice_ranges")
+_C_RANGE_MERGED = _counters.number("read.range.merged_ranges")
 # monotonic totals of the two quiet device bypasses this module owns: a
 # failed residency prime (file stays host-packed) and a mesh that would
 # not resolve (manual_compact stays single-chip)
@@ -93,6 +97,55 @@ def _count_rows(it):
     finally:
         if c:
             _C_RANGE_ROWS.increment(c)
+
+
+# rows a single-source range cuts from its SST's block at once. Kept under
+# 500: numpy gives the GIL up inside a loop over more than 500 elements,
+# and a serving thread that gives it up waits out the other threads'
+# switch intervals to get it back
+_SLICE_ROWS = 256
+
+
+def _span(arena, off, ln):
+    """-> (starts, ends, buf): rows' bounds within buf, which is ONE
+    tobytes() of the arena span the rows lie in."""
+    end = off + ln
+    base = int(off.min())
+    return ((off - base).tolist(), (end - base).tolist(),
+            arena[base:int(end.max())].tobytes())
+
+
+def _slice_rows(b, lo, hi, now, include_deleted, reverse, flagged=False):
+    """Rows [lo, hi) of one sorted block whose keys are unique (descending
+    when `reverse`), _SLICE_ROWS at a time, each row's key and value cut
+    from its chunk's two arena spans. Unless `include_deleted`, one mask a
+    chunk drops tombstones and expired rows (check_if_ts_expired's
+    0 < expire_ts <= now). -> the (key, value, expire_ts) tuples the merge
+    yields; `flagged` adds each row's tombstone flag (a heap source's
+    rows, which the merge itself filters)."""
+    chunks = range(lo, hi, _SLICE_ROWS)
+    for i in (reversed(chunks) if reverse else chunks):
+        j = min(i + _SLICE_ROWS, hi)
+        cols = [b.key_off[i:j], b.key_len[i:j], b.val_off[i:j],
+                b.val_len[i:j], b.expire_ts[i:j], b.deleted[i:j]]
+        if not include_deleted:
+            e = cols[4]
+            live = ~cols[5] & ((e == 0) | (e > now))
+            if not live.all():
+                if not live.any():
+                    continue
+                cols = [c[live] for c in cols]
+        if reverse:
+            cols = [c[::-1] for c in cols]
+        ko, kl, vo, vl, e, d = cols
+        ks, ke, kb = _span(b.key_arena, ko, kl)
+        vs, ve, vb = _span(b.val_arena, vo, vl)
+        if flagged:
+            yield from [(kb[p:q], vb[r:s], x, y) for p, q, r, s, x, y
+                        in zip(ks, ke, vs, ve, e.tolist(), d.tolist())]
+        else:
+            yield from [(kb[p:q], vb[r:s], x) for p, q, r, s, x
+                        in zip(ks, ke, vs, ve, e.tolist())]
 
 # meta-store keys (reference: src/server/meta_store.cpp:29)
 META_DATA_VERSION = "pegasus_data_version"
@@ -793,9 +846,19 @@ class LsmEngine:
         one lazily on first pull, preserving scan()'s generator
         semantics). `sst_bounds` ({id(sst): (lo, hi)}) injects
         pre-resolved per-SST row intervals — the device range path
-        (scan_range_batch) supplies them so the IDENTICAL merge below
+        (scan_range_batch) supplies them so the IDENTICAL generator below
         yields byte-identical rows with the host binary searches elided;
-        absent entries mean the SST was pruned."""
+        absent entries mean the SST was pruned.
+
+        A range whose rows all lie in ONE SST (memtable and immutables
+        empty over it, one SST interval non-empty) leaves as slices of
+        that SST's block (_slice_rows), not through the heap: a run holds
+        each key once (a flush sorts a dict with newest-wins dedup,
+        compact_blocks dedups every merge, mesh and offload ones included,
+        and a bulk-load ingest goes through compact_blocks), so there is
+        no older version to shadow. Two or more non-empty sources take the
+        k-way heap merge, whose SST sources are the same cutter with every
+        row and its tombstone flag kept."""
         if snap is None:
             snap = self._scan_snapshot()
         now = epoch_now() if now is None else now
@@ -804,51 +867,28 @@ class LsmEngine:
         def in_range(k):
             return k >= start_key and (stop_key is None or k < stop_key)
 
-        mem_snapshot = sorted((k, v) for k, v in mem_items if in_range(k))
-        imm_snapshots = [sorted((k, v) for k, v in items if in_range(k))
-                         for items in imm_items]
+        mems = [sorted((k, v) for k, v in items if in_range(k))
+                for items in [mem_items] + imm_items]
+        mems = [m for m in mems if m]  # newest first, like the heap's ranks
+        runs = [r for r in (self._sst_rows(s, start_key, stop_key, hash32,
+                                           sst_bounds) for s in ssts)
+                if r is not None]
+        if not mems and len(runs) == 1:
+            _C_RANGE_SLICE.increment()
+            yield from _slice_rows(*runs[0], now, include_deleted, reverse)
+            return
+        if not mems and not runs:
+            return
+        _C_RANGE_MERGED.increment()
 
         def mem_source(snap):
             it = reversed(snap) if reverse else snap
             for k, (v, e, d) in it:
                 yield k, v, e, d
 
-        def sst_source(sst):
-            if sst_bounds is not None:
-                lohi = sst_bounds.get(id(sst))
-                if lohi is None or lohi[0] >= lohi[1]:
-                    return  # pruned or empty interval
-                try:
-                    b = sst.block()
-                except CorruptionError as e:
-                    self._notify_corruption(e)
-                    raise
-                lo, hi = lohi
-            else:
-                if sst.n == 0:
-                    return
-                if stop_key is not None and sst.min_key and sst.min_key >= stop_key:
-                    return
-                if start_key and sst.max_key and sst.max_key < start_key:
-                    return
-                if hash32 is not None and not sst.maybe_contains_hash(hash32):
-                    return
-                try:
-                    b = sst.block()
-                except CorruptionError as e:
-                    self._notify_corruption(e)
-                    raise
-                lo = sst.lower_bound(start_key) if start_key else 0
-                hi = sst.lower_bound(stop_key) if stop_key is not None else b.n
-                if start_key or stop_key is not None:
-                    _C_RANGE_HOST_RANGES.increment()
-            rng = range(hi - 1, lo - 1, -1) if reverse else range(lo, hi)
-            for i in rng:
-                yield b.key(i), b.value(i), int(b.expire_ts[i]), bool(b.deleted[i])
-
-        sources = [mem_source(mem_snapshot)]
-        sources += [mem_source(s) for s in imm_snapshots]
-        sources += [sst_source(s) for s in ssts]
+        sources = [mem_source(m) for m in mems]
+        sources += [_slice_rows(*r, now, True, reverse, flagged=True)
+                    for r in runs]
         # recency rank = position in `sources`; lower wins for equal keys.
         # descending merges invert the key order, not the recency order.
         hk = (lambda k: _RevBytes(k)) if reverse else (lambda k: k)
@@ -876,6 +916,38 @@ class LsmEngine:
                 if d or check_if_ts_expired(now, e):
                     continue
             yield k, v, e
+
+    def _sst_rows(self, sst, start_key, stop_key, hash32, sst_bounds):
+        """One SST's part of a merged scan: -> (block, lo, hi) for a
+        non-empty row interval, None for a pruned file or an empty one.
+        With `sst_bounds` the interval is the one the batch resolved;
+        without, the file walk prunes by min/max key and hashkey bloom and
+        resolves both bounds itself (counted in read.range.host_ranges)."""
+        if sst_bounds is not None:
+            lohi = sst_bounds.get(id(sst))
+            if lohi is None or lohi[0] >= lohi[1]:
+                return None  # pruned or empty interval
+            lo, hi = lohi
+        else:
+            if sst.n == 0:
+                return None
+            if stop_key is not None and sst.min_key and sst.min_key >= stop_key:
+                return None
+            if start_key and sst.max_key and sst.max_key < start_key:
+                return None
+            if hash32 is not None and not sst.maybe_contains_hash(hash32):
+                return None
+        try:
+            b = sst.block()
+        except CorruptionError as e:
+            self._notify_corruption(e)
+            raise
+        if sst_bounds is None:
+            lo = sst.lower_bound(start_key) if start_key else 0
+            hi = sst.lower_bound(stop_key) if stop_key is not None else b.n
+            if start_key or stop_key is not None:
+                _C_RANGE_HOST_RANGES.increment()
+        return (b, lo, hi) if lo < hi else None
 
     def scan_range_batch(self, ranges, now=None, reverse=False,
                          hash32s=None) -> list:
